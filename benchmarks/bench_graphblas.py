@@ -38,7 +38,7 @@ from repro.challenge.pipeline import analyze_peak_buffer_bytes
 from repro.core import Table, run_all_queries, run_all_queries_csr
 from repro.core.temporal import windowed_queries
 
-from .common import emit, packet_arrays, run_manifest, time_fn
+from .common import device_kind, emit, packet_arrays, run_manifest, time_fn
 
 # the memory A/B compiles analyze twice; a larger window axis makes the
 # dense grids' O(n_windows × capacity) term dominate (tests pin >= 4x here)
@@ -147,10 +147,12 @@ def run(
     from repro.launch.roofline import program_roofline
 
     roofline = {
-        "csr_all14": program_roofline(jcsr.lower(t).compile().as_text(), t_csr),
-        "jaxdf_all14": program_roofline(jall.lower(t).compile().as_text(), t_jax),
+        "csr_all14": program_roofline(
+            jcsr.lower(t).compile().as_text(), t_csr, device_kind()),
+        "jaxdf_all14": program_roofline(
+            jall.lower(t).compile().as_text(), t_jax, device_kind()),
         "windowed_csr": program_roofline(
-            jw_csr.lower(tw).compile().as_text(), t_wcsr),
+            jw_csr.lower(tw).compile().as_text(), t_wcsr, device_kind()),
     }
     for kname, rf in roofline.items():
         emit(f"roofline/{kname}", rf["wall_s"],

@@ -30,7 +30,8 @@ from repro.core.plan import count_hlo_sorts
 from repro.core.ref import ref_run_all_queries, ref_traffic_matrix
 from repro.core.temporal import windowed_queries, windowed_queries_naive
 
-from .common import emit, kernel_roofline, packet_arrays, run_manifest, time_fn
+from .common import (device_kind, emit, kernel_roofline, packet_arrays,
+                     run_manifest, time_fn)
 
 QUERIES = {
     "valid_packets": (Q.valid_packets, lambda s, d: int(len(s))),
@@ -195,7 +196,7 @@ def _roofline_section(t, jall, t_all: float, src: np.ndarray,
             lambda c, ci, p: cms_update(c, ci, p),
             counts, cols, props, iters=iters),
         "all14_pipeline": program_roofline(
-            jall.lower(t).compile().as_text(), t_all),
+            jall.lower(t).compile().as_text(), t_all, device_kind()),
     }
     return out
 

@@ -164,13 +164,16 @@ def run(n: int = 1 << 17, json_path: Optional[str] = None) -> Dict[str, Dict]:
     from repro.stream.engine import update_state
     from repro.stream.state import init_state
 
+    from .common import device_kind
+
     state0 = init_state(cfg.link_capacity, cfg.ips, cfg.n_windows, cfg.ip_bins)
     z = jnp.zeros((batch,), jnp.int32)
     fold_fn = jax.jit(lambda s, a, b, c, nv: update_state(s, a, b, c, nv))
     fold_hlo = fold_fn.lower(
         state0, z, z, z, jnp.asarray(batch, jnp.int32)).compile().as_text()
     roofline = {
-        "fold": program_roofline(fold_hlo, base["steady"]["update_s"]),
+        "fold": program_roofline(fold_hlo, base["steady"]["update_s"],
+                                 device_kind()),
     }
     emit("roofline/fold", roofline["fold"]["wall_s"],
          f"{roofline['fold']['roofline_fraction']:.4f} of peak "

@@ -50,7 +50,12 @@ def kernel_roofline(fn: Callable, *args, iters: int = 5) -> Dict:
     jitted = jax.jit(fn)
     compiled = jitted.lower(*args).compile()
     wall = time_fn(jitted, *args, iters=iters)
-    return program_roofline(compiled.as_text(), wall)
+    return program_roofline(compiled.as_text(), wall, device_kind())
+
+
+def device_kind() -> str:
+    """The measured device's kind — the key of its roofline peak row."""
+    return jax.devices()[0].device_kind
 
 
 def time_fn(fn: Callable, *args, iters: int = 5, warmup: int = 1) -> float:

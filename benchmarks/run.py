@@ -7,7 +7,7 @@ Emits ``name,us_per_call,derived`` CSV rows:
   algorithms/*   Graph Challenge  (BFS/CC/PageRank/triangles, oracle-gated)
   anonymize/*    paper §IV        (shuffle vs HashGraph-style vs numpy)
   kernel/*       beyond-paper     (autotune sweep: chosen vs default config)
-  distributed/*  beyond-paper     (shard_map pipeline at 8 shards)
+  distributed/*  beyond-paper     (shard_map suite over this process's devices)
   endtoend/*     paper pipeline   (per-phase + fused full-workload throughput)
   sketch/*       beyond-paper     (bounded-memory tier: wall + error-vs-bound)
   serve/*        beyond-paper     (fault-tolerant service: checkpoint tax +
@@ -70,6 +70,10 @@ def main() -> None:
                          "(empty string disables)")
     args = ap.parse_args()
     n = (1 << 17) if args.quick else args.n
+
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     from . import (bench_algorithms, bench_anonymize, bench_distributed,
                    bench_endtoend, bench_graphblas, bench_io, bench_kernels,

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 from repro.core.ref import ref_run_all_queries
 from repro.core.table import Table
 from repro.dist import distributed_queries
@@ -37,7 +35,7 @@ def main(n: int = 1 << 20) -> None:
     dst = cols["dst"].astype(np.int32)
 
     mesh = jax.make_mesh((8,), ("rows",))
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         lambda s, d: distributed_queries(
             Table.from_dict({"src": s, "dst": d}), "rows"),
         mesh=mesh, in_specs=(P("rows"), P("rows")), out_specs=P(),

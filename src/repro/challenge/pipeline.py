@@ -28,6 +28,7 @@ see.  ``distributed=True`` runs the scalar suite via shard_map
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 import tempfile
 import time
@@ -274,7 +275,10 @@ jax.tree_util.register_dataclass(
 class ChallengeRun:
     """A finished run: device results + timings + the host capture columns.
 
-    ``anon_columns`` (populated when ``config.algorithms`` is set) holds
+    ``anon_table`` is the anonymized device table ``analyze`` ran on, so a
+    caller can re-run analyze on exactly that input (chip_smoke.py compares
+    kernel backends on it).  ``anon_columns`` (populated when
+    ``config.algorithms`` is set) holds
     host copies of the anonymized src/dst live prefix — the exact edge
     list the algorithm pass ran on, so the NumPy oracles can replay it
     directly in the anonymized-id domain (challenge/run.py --verify).
@@ -285,6 +289,7 @@ class ChallengeRun:
     capture: Dict[str, np.ndarray]
     config: ChallengeConfig
     anon_columns: Optional[Dict[str, np.ndarray]] = None
+    anon_table: Optional[Table] = None
 
 
 # ---------------------------------------------------------------------------
@@ -641,6 +646,8 @@ def run_challenge(
     cfg: ChallengeConfig, key: Optional[jax.Array] = None
 ) -> ChallengeRun:
     """Run read -> build -> anonymize -> analyze, timing each phase."""
+    if cfg.distributed:
+        _mesh_device_count()  # refuse before the run, not after it
     if key is None:
         key = jax.random.key(cfg.seed)
     workdir = cfg.workdir or tempfile.mkdtemp(prefix="netsense_challenge_")
@@ -705,7 +712,7 @@ def run_challenge(
             compile_s=sp_compile.duration_s if sp_compile is not None else None,
         )
 
-        if cfg.distributed and len(jax.devices()) > 1:
+        if cfg.distributed:
             results = dataclasses.replace(
                 results, scalars=distributed_scalar_queries(anon.table)
             )
@@ -722,7 +729,8 @@ def run_challenge(
         }
 
     return ChallengeRun(results=results, timings=timings, capture=capture,
-                        config=cfg, anon_columns=anon_columns)
+                        config=cfg, anon_columns=anon_columns,
+                        anon_table=anon.table)
 
 
 def _time_fused(cfg, src, dst, win, n, key, kw) -> float:
@@ -745,6 +753,43 @@ def _time_fused(cfg, src, dst, win, n, key, kw) -> float:
     return sp.duration_s
 
 
+def _mesh_device_count() -> int:
+    """Local device count for the shard_map path; refuses fewer than two."""
+    n_dev = len(jax.devices())
+    if n_dev < 2:
+        raise RuntimeError(
+            f"distributed scalar queries need >= 2 devices, found {n_dev} "
+            f"({jax.devices()[0].platform}); run without --distributed"
+        )
+    return n_dev
+
+
+@functools.lru_cache(maxsize=None)
+def _distributed_suite(n_dev: int):
+    """The jitted shard_map scalar suite over ``n_dev`` devices, built once
+    so that repeated snapshots reuse one executable."""
+    from jax.sharding import PartitionSpec as P
+
+    from ..dist.relational import distributed_queries
+    from ..launch.mesh import make_analytics_mesh
+
+    def fn(src, dst, w, nv):
+        # per-shard validity: rows are globally [0, n_valid) — recompute
+        # locally
+        shard = jax.lax.axis_index("rows")
+        local = src.shape[0]
+        local_nv = jnp.clip(nv - shard * local, 0, local)
+        tt = Table(columns={"src": src, "dst": dst, "n_packets": w},
+                   n_valid=local_nv)
+        return distributed_queries(tt, "rows")
+
+    return jax.jit(jax.shard_map(
+        fn, mesh=make_analytics_mesh(n_dev),
+        in_specs=(P("rows"), P("rows"), P("rows"), P()),
+        out_specs=P(),
+    ))
+
+
 def distributed_scalar_queries(t: Table) -> QueryResults:
     """Scalar suite via the shard_map path over all local devices.
 
@@ -752,37 +797,17 @@ def distributed_scalar_queries(t: Table) -> QueryResults:
     ``n_packets`` weights) — the streaming engine reuses this to merge its
     accumulated link-table state through ``repro.dist`` (weighted links are
     query-equivalent to the packets they summarize).
+
+    Raises ``RuntimeError`` with fewer than two devices: a one-shard
+    "distributed" run would silently be the single-device path.
     """
-    from jax.sharding import PartitionSpec as P
-
-    from ..compat import shard_map
-    from ..dist.relational import distributed_queries
-    from ..launch.mesh import make_analytics_mesh
-
-    n_dev = len(jax.devices())
+    n_dev = _mesh_device_count()
     cap = t.capacity
     pad_to = -(-cap // n_dev) * n_dev
     grow = lambda a: jnp.pad(a, (0, pad_to - cap))
-    mesh = make_analytics_mesh(n_dev)
-    # per-shard validity: rows are globally [0, n_valid) — recompute locally
-    n_valid = t.n_valid
-
-    def fn(src, dst, w, nv):
-        import jax.lax as lax
-
-        shard = lax.axis_index("rows")
-        local = src.shape[0]
-        local_nv = jnp.clip(nv - shard * local, 0, local)
-        tt = Table(columns={"src": src, "dst": dst, "n_packets": w},
-                   n_valid=local_nv)
-        return distributed_queries(tt, "rows")
-
-    w = packet_weights(t)
-    out = jax.jit(shard_map(
-        fn, mesh=mesh,
-        in_specs=(P("rows"), P("rows"), P("rows"), P()),
-        out_specs=P(),
-    ))(grow(t["src"]), grow(t["dst"]), grow(w), n_valid)
+    out = _distributed_suite(n_dev)(
+        grow(t["src"]), grow(t["dst"]), grow(packet_weights(t)), t.n_valid
+    )
     overflow = int(out["overflow"])
     if overflow:
         # the exchange contract: overflow is reported, never silent — the

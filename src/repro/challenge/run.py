@@ -11,7 +11,6 @@ statistics, cross-window IP overlap and the k heaviest links; ``--verify``
 from __future__ import annotations
 
 import argparse
-import functools
 import sys
 from typing import Mapping, Optional, Sequence
 
@@ -177,12 +176,14 @@ def run_sketch_tier(
     import jax
     import jax.numpy as jnp
 
-    from ..core.sketch import init_sketch, snapshot_sketch, update_sketch
+    from ..core.sketch import init_sketch, snapshot_sketch
+    from ..stream.engine import _jitted_sketch_update
 
     src = np.asarray(capture["src"], np.int64)
     dst = np.asarray(capture["dst"], np.int64)
     state = init_sketch(cfg)
-    update = jax.jit(functools.partial(update_sketch, backend=backend))
+    # the stream engine's cached fold: repeated runs reuse one executable
+    update = _jitted_sketch_update(backend, jax.default_backend() != "cpu")
     for off in range(0, len(src), batch_capacity):
         s = src[off:off + batch_capacity]
         d = dst[off:off + batch_capacity]
@@ -346,6 +347,10 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     ap.add_argument("--no-verify", dest="verify", action="store_false",
                     help="skip the NumPy-oracle scalar check")
     args = ap.parse_args(argv)
+    if argv is None:  # the command line, not a caller passing its own argv
+        from ..launch.compile_cache import use_compile_cache
+
+        use_compile_cache()
 
     try:
         cfg = ChallengeConfig(

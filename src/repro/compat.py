@@ -1,26 +1,16 @@
-"""Version shims for jax API moves (0.4.x ↔ 0.5+).
+"""The one home for calls whose spelling depends on the installed JAX.
 
-``shard_map`` graduated from ``jax.experimental.shard_map`` to ``jax.shard_map``
-and ``lax.axis_size`` appeared after 0.4.37; callers import both from here so
-the rest of the tree is version-agnostic.
+``enable_x64`` scopes 64-bit types for the packed uint64 sort key
+(``core/ops.py``).  JAX 0.9 removed the experimental x64 context manager;
+the supported one is ``jax.enable_x64(True)``.
 """
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
-from jax import lax
 
-__all__ = ["shard_map", "axis_size"]
-
-try:
-    shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map  # type: ignore[no-redef]
+__all__ = ["enable_x64"]
 
 
-def axis_size(axis_name) -> int:
-    """Static size of a mapped axis (or tuple of axes) inside shard_map."""
-    if hasattr(lax, "axis_size"):
-        return int(lax.axis_size(axis_name))
-    # all_gather of a scalar has static shape (n,) — a trace-time constant.
-    return lax.all_gather(jnp.zeros((), jnp.int32), axis_name).shape[0]
+def enable_x64():
+    """Context manager that enables 64-bit types for its body."""
+    return jax.enable_x64(True)

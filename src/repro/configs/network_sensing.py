@@ -5,7 +5,7 @@ the 14 Table III queries + anonymization over a row-sharded packet table
 (2^26 rows for the dry-run ≈ 1/16 of the challenge's 2^30, so the per-device
 shard matches a full-scale 8192-device deployment row-for-row).
 
-Cells lower a jit(shard_map(...)) over the production mesh — this is the
+Cells lower a jit(jax.shard_map(...)) over the production mesh — this is the
 paper's technique under the multi-pod dry-run, distinct from the 40
 assigned-architecture cells.
 """
@@ -20,7 +20,6 @@ from jax.sharding import PartitionSpec as P
 
 from ..core.table import Table
 from ..dist.relational import distributed_queries
-from ..compat import shard_map
 from .common import ArchSpec, Cell, MeshAxes
 
 ARCH_ID = "network-sensing"
@@ -45,7 +44,7 @@ def build_cell(shape: str, mp: MeshAxes) -> Optional[Cell]:
         t = Table.from_dict({"src": src, "dst": dst, "n_packets": w})
         return distributed_queries(t, axis_names)
 
-    step = shard_map(
+    step = jax.shard_map(
         queries_fn, mesh=mp.mesh,
         in_specs=(col_spec, col_spec, col_spec),
         out_specs=P(),

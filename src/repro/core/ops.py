@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..compat import enable_x64
+
 __all__ = [
     "packable_keys",
     "packed_key_words",
@@ -77,12 +79,12 @@ def _validity_key(capacity: int, n_valid: jnp.ndarray) -> jnp.ndarray:
 #     repaired after the sort by a stable partition on the carried validity
 #     payload (one cumsum + scatter — O(n), not a second sort).
 #
-# 64-bit wrinkle: the default JAX config canonicalizes 64-bit *literals* away
-# even when a traced uint64 value is legal, so the pack/unpack never performs
-# uint64 arithmetic — words are assembled in uint32 and a
-# ``bitcast_convert_type`` inside ``jax.experimental.enable_x64()`` fuses
+# 64-bit wrinkle: with x64 off (the default) JAX canonicalizes 64-bit types
+# to 32 bits, so the pack/unpack never performs uint64 arithmetic — words
+# are assembled in uint32, and only the ``bitcast_convert_type`` that fuses
 # (n, 2) uint32 -> (n,) uint64 (XLA defines element 0 of the trailing dim as
-# the least-significant word).  Wider or non-32-bit key sets fall back to the
+# the least-significant word), the sort, and the split back run inside
+# ``compat.enable_x64()``.  Wider or non-32-bit key sets fall back to the
 # multi-operand comparator sort unchanged.
 # -----------------------------------------------------------------------------
 
@@ -113,12 +115,12 @@ def _unbias_u32(u: jnp.ndarray, dtype) -> jnp.ndarray:
 
 def _fuse_u64(hi: jnp.ndarray, lo: jnp.ndarray) -> jnp.ndarray:
     pair = jnp.stack([lo, hi], axis=-1)  # element 0 = least-significant word
-    with jax.experimental.enable_x64():
+    with enable_x64():
         return lax.bitcast_convert_type(pair, jnp.uint64)
 
 
 def _split_u64(packed: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    with jax.experimental.enable_x64():
+    with enable_x64():
         pair = lax.bitcast_convert_type(packed, jnp.uint32)
     return pair[..., 1], pair[..., 0]
 
@@ -186,7 +188,7 @@ def _packed_sort(
     # with the invalid sentinel — carry validity and repair post-sort.
     repair = len(keys) == 2 and valid_mask is not None
     operands = (packed, *payloads) + ((valid_mask,) if repair else ())
-    with jax.experimental.enable_x64():
+    with enable_x64():
         out = lax.sort(operands, num_keys=1, is_stable=True)
     packed, spayloads = out[0], out[1:]
     shi, slo = _split_u64(packed)  # back to uint32 words before any gather —

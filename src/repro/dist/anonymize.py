@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..compat import axis_size
 from ..core.ops import factorize, mix32, random_permutation, unique
 from ..core.queries import unique_ips
 from ..core.table import Table
@@ -45,7 +44,7 @@ def distributed_anonymize(
     incomplete — callers must treat the batch as failed and retry with a
     larger ``overflow_factor``.
     """
-    n_shards = axis_size(axis_name)
+    n_shards = lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
 
     ips = unique_ips(t)  # local distinct, tail-padded

@@ -29,7 +29,6 @@ from typing import Sequence, Tuple
 import jax.numpy as jnp
 from jax import lax
 
-from ..compat import axis_size
 from ..core.ops import mix32, multi_key_sort, segment_ids_from_sorted
 from ..core.sparse import CsrMatrix, from_coo
 
@@ -73,7 +72,7 @@ def exchange_by_owner(
     """
     cols = [jnp.asarray(c) for c in cols]
     cap = owner.shape[0]
-    n_shards = axis_size(axis_name)
+    n_shards = lax.axis_size(axis_name)
     bucket = bucket_size(cap, n_shards, overflow_factor)
     n_send = n_shards * bucket
 
@@ -135,7 +134,7 @@ def exchange_csr(
     missed their per-peer bucket (skewed keys) plus owner-side drops —
     reported, never silent, per the exchange contract.
     """
-    n_shards = axis_size(axis_name)
+    n_shards = lax.axis_size(axis_name)
     rows = csr.entry_rows()
     row_cols = [csr.entry_row_key(i, rows) for i in range(len(csr.row_keys))]
     owner = (mix32(row_cols[0]) % jnp.uint32(n_shards)).astype(jnp.int32)
@@ -162,7 +161,7 @@ def return_to_sender(
     on the *owner* side; the result, gathered at ``slot`` (where >= 0), is
     each original row's answer on the *sender* side.
     """
-    n_shards = axis_size(axis_name)
+    n_shards = lax.axis_size(axis_name)
     bucket = reply.shape[0] // n_shards
     back = _swap(reply, axis_name, n_shards, bucket)
     safe = jnp.where(slot >= 0, slot, 0)

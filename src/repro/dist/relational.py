@@ -31,7 +31,6 @@ from typing import Dict
 import jax.numpy as jnp
 from jax import lax
 
-from ..compat import axis_size
 from ..core.ops import groupby_aggregate, masked_max, mix32, unique
 from ..core.queries import packet_weights, table_csrs, unique_ips
 from ..core.sparse import degrees, reduce_rows
@@ -107,7 +106,7 @@ def distributed_queries_naive(
     as the A/B baseline for :func:`distributed_queries` — identical
     outputs, exercised by tests/_distributed_worker.py.
     """
-    n_shards = axis_size(axis_name)
+    n_shards = lax.axis_size(axis_name)
     w = packet_weights(t)
     valid = t.valid_mask()
 
@@ -175,7 +174,7 @@ def distributed_unique_count(
     Returns ``(count, overflow)`` replicated scalars.  Works over a tuple of
     axes (e.g. ``("pod", "rows")``) — the hash route then crosses pods.
     """
-    n_shards = axis_size(axis_name)
+    n_shards = lax.axis_size(axis_name)
     if valid_mask is None:
         valid_mask = jnp.ones(x.shape, jnp.bool_)
     # local distinct first: bounds the exchange volume by the local key space

@@ -14,18 +14,19 @@ runs on the VPU, and consecutive proposal blocks revisit the same output
 tile resident in VMEM, folding partials with ``jnp.maximum`` — the TPU
 replacement for CUDA ``atomicMax`` (what cuDF-style CMS kernels use).
 
-``cms_update_pallas`` is the depth-row generalisation: the grid grows a
-leading ``depth`` axis — ``(depth, num_width_tiles, num_prop_blocks)`` —
-and every depth row scatters the *same* proposal vector through its own
-hash row of ``col_ids``.  The conservative-update rule (propose
+``cms_update_pallas`` is the depth-row generalisation: the grid is
+``(num_width_tiles, num_prop_blocks)``, each block holds every depth row,
+and every row scatters the *same* proposal vector through its own hash
+row of ``col_ids``.  The conservative-update rule (propose
 ``min_r counts[r, h_r(x)] + n_x``, take the cell-wise max) means the cell
 update is a pure max fold, so the existing accumulate idiom (seed the
 output tile from the running counts) gives batch-into-state folding in one
 dispatch.  ``hll_update_pallas`` is the 1-row case and simply re-exports
 the segmented-max kernel: an HLL register fold *is* a segmented max.
 
-VMEM per step is ``2·Bn + Wt + Bn·Wt`` fp32 elements — the segreduce
-budget.  NumPy oracles: :func:`repro.kernels.ref.ref_cms_update` /
+VMEM per step is ``(depth + 1)·Bn + 2·depth·Wt + Bn·Wt`` 32-bit
+elements — the segreduce budget with the depth rows of ids and counts.
+NumPy oracles: :func:`repro.kernels.ref.ref_cms_update` /
 :func:`repro.kernels.ref.ref_hll_update` (interpret-parity tested in
 tests/test_kernels.py).
 """
@@ -45,25 +46,26 @@ __all__ = ["cms_update_pallas", "hll_update_pallas"]
 _NEG_INF = float("-inf")
 
 
-def _cms_kernel(ids_ref, prop_ref, init_ref, out_ref, *, block_width: int,
-                sentinel):
-    k = pl.program_id(2)  # proposal-block index (inner, accumulating)
-    i = pl.program_id(1)  # width-tile index
-    ids = ids_ref[...]  # (1, Bn) int32 — this depth row's hashed columns
+def _cms_kernel(ids_ref, prop_ref, init_ref, out_ref, *, depth: int,
+                block_width: int, sentinel):
+    k = pl.program_id(1)  # proposal-block index (inner, accumulating)
+    i = pl.program_id(0)  # width-tile index
     prop = prop_ref[...]  # (1, Bn) — shared across rows
-    base = i * block_width
-    cols = base + jax.lax.broadcasted_iota(jnp.int32, (1, block_width), 1)
-    sel = ids.T == cols  # (Bn, Wt)
-    cand = jnp.where(
-        sel, jnp.broadcast_to(prop.T, sel.shape), prop.dtype.type(sentinel)
+    cols = i * block_width + jax.lax.broadcasted_iota(
+        jnp.int32, (1, block_width), 1
     )
-    partial = jnp.max(cand, axis=0, keepdims=True)  # (1, Wt)
+    fill = prop.dtype.type(sentinel)
 
     @pl.when(k == 0)
     def _init():
         out_ref[...] = init_ref[...]
 
-    out_ref[...] = jnp.maximum(out_ref[...], partial)
+    for d in range(depth):  # static unroll: one (Bn, Wt) select per row
+        ids = ids_ref[d:d + 1, :]  # (1, Bn) int32 — row d's hashed columns
+        sel = ids.T == cols  # (Bn, Wt)
+        cand = jnp.where(sel, jnp.broadcast_to(prop.T, sel.shape), fill)
+        partial = jnp.max(cand, axis=0, keepdims=True)  # (1, Wt)
+        out_ref[d:d + 1, :] = jnp.maximum(out_ref[d:d + 1, :], partial)
 
 
 def cms_update_pallas(
@@ -109,18 +111,22 @@ def cms_update_pallas(
     init_p = jnp.pad(counts, ((0, 0), (0, w_pad)))
     width_padded = width + w_pad
 
-    grid = (depth, width_padded // block_width, ids_p.shape[1] // block_props)
+    # Each block spans the whole depth axis: the TPU tiling wants the
+    # last two block dims divisible by (8, 128) or equal to the array's,
+    # and a depth-1 block of a (depth, n) array is neither.
+    grid = (width_padded // block_width, ids_p.shape[1] // block_props)
     out = pl.pallas_call(
         functools.partial(
-            _cms_kernel, block_width=block_width, sentinel=sentinel
+            _cms_kernel, depth=depth, block_width=block_width,
+            sentinel=sentinel,
         ),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_props), lambda d, i, k: (d, k)),
-            pl.BlockSpec((1, block_props), lambda d, i, k: (0, k)),
-            pl.BlockSpec((1, block_width), lambda d, i, k: (d, i)),
+            pl.BlockSpec((depth, block_props), lambda i, k: (0, k)),
+            pl.BlockSpec((1, block_props), lambda i, k: (0, k)),
+            pl.BlockSpec((depth, block_width), lambda i, k: (0, i)),
         ],
-        out_specs=pl.BlockSpec((1, block_width), lambda d, i, k: (d, i)),
+        out_specs=pl.BlockSpec((depth, block_width), lambda i, k: (0, i)),
         out_shape=jax.ShapeDtypeStruct((depth, width_padded), dtype),
         interpret=interpret,
     )(ids_p, prop_p, init_p)

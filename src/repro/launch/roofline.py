@@ -17,8 +17,7 @@ MODEL/HLO ratio flags remat/redundancy waste.
 :func:`program_roofline` is the *measured* counterpart used by the
 benchmark lanes (DESIGN.md §2.8): given a timed compiled program's HLO
 text and its steady-state wall, it reports achieved bytes/s and flops/s
-against the :data:`BACKEND_PEAKS` ceiling of the active backend — the
-tracked roofline-fraction number of ROADMAP item 5.
+against the :data:`DEVICE_PEAKS` ceiling of the device it ran on.
 """
 from __future__ import annotations
 
@@ -28,22 +27,21 @@ import json
 import os
 from typing import Dict, Optional
 
-PEAK_FLOPS = 197e12        # bf16 per chip (v5e-class)
-HBM_BW = 819e9             # bytes/s per chip
-LINK_BW = 50e9             # bytes/s per ICI link
-
-# Per-backend peak tables for the *measured* roofline (program_roofline):
-# achieved bytes/s and flops/s of an actually-timed compiled program vs the
-# hardware ceiling.  The tpu row mirrors the v5e constants above; gpu is
-# A100-class (the paper's 147x-2185x table spans A100/H100/H200); cpu is a
-# commodity many-core node (~50 GB/s DRAM, ~0.5 TFLOP/s sustained f32) —
-# coarse on purpose: the fraction's job is regression *tracking* (ROADMAP
-# item 5), where only consistency across PRs matters, not absolute truth.
-BACKEND_PEAKS = {
+# Peak rates per device for the *measured* roofline (program_roofline),
+# keyed by ``jax.Device.device_kind``.  A kind missing here is an error,
+# never a default: a fraction against another chip's peaks is wrong.
+#   "TPU v5 lite" (TPU v5e): 197 TFLOP/s bf16 and 819 GB/s HBM per chip,
+#     from Google Cloud's "TPU v5e" documentation.
+#   "cpu": a commodity many-core node (~50 GB/s DRAM, ~0.5 TFLOP/s f32),
+#     coarse on purpose — CPU fractions only track one machine over time.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "bytes_per_s": 819e9},
     "cpu": {"flops": 5e11, "bytes_per_s": 5e10},
-    "gpu": {"flops": 312e12, "bytes_per_s": 2.0e12},
-    "tpu": {"flops": PEAK_FLOPS, "bytes_per_s": HBM_BW},
 }
+
+PEAK_FLOPS = DEVICE_PEAKS["TPU v5 lite"]["flops"]         # per chip
+HBM_BW = DEVICE_PEAKS["TPU v5 lite"]["bytes_per_s"]       # per chip
+LINK_BW = 50e9             # bytes/s per ICI link
 
 
 def hardware_fingerprint(backend: Optional[str] = None) -> Dict[str, object]:
@@ -81,18 +79,19 @@ def hardware_fingerprint(backend: Optional[str] = None) -> Dict[str, object]:
     }
 
 
-def peak_table(backend: Optional[str] = None) -> Dict[str, float]:
-    """The peak row for ``backend`` (default: the active jax backend)."""
-    if backend is None:
-        import jax
-
-        backend = jax.default_backend()
-    return dict(BACKEND_PEAKS.get(backend, BACKEND_PEAKS["cpu"]),
-                backend=backend)
+def peak_table(device_kind: str) -> Dict[str, float]:
+    """The peak row for ``device_kind``; raises ``KeyError`` for a kind
+    that is not in :data:`DEVICE_PEAKS`."""
+    if device_kind not in DEVICE_PEAKS:
+        raise KeyError(
+            f"no peak rates for device kind {device_kind!r}; known: "
+            f"{sorted(DEVICE_PEAKS)}"
+        )
+    return dict(DEVICE_PEAKS[device_kind], device_kind=device_kind)
 
 
 def program_roofline(
-    compiled_text: str, wall_s: float, backend: Optional[str] = None
+    compiled_text: str, wall_s: float, device_kind: str
 ) -> Dict[str, object]:
     """Achieved-vs-peak roofline of one timed compiled program.
 
@@ -102,7 +101,8 @@ def program_roofline(
     numerators come from the loop-trip-exact HLO traffic model
     (:func:`repro.launch.hloanalysis.analyze_hlo`); dividing by the wall
     gives achieved bytes/s and flops/s, and dividing those by the
-    :data:`BACKEND_PEAKS` row gives the two roofline fractions.  The
+    :data:`DEVICE_PEAKS` row of ``device_kind`` (the device the wall was
+    measured on) gives the two roofline fractions.  The
     reported ``roofline_fraction`` is the max of the two — how close the
     program runs to the binding ceiling — and ``bottleneck`` names which
     ceiling binds (the challenge kernels are memory-bound: sort/scatter
@@ -117,7 +117,7 @@ def program_roofline(
     """
     from .hloanalysis import analyze_hlo
 
-    peaks = peak_table(backend)
+    peaks = peak_table(device_kind)
     a = analyze_hlo(compiled_text)
     hbm = float(a["hbm_bytes"])
     flops = float(a["dot_flops"])
@@ -126,7 +126,7 @@ def program_roofline(
     frac_bw = b_s / peaks["bytes_per_s"]
     frac_fl = f_s / peaks["flops"]
     return {
-        "backend": peaks["backend"],
+        "device_kind": peaks["device_kind"],
         "wall_s": wall_s,
         "hbm_bytes": hbm,
         "dot_flops": flops,

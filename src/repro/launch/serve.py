@@ -129,6 +129,10 @@ def main(argv=None) -> int:
                     help="re-run uninterrupted/fault-free and require the "
                          "14-query snapshots to match exactly (chaos gate)")
     args = ap.parse_args(argv)
+    if argv is None:  # the command line, not a caller passing its own argv
+        from .compile_cache import use_compile_cache
+
+        use_compile_cache()
     return _run_with_telemetry(args, ap)
 
 

@@ -736,7 +736,7 @@ class StreamEngine:
         results = None
         if self.cfg.exact_enabled:
             results = self._snap(state)
-            if distributed and len(jax.devices()) > 1:
+            if distributed:
                 results = dataclasses.replace(
                     results,
                     scalars=distributed_scalar_queries(link_table(state)),

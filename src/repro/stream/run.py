@@ -124,6 +124,10 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     ap.add_argument("--no-verify", dest="verify", action="store_false",
                     help="skip the NumPy-oracle scalar check")
     args = ap.parse_args(argv)
+    if argv is None:  # the command line, not a caller passing its own argv
+        from ..launch.compile_cache import use_compile_cache
+
+        use_compile_cache()
 
     n = args.n_packets if args.n_packets is not None else 1 << args.scale
     if args.batches < 1 or n < 1:

@@ -4,8 +4,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 from repro.core.ref import ref_run_all_queries
 from repro.core.table import Table
 from repro.dist import (
@@ -36,10 +34,10 @@ def check_queries_match_oracle():
         return distributed_queries_naive(t, "rows")
 
     f = jax.jit(
-        shard_map(fn, mesh=mesh, in_specs=(P("rows"),) * 3, out_specs=P())
+        jax.shard_map(fn, mesh=mesh, in_specs=(P("rows"),) * 3, out_specs=P())
     )
     g = jax.jit(
-        shard_map(fn_naive, mesh=mesh, in_specs=(P("rows"),) * 3, out_specs=P())
+        jax.shard_map(fn_naive, mesh=mesh, in_specs=(P("rows"),) * 3, out_specs=P())
     )
     res, res_naive = f(src, dst, w), g(src, dst, w)
     assert int(res["overflow"]) == 0
@@ -61,7 +59,7 @@ def check_skewed_keys_still_exact():
         return distributed_queries(t, "rows", overflow_factor=4.0)
 
     f = jax.jit(
-        shard_map(fn, mesh=mesh, in_specs=(P("rows"),) * 2, out_specs=P())
+        jax.shard_map(fn, mesh=mesh, in_specs=(P("rows"),) * 2, out_specs=P())
     )
     res = f(src, dst)
     ref = ref_run_all_queries(src, dst)
@@ -82,7 +80,7 @@ def check_multi_pod_axes():
         return distributed_unique_count(x, ("pod", "rows"))
 
     f = jax.jit(
-        shard_map(fn, mesh=mesh, in_specs=(P(("pod", "rows")),), out_specs=(P(), P()))
+        jax.shard_map(fn, mesh=mesh, in_specs=(P(("pod", "rows")),), out_specs=(P(), P()))
     )
     cnt, ov = f(x)
     assert int(ov) == 0
@@ -101,7 +99,7 @@ def check_compression():
         return exact, b, q, res
 
     f = jax.jit(
-        shard_map(
+        jax.shard_map(
             fn,
             mesh=mesh,
             in_specs=(P("dp"),),
@@ -126,7 +124,7 @@ def check_distributed_anonymize():
     n = 8 * 2048
     src = rng.integers(0, 3000, n).astype(np.int32)
     dst = rng.integers(1000, 5000, n).astype(np.int32)
-    f = jax.jit(shard_map(
+    f = jax.jit(jax.shard_map(
         lambda s, d, k: distributed_anonymize(
             Table.from_dict({"src": s, "dst": d}), k, "rows"),
         mesh=mesh, in_specs=(P("rows"), P("rows"), P()),

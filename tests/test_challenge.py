@@ -259,3 +259,26 @@ def test_cli_main_smoke(tmp_path, capsys):
     assert rc == 0
     assert "14 max destination fan-in" in out
     assert "all scalar queries match the NumPy oracle" in out
+
+
+@pytest.mark.parametrize("entry", ["function", "challenge_cli", "stream_cli"])
+def test_distributed_refuses_one_device(entry, tmp_path, monkeypatch):
+    """--distributed on one device raises instead of quietly running the
+    single-device path."""
+    from repro.challenge.pipeline import distributed_scalar_queries
+    from repro.challenge.run import main as challenge_main
+    from repro.stream.run import main as stream_main
+
+    one = jax.devices()[:1]
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: one)
+    common = ["--scale", "9", "--windows", "2", "--workdir", str(tmp_path),
+              "--distributed"]
+    call = {
+        "function": lambda: distributed_scalar_queries(Table.from_dict(
+            {"src": np.arange(8, dtype=np.int32),
+             "dst": np.arange(8, dtype=np.int32)}, n_valid=8)),
+        "challenge_cli": lambda: challenge_main(common),
+        "stream_cli": lambda: stream_main(common + ["--batches", "2"]),
+    }[entry]
+    with pytest.raises(RuntimeError, match=r">= 2 devices, found 1"):
+        call()

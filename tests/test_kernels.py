@@ -158,7 +158,9 @@ def test_flash_attention_grad_matches_ref():
 
 @pytest.mark.parametrize("dtype", [np.float32, np.int32])
 @pytest.mark.parametrize("n", [1, 100, 1024, 5000])
-@pytest.mark.parametrize("depth,width", [(1, 64), (4, 512), (3, 1000)])
+# (4, 4096) is the sketch tier's default Count-Min: every block spans the
+# whole depth axis, as the TPU's (8, 128) block tiling requires.
+@pytest.mark.parametrize("depth,width", [(1, 64), (4, 512), (3, 1000), (4, 4096)])
 def test_cms_update_sweep(n, depth, width, dtype):
     counts = RNG.integers(0, 50, (depth, width)).astype(dtype)
     # incl. out-of-range ids and -1 = masked proposal, per the contract
