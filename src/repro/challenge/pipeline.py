@@ -521,52 +521,73 @@ def analyze(
         return _analyze_naive(
             t, n_windows=n_windows, ip_bins=ip_bins, k=k, backend=backend
         )
-    plans = table_plans(t)
-    plan_src, plan_dst = plans
-    ips = unique_ips(t)
-    links = link_groups(plan_src)
-    per_src = lead_groups(plan_src)
-    per_dst = lead_groups(plan_dst)
-    fanout = lead_fanout(plan_src)
-    fanin = lead_fanout(plan_dst)
+    # jax.named_scope labels each query family in the ops' metadata
+    # (analyze/plan, analyze/groups, ...) and changes no computation
+    with jax.named_scope("analyze"):
+        with jax.named_scope("plan"):
+            plans = table_plans(t)
+            plan_src, plan_dst = plans
+            ips = unique_ips(t)
+        with jax.named_scope("groups"):
+            links = link_groups(plan_src)
+            per_src = lead_groups(plan_src)
+            per_dst = lead_groups(plan_dst)
+            fanout = lead_fanout(plan_src)
+            fanin = lead_fanout(plan_dst)
 
-    algo = None
-    if algorithms:
-        from ..core.algorithms import graph_algorithms
-        from ..core.queries import table_csrs
+        algo = None
+        if algorithms:
+            from ..core.algorithms import graph_algorithms
+            from ..core.queries import table_csrs
 
-        csr_src, csr_dst = table_csrs(t, plans)
-        # static vertex domain: anonymized ids are < n_unique_ips, which is
-        # bounded by both endpoints of every packet row -> 2 * capacity
-        algo = graph_algorithms(
-            csr_src, csr_dst, 2 * t.capacity,
-            n_live=ips.n_unique, source=bfs_source, backend=backend,
-        )
+            with jax.named_scope("algorithms"):
+                csr_src, csr_dst = table_csrs(t, plans)
+                # static vertex domain: anonymized ids are < n_unique_ips,
+                # which is bounded by both endpoints of every packet row
+                # -> 2 * capacity
+                algo = graph_algorithms(
+                    csr_src, csr_dst, 2 * t.capacity,
+                    n_live=ips.n_unique, source=bfs_source, backend=backend,
+                )
+
+        with jax.named_scope("scalars"):
+            scalars = scalar_queries_from_plans(
+                t, plan_src, plan_dst, ips, links=links, per_src=per_src,
+                per_dst=per_dst, fanout=fanout, fanin=fanin,
+            )
+        with jax.named_scope("groups"):
+            unique_sources = unique_lead(plan_src)
+            unique_destinations = unique_lead(plan_dst)
+        with jax.named_scope("topk"):
+            top = top_links_from_plan(
+                plan_src, k, links, fused=fused_epilogue, backend=backend
+            )
+        with jax.named_scope("windowed"):
+            windowed = windowed_queries(
+                t, 1, n_windows, ts_col="win", t0=0, plans=plans,
+                method=windowed_method, fused=fused_epilogue, backend=backend)
+        with jax.named_scope("activity"):
+            activity = _window_activity(t, n_windows, ip_bins, backend)
+        with jax.named_scope("overlap"):
+            overlap = cross_window_ip_overlap(
+                t, n_windows, ips=ips,
+                method="scan" if windowed_method == "csr" else "grid",
+            )
 
     return ChallengeResults(
         algorithms=algo,
-        scalars=scalar_queries_from_plans(
-            t, plan_src, plan_dst, ips, links=links, per_src=per_src,
-            per_dst=per_dst, fanout=fanout, fanin=fanin,
-        ),
+        scalars=scalars,
         links=links,
         per_source=per_src,
         per_destination=per_dst,
         source_fanout=fanout,
         destination_fanin=fanin,
-        unique_sources=unique_lead(plan_src),
-        unique_destinations=unique_lead(plan_dst),
-        top=top_links_from_plan(
-            plan_src, k, links, fused=fused_epilogue, backend=backend
-        ),
-        windowed=windowed_queries(t, 1, n_windows, ts_col="win", t0=0,
-                                  plans=plans, method=windowed_method,
-                                  fused=fused_epilogue, backend=backend),
-        window_activity=_window_activity(t, n_windows, ip_bins, backend),
-        window_ip_overlap=cross_window_ip_overlap(
-            t, n_windows, ips=ips,
-            method="scan" if windowed_method == "csr" else "grid",
-        ),
+        unique_sources=unique_sources,
+        unique_destinations=unique_destinations,
+        top=top,
+        windowed=windowed,
+        window_activity=activity,
+        window_ip_overlap=overlap,
     )
 
 
@@ -575,30 +596,48 @@ def _analyze_naive(
 ) -> ChallengeResults:
     """Pre-plan analyze: one group-by sort per query family, relying on XLA
     CSE to dedupe what it structurally can."""
-    w = packet_weights(t)
-    links = traffic_matrix(t)
-    per_src = groupby_aggregate(
-        [t["src"]], {"packets": (w, "sum")}, n_valid=t.n_valid
-    )
-    per_dst = groupby_aggregate(
-        [t["dst"]], {"packets": (w, "sum")}, n_valid=t.n_valid
-    )
-    fanout = groupby_aggregate([links.keys[0]], None, n_valid=links.n_groups)
-    fanin = groupby_aggregate([links.keys[1]], None, n_valid=links.n_groups)
+    with jax.named_scope("analyze"):
+        with jax.named_scope("groups"):
+            w = packet_weights(t)
+            links = traffic_matrix(t)
+            per_src = groupby_aggregate(
+                [t["src"]], {"packets": (w, "sum")}, n_valid=t.n_valid
+            )
+            per_dst = groupby_aggregate(
+                [t["dst"]], {"packets": (w, "sum")}, n_valid=t.n_valid
+            )
+            fanout = groupby_aggregate([links.keys[0]], None,
+                                       n_valid=links.n_groups)
+            fanin = groupby_aggregate([links.keys[1]], None,
+                                      n_valid=links.n_groups)
+        with jax.named_scope("scalars"):
+            scalars = run_all_queries_naive(t)
+        with jax.named_scope("groups"):
+            unique_sources = unique(t["src"], n_valid=t.n_valid)
+            unique_destinations = unique(t["dst"], n_valid=t.n_valid)
+        with jax.named_scope("topk"):
+            top = top_links(t, k)
+        with jax.named_scope("windowed"):
+            windowed = windowed_queries_naive(t, 1, n_windows, ts_col="win",
+                                              t0=0)
+        with jax.named_scope("activity"):
+            activity = _window_activity(t, n_windows, ip_bins, backend)
+        with jax.named_scope("overlap"):
+            overlap = cross_window_ip_overlap_naive(t, n_windows, backend)
 
     return ChallengeResults(
-        scalars=run_all_queries_naive(t),
+        scalars=scalars,
         links=links,
         per_source=per_src,
         per_destination=per_dst,
         source_fanout=fanout,
         destination_fanin=fanin,
-        unique_sources=unique(t["src"], n_valid=t.n_valid),
-        unique_destinations=unique(t["dst"], n_valid=t.n_valid),
-        top=top_links(t, k),
-        windowed=windowed_queries_naive(t, 1, n_windows, ts_col="win", t0=0),
-        window_activity=_window_activity(t, n_windows, ip_bins, backend),
-        window_ip_overlap=cross_window_ip_overlap_naive(t, n_windows, backend),
+        unique_sources=unique_sources,
+        unique_destinations=unique_destinations,
+        top=top,
+        windowed=windowed,
+        window_activity=activity,
+        window_ip_overlap=overlap,
     )
 
 
@@ -642,6 +681,16 @@ def _block(x):
     return x
 
 
+def _dispatch_and_sync(fn, *args):
+    """Run one jitted phase program to completion under two child spans:
+    ``dispatch`` until the call returns (trace, lower, compile or load,
+    enqueue) and ``sync`` while the host waits for the device."""
+    with obs_span("dispatch"):
+        out = fn(*args)
+    with obs_span("sync"):
+        return _block(out)
+
+
 def run_challenge(
     cfg: ChallengeConfig, key: Optional[jax.Array] = None
 ) -> ChallengeRun:
@@ -657,8 +706,10 @@ def run_challenge(
               algorithms=cfg.algorithms, bfs_source=cfg.bfs_source)
 
     def _build(s, d, wn, nv):
-        table = build_table(s, d, wn, nv)  # build once; A_t groups the same
-        return table, traffic_matrix(table)
+        with jax.named_scope("build_table"):
+            table = build_table(s, d, wn, nv)  # build once; A_t groups it
+        with jax.named_scope("traffic_matrix"):
+            return table, traffic_matrix(table)
 
     build_fn = jax.jit(_build)
     anon_fn = jax.jit(
@@ -693,15 +744,15 @@ def run_challenge(
 
         # ---- build (windows + transfer + A_t group-by) ----
         with obs_span("build_device") as sp_build_dev:
-            table, _links = _block(build_fn(src, dst, win, n))
+            table, _links = _dispatch_and_sync(build_fn, src, dst, win, n)
 
         # ---- anonymize ----
         with obs_span("anonymize") as sp_anon:
-            anon = _block(anon_fn(table, key))
+            anon = _dispatch_and_sync(anon_fn, table, key)
 
         # ---- analyze ----
         with obs_span("analyze") as sp_analyze:
-            results = _block(analyze_fn(anon.table))
+            results = _dispatch_and_sync(analyze_fn, anon.table)
 
         timings = ChallengePhaseTimings(
             n_packets=n,
@@ -737,7 +788,8 @@ def _time_fused(cfg, src, dst, win, n, key, kw) -> float:
     """build+anonymize+analyze as ONE jitted, buffer-donated program."""
 
     def fused(s, d, wn, nv, k_):
-        t = build_table(s, d, wn, nv)
+        with jax.named_scope("build_table"):
+            t = build_table(s, d, wn, nv)
         return analyze(
             anonymize(t, k_, method=cfg.method, rounds=cfg.rounds).table, **kw
         )

@@ -57,25 +57,33 @@ def anonymize(
       rounds: extra shuffle rounds — the paper notes one or two extra
         iterations further decorrelate the permutation at negligible cost.
     """
-    ips = unique_ips(t)
-    cap = ips.values.shape[0]
-    n = ips.n_unique
-    if method == "shuffle":
-        if key is None:
-            raise ValueError("method='shuffle' requires a PRNG key")
-        keys = jax.random.split(key, rounds)
-        perm = random_permutation(keys[0], cap, n)
-        for k in keys[1:]:
-            # composing uniform permutations == shuffling again (paper §IV)
-            perm = perm[random_permutation(k, cap, n)]
-    elif method == "hash":
-        perm = hash_permutation(cap, n)
-        for r in range(1, rounds):
-            perm = perm[hash_permutation(cap, n, salt=0x9E3779B9 + r)]
-    else:
-        raise ValueError(f"unknown method {method!r}")
+    # device scopes name the paper's three steps in the profiler's op
+    # metadata: anonymize/unique, /permutation, /factorize, /gather
+    with jax.named_scope("anonymize"):
+        with jax.named_scope("unique"):
+            ips = unique_ips(t)
+        cap = ips.values.shape[0]
+        n = ips.n_unique
+        with jax.named_scope("permutation"):
+            if method == "shuffle":
+                if key is None:
+                    raise ValueError("method='shuffle' requires a PRNG key")
+                keys = jax.random.split(key, rounds)
+                perm = random_permutation(keys[0], cap, n)
+                for k in keys[1:]:
+                    # composing uniform permutations == shuffling again
+                    # (paper §IV)
+                    perm = perm[random_permutation(k, cap, n)]
+            elif method == "hash":
+                perm = hash_permutation(cap, n)
+                for r in range(1, rounds):
+                    perm = perm[hash_permutation(cap, n, salt=0x9E3779B9 + r)]
+            else:
+                raise ValueError(f"unknown method {method!r}")
 
-    src_rank = factorize(t["src"], ips.values)
-    dst_rank = factorize(t["dst"], ips.values)
-    anon = t.with_columns(src=perm[src_rank], dst=perm[dst_rank])
+        with jax.named_scope("factorize"):
+            src_rank = factorize(t["src"], ips.values)
+            dst_rank = factorize(t["dst"], ips.values)
+        with jax.named_scope("gather"):
+            anon = t.with_columns(src=perm[src_rank], dst=perm[dst_rank])
     return AnonymizationResult(table=anon, ip_values=ips.values, new_ids=perm, n_ips=n)

@@ -52,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels.ops import cms_update, segmented_reduce
+from ..obs import span as obs_span
 from .ops import groupby_aggregate, mix32, top_k
 
 __all__ = [
@@ -364,90 +365,102 @@ def update_sketch(
     then each summary folds in one dispatch.  Nothing overflows, ever —
     the sketches absorb arbitrary traffic at fixed memory; accuracy, not
     capacity, is what degrades.
+
+    Device scopes: ``update_sketch/groupby``, ``/cms``, ``/hll``,
+    ``/heavy``.
     """
-    cap = src.shape[0]
-    n_valid = jnp.asarray(n_valid, jnp.int32)
-    src = src.astype(jnp.int32)
-    dst = dst.astype(jnp.int32)
-    valid = jnp.arange(cap, dtype=jnp.int32) < n_valid
-    w = (jnp.ones((cap,), jnp.int32) if weights is None
-         else weights.astype(jnp.int32))
-    w = jnp.where(valid, w, 0)
-    seed, depth, width = state.seed, state.cms_depth, state.cms_width
+    with jax.named_scope("update_sketch"):
+        cap = src.shape[0]
+        n_valid = jnp.asarray(n_valid, jnp.int32)
+        src = src.astype(jnp.int32)
+        dst = dst.astype(jnp.int32)
+        valid = jnp.arange(cap, dtype=jnp.int32) < n_valid
+        w = (jnp.ones((cap,), jnp.int32) if weights is None
+             else weights.astype(jnp.int32))
+        w = jnp.where(valid, w, 0)
+        seed, depth, width = state.seed, state.cms_depth, state.cms_width
 
-    # batch group-bys: distinct links and distinct sources with totals
-    g_link = groupby_aggregate(
-        [src, dst], {"packets": (w, "sum")},
-        valid_mask=valid, count_name=None,
-    )
-    g_src = groupby_aggregate(
-        [src], {"packets": (w, "sum")},
-        valid_mask=valid, count_name=None,
-    )
+        # batch group-bys: distinct links and distinct sources with totals
+        with jax.named_scope("groupby"):
+            g_link = groupby_aggregate(
+                [src, dst], {"packets": (w, "sum")},
+                valid_mask=valid, count_name=None,
+            )
+            g_src = groupby_aggregate(
+                [src], {"packets": (w, "sum")},
+                valid_mask=valid, count_name=None,
+            )
 
-    def cms_fold(counts, rows, group_counts, mask):
-        # conservative update: propose est + batch_count at every row cell.
-        # All int32 end to end — a float32 round-trip would round the
-        # proposal down past 2^24 and underestimate.
-        safe = jnp.clip(rows, 0, width - 1)
-        gathered = jnp.stack(
-            [counts[r][safe[r]] for r in range(depth)]
-        )  # (depth, cap)
-        est = jnp.min(gathered, axis=0)
-        props = jnp.where(mask, est + group_counts.astype(jnp.int32), 0)
-        ids = jnp.where(mask[None, :], rows, -1)
-        return cms_update(counts, ids, props, backend=backend)
+        def cms_fold(counts, rows, group_counts, mask):
+            # conservative update: propose est + batch_count at every row cell.
+            # All int32 end to end — a float32 round-trip would round the
+            # proposal down past 2^24 and underestimate.
+            safe = jnp.clip(rows, 0, width - 1)
+            gathered = jnp.stack(
+                [counts[r][safe[r]] for r in range(depth)]
+            )  # (depth, cap)
+            est = jnp.min(gathered, axis=0)
+            props = jnp.where(mask, est + group_counts.astype(jnp.int32), 0)
+            ids = jnp.where(mask[None, :], rows, -1)
+            return cms_update(counts, ids, props, backend=backend)
 
-    lmask = g_link.mask() & (g_link.aggs["packets"] > 0)
-    smask = g_src.mask() & (g_src.aggs["packets"] > 0)
-    cms_links = cms_fold(
-        state.cms_links,
-        _link_rows(g_link.keys[0], g_link.keys[1], seed, depth, width),
-        g_link.aggs["packets"], lmask,
-    )
-    cms_sources = cms_fold(
-        state.cms_sources,
-        _src_rows(g_src.keys[0], seed, depth, width),
-        g_src.aggs["packets"], smask,
-    )
+        lmask = g_link.mask() & (g_link.aggs["packets"] > 0)
+        smask = g_src.mask() & (g_src.aggs["packets"] > 0)
+        with jax.named_scope("cms"):
+            cms_links = cms_fold(
+                state.cms_links,
+                _link_rows(g_link.keys[0], g_link.keys[1], seed, depth,
+                           width),
+                g_link.aggs["packets"], lmask,
+            )
+            cms_sources = cms_fold(
+                state.cms_sources,
+                _src_rows(g_src.keys[0], seed, depth, width),
+                g_src.aggs["packets"], smask,
+            )
 
-    # HLL folds over raw rows (duplicates are harmless to a max fold)
-    p = state.hll_p
+        # HLL folds over raw rows (duplicates are harmless to a max fold)
+        p = state.hll_p
 
-    def hll_fold(regs, hashes, mask):
-        reg, rho = _hll_parts(hashes, p)
-        return segmented_reduce(
-            rho.astype(jnp.float32), jnp.where(mask, reg, -1),
-            state.hll_m, op="max", init=regs, backend=backend,
+        def hll_fold(regs, hashes, mask):
+            reg, rho = _hll_parts(hashes, p)
+            return segmented_reduce(
+                rho.astype(jnp.float32), jnp.where(mask, reg, -1),
+                state.hll_m, op="max", init=regs, backend=backend,
+            )
+
+        with jax.named_scope("hll"):
+            hll_src = hll_fold(state.hll_src, _hash_src(src, seed + 1),
+                               valid)
+            hll_dst = hll_fold(state.hll_dst, _hash_src(dst, seed + 2),
+                               valid)
+            hll_links = hll_fold(state.hll_links,
+                                 _hash_link(src, dst, seed + 3), valid)
+
+        # space-saving folds over the batch-distinct groups
+        with jax.named_scope("heavy"):
+            (hl_src, hl_dst), hl_count, hl_off = _ss_fold(
+                [state.hh_link_src, state.hh_link_dst], state.hh_link_count,
+                state.hh_link_offset,
+                [g_link.keys[0], g_link.keys[1]], g_link.aggs["packets"],
+                lmask, jnp.zeros((), jnp.int32), state.heavy_capacity,
+            )
+            (hs_key,), hs_count, hs_off = _ss_fold(
+                [state.hh_src_key], state.hh_src_count, state.hh_src_offset,
+                [g_src.keys[0]], g_src.aggs["packets"], smask,
+                jnp.zeros((), jnp.int32), state.heavy_capacity,
+            )
+
+        return SketchState(
+            cms_links=cms_links, cms_sources=cms_sources,
+            hll_src=hll_src, hll_dst=hll_dst, hll_links=hll_links,
+            hh_link_src=hl_src, hh_link_dst=hl_dst, hh_link_count=hl_count,
+            hh_link_offset=hl_off,
+            hh_src_key=hs_key, hh_src_count=hs_count, hh_src_offset=hs_off,
+            n_packets=state.n_packets + jnp.sum(w),
+            n_batches=state.n_batches + 1,
+            seed=seed,
         )
-
-    hll_src = hll_fold(state.hll_src, _hash_src(src, seed + 1), valid)
-    hll_dst = hll_fold(state.hll_dst, _hash_src(dst, seed + 2), valid)
-    hll_links = hll_fold(state.hll_links, _hash_link(src, dst, seed + 3), valid)
-
-    # space-saving folds over the batch-distinct groups
-    (hl_src, hl_dst), hl_count, hl_off = _ss_fold(
-        [state.hh_link_src, state.hh_link_dst], state.hh_link_count,
-        state.hh_link_offset,
-        [g_link.keys[0], g_link.keys[1]], g_link.aggs["packets"], lmask,
-        jnp.zeros((), jnp.int32), state.heavy_capacity,
-    )
-    (hs_key,), hs_count, hs_off = _ss_fold(
-        [state.hh_src_key], state.hh_src_count, state.hh_src_offset,
-        [g_src.keys[0]], g_src.aggs["packets"], smask,
-        jnp.zeros((), jnp.int32), state.heavy_capacity,
-    )
-
-    return SketchState(
-        cms_links=cms_links, cms_sources=cms_sources,
-        hll_src=hll_src, hll_dst=hll_dst, hll_links=hll_links,
-        hh_link_src=hl_src, hh_link_dst=hl_dst, hh_link_count=hl_count,
-        hh_link_offset=hl_off,
-        hh_src_key=hs_key, hh_src_count=hs_count, hh_src_offset=hs_off,
-        n_packets=state.n_packets + jnp.sum(w),
-        n_batches=state.n_batches + 1,
-        seed=seed,
-    )
 
 
 def merge_sketches(a: SketchState, b: SketchState) -> SketchState:
@@ -662,25 +675,44 @@ class SketchSnapshot:
 def snapshot_sketch(
     state: SketchState, k: Optional[int] = None, hll_sigma: float = 4.0
 ) -> SketchSnapshot:
-    """Answer the sketch-tier query suite from the accumulated state."""
+    """Answer the sketch-tier query suite from the accumulated state.
+
+    Runs eagerly on the host; each part is a child span of the caller's
+    (``scalars``, ``heavy_links``, ``heavy_talkers``, ``bounds``), ending
+    when its answers are host values."""
     k = state.heavy_capacity if k is None else min(k, state.heavy_capacity)
-    scalars = {n: v for n, v in sketch_scalars(state).items()}
-    hl_src, hl_dst, hl_est, hl_n = heavy_links(state)
-    hs_key, hs_est, hs_n = heavy_talkers(state)
+    with obs_span("scalars"):
+        scalars = {n: float(v) for n, v in sketch_scalars(state).items()
+                   if n != "valid_packets"}
+        n_packets = int(state.n_packets)
+        n_batches = int(state.n_batches)
+    with obs_span("heavy_links"):
+        hl_src, hl_dst, hl_est, hl_n = heavy_links(state)
+        top_link_src = np.asarray(hl_src)[:k]
+        top_link_dst = np.asarray(hl_dst)[:k]
+        top_link_packets = np.asarray(hl_est)[:k]
+        n_top_links = min(int(hl_n), k)
+    with obs_span("heavy_talkers"):
+        hs_key, hs_est, hs_n = heavy_talkers(state)
+        top_talker_src = np.asarray(hs_key)[:k]
+        top_talker_packets = np.asarray(hs_est)[:k]
+        n_top_talkers = min(int(hs_n), k)
+    with obs_span("bounds"):
+        bounds = error_bounds(state, hll_sigma=hll_sigma)
     return SketchSnapshot(
-        n_packets=int(state.n_packets),
-        n_batches=int(state.n_batches),
-        unique_sources=float(scalars["n_unique_sources"]),
-        unique_destinations=float(scalars["n_unique_destinations"]),
-        unique_links=float(scalars["unique_links"]),
-        max_link_packets=float(scalars["max_link_packets"]),
-        max_source_packets=float(scalars["max_source_packets"]),
-        top_link_src=np.asarray(hl_src)[:k],
-        top_link_dst=np.asarray(hl_dst)[:k],
-        top_link_packets=np.asarray(hl_est)[:k],
-        n_top_links=min(int(hl_n), k),
-        top_talker_src=np.asarray(hs_key)[:k],
-        top_talker_packets=np.asarray(hs_est)[:k],
-        n_top_talkers=min(int(hs_n), k),
-        bounds=error_bounds(state, hll_sigma=hll_sigma),
+        n_packets=n_packets,
+        n_batches=n_batches,
+        unique_sources=scalars["n_unique_sources"],
+        unique_destinations=scalars["n_unique_destinations"],
+        unique_links=scalars["unique_links"],
+        max_link_packets=scalars["max_link_packets"],
+        max_source_packets=scalars["max_source_packets"],
+        top_link_src=top_link_src,
+        top_link_dst=top_link_dst,
+        top_link_packets=top_link_packets,
+        n_top_links=n_top_links,
+        top_talker_src=top_talker_src,
+        top_talker_packets=top_talker_packets,
+        n_top_talkers=n_top_talkers,
+        bounds=bounds,
     )

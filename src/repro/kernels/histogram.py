@@ -185,5 +185,6 @@ def histogram_pallas(
         out_specs=bin_spec,
         out_shape=jax.ShapeDtypeStruct((1, bins_padded), jnp.float32),
         interpret=interpret,
+        name="histogram",
     )(*operands)
     return out[0, :num_bins]

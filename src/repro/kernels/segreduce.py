@@ -164,5 +164,6 @@ def segment_max_pallas(
         out_specs=seg_spec,
         out_shape=jax.ShapeDtypeStruct((1, segs_padded), jnp.float32),
         interpret=interpret,
+        name="segmax",
     )(*operands)
     return out[0, :num_segments]

@@ -129,6 +129,7 @@ def cms_update_pallas(
         out_specs=pl.BlockSpec((depth, block_width), lambda i, k: (0, i)),
         out_shape=jax.ShapeDtypeStruct((depth, width_padded), dtype),
         interpret=interpret,
+        name="cms_update",
     )(ids_p, prop_p, init_p)
     return out[:, :width]
 

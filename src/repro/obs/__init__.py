@@ -4,7 +4,9 @@ Two halves, one record stream:
 
 * :mod:`repro.obs.trace` — nestable :func:`span`\\ s and point
   :func:`counter_event`\\ s in a bounded ring, exported as
-  schema-versioned JSONL stamped with git sha / backend / jax version.
+  schema-versioned JSONL stamped with git sha / backend / jax version;
+  once jax is imported, spans also annotate the profiler's host timeline
+  (``repro.<path>``) and JAX's compile steps arrive as ``jit.*`` counters.
 * :mod:`repro.obs.metrics` — a process-global registry of counters,
   gauges, and fixed-bucket histograms (p50/p99 without stored samples),
   exportable as BENCH JSON, JSONL records, or Prometheus text.
@@ -20,6 +22,7 @@ from .trace import (  # noqa: F401
     counter_event,
     export_jsonl,
     get_tracer,
+    jit_compile_count,
     read_jsonl,
     reset_tracer,
     run_context,
@@ -46,6 +49,7 @@ __all__ = [
     "run_context",
     "export_jsonl",
     "read_jsonl",
+    "jit_compile_count",
     "Counter",
     "Gauge",
     "Histogram",

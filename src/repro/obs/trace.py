@@ -21,6 +21,18 @@ are diffable without out-of-band notes.
 
 Dependency-free by design: stdlib only; jax is probed lazily and absent
 jax the backend stamp degrades to ``None`` instead of an import error.
+
+Once jax is imported, two hooks join the record stream (installed on the
+first span that opens after the import, never importing jax themselves):
+
+* every span also opens a ``jax.profiler.TraceAnnotation`` named
+  ``repro.<span path>``, so a profiler trace shows the program's own spans
+  on its host timeline, on the same clock as the device ops;
+* one ``jax.monitoring`` duration listener turns JAX's compile-path events
+  into counter records: ``jit.trace_s`` (Python tracing to a jaxpr),
+  ``jit.lower_s`` (jaxpr to an MLIR module) and ``jit.compile_s`` (backend
+  compile, or a persistent-cache load), each valued in seconds, recorded as
+  the step ends, and parented by the span open on the calling thread.
 """
 from __future__ import annotations
 
@@ -45,6 +57,7 @@ __all__ = [
     "run_context",
     "export_jsonl",
     "read_jsonl",
+    "jit_compile_count",
 ]
 
 SCHEMA_VERSION = 1
@@ -261,14 +274,74 @@ class _SpanContext:
         self._name = name
         self._attrs = attrs
         self.span: Optional[Span] = None
+        self._annotation = None
 
     def __enter__(self) -> Span:
         self.span = self._tracer.open_span(self._name, self._attrs)
+        annotate = _jax_hooks()
+        if annotate is not None:
+            self._annotation = annotate(f"repro.{self.span.path}")
+            self._annotation.__enter__()
         return self.span
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
         self._tracer.close_span(self.span, exc)
         return False  # never swallow
+
+
+# ---------------------------------------------------------------------------
+# jax hooks: profiler annotations and JIT counters
+# ---------------------------------------------------------------------------
+
+_JIT_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit.trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower_s",
+    "/jax/core/compile/backend_compile_duration": "jit.compile_s",
+}
+
+_HOOK_LOCK = threading.Lock()
+_ANNOTATION: Optional[Callable[[str], Any]] = None
+_JIT_COMPILES = 0
+
+
+def _on_jax_duration(event: str, secs: float, **kw: Any) -> None:
+    global _JIT_COMPILES
+    name = _JIT_EVENTS.get(event)
+    if name is None:
+        return
+    if name == "jit.compile_s":
+        _JIT_COMPILES += 1
+    try:
+        _GLOBAL.counter_event(name, secs, fun=kw.get("fun_name"))
+    except Exception:
+        pass  # telemetry must never fail a compile
+
+
+def _jax_hooks() -> Optional[Callable[[str], Any]]:
+    """``jax.profiler.TraceAnnotation`` once jax has been imported (None
+    before), installing the JIT listener with it, once per process."""
+    global _ANNOTATION
+    if _ANNOTATION is None and "jax" in sys.modules:
+        with _HOOK_LOCK:
+            if _ANNOTATION is None:
+                import jax.monitoring
+                import jax.profiler
+
+                jax.monitoring.register_event_duration_secs_listener(
+                    _on_jax_duration)
+                _ANNOTATION = jax.profiler.TraceAnnotation
+    return _ANNOTATION
+
+
+def jit_compile_count() -> int:
+    """Backend compiles (persistent-cache loads included) seen so far.
+
+    Installs the hooks when jax is imported; a caller that compares two
+    readings sees whether a compile happened in between."""
+    _jax_hooks()
+    return _JIT_COMPILES
 
 
 # ---------------------------------------------------------------------------

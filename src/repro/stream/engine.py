@@ -56,7 +56,8 @@ from ..data.faults import IngestHealth
 from ..data.pipeline import Prefetcher
 from ..data.plq import read_plq_chunks
 from ..kernels.ops import windowed_histogram
-from ..obs import get_registry
+from ..obs import get_registry, jit_compile_count
+from ..obs import span as obs_span
 from .state import StreamState, init_state
 
 __all__ = [
@@ -247,27 +248,30 @@ def _fold_dictionary_and_activity(
     Bins hash the ORIGINAL IP so independently built states merge by
     addition; the (lossy) sketch does not expose ids — see DESIGN.md §6.
     """
-    rows = jnp.arange(src.shape[0], dtype=jnp.int32)
-    bu = unique_concat(
-        src, dst, n_valid,
-        positions=jnp.concatenate([2 * rows, 2 * rows + 1]),
-        count_name=None,
-    )
-    known = isin(bu.keys[0], state.ip_values, state.n_ips,
-                 n_valid=bu.n_groups)
-    new = bu.mask() & ~known
-    dictionary = _merge_dictionary(
-        state.ip_values, state.ip_ids, state.n_ips,
-        bu.keys[0], new, bu.aggs["first_pos"],
-    )
-    act_ids = jnp.where(
-        valid, (mix32(src) % jnp.uint32(state.ip_bins)).astype(jnp.int32), -1
-    )
-    activity = windowed_histogram(
-        win, act_ids, state.n_windows, state.ip_bins,
-        weights=valid.astype(jnp.float32),
-        init=state.activity, backend=backend,
-    )
+    with jax.named_scope("dictionary"):
+        rows = jnp.arange(src.shape[0], dtype=jnp.int32)
+        bu = unique_concat(
+            src, dst, n_valid,
+            positions=jnp.concatenate([2 * rows, 2 * rows + 1]),
+            count_name=None,
+        )
+        known = isin(bu.keys[0], state.ip_values, state.n_ips,
+                     n_valid=bu.n_groups)
+        new = bu.mask() & ~known
+        dictionary = _merge_dictionary(
+            state.ip_values, state.ip_ids, state.n_ips,
+            bu.keys[0], new, bu.aggs["first_pos"],
+        )
+    with jax.named_scope("activity"):
+        act_ids = jnp.where(
+            valid, (mix32(src) % jnp.uint32(state.ip_bins)).astype(jnp.int32),
+            -1,
+        )
+        activity = windowed_histogram(
+            win, act_ids, state.n_windows, state.ip_bins,
+            weights=valid.astype(jnp.float32),
+            init=state.activity, backend=backend,
+        )
     return dictionary, activity
 
 
@@ -289,37 +293,43 @@ def update_state(
     costs one sort where the pre-CSR path (:func:`update_state_naive`)
     paid two.  Overflow (groups beyond ``link_capacity``) is counted by
     ``from_coo``, never silent.
+
+    Device scopes: ``update_state/dictionary``, ``/links``, ``/activity``.
     """
-    n_windows = state.n_windows
-    n_valid = jnp.asarray(n_valid, jnp.int32)
-    src = src.astype(jnp.int32)
-    dst = dst.astype(jnp.int32)
-    win = jnp.clip(win.astype(jnp.int32), 0, n_windows - 1)
-    t = Table(columns={"src": src, "dst": dst}, n_valid=n_valid)
-    valid = t.valid_mask()
+    with jax.named_scope("update_state"):
+        n_windows = state.n_windows
+        n_valid = jnp.asarray(n_valid, jnp.int32)
+        src = src.astype(jnp.int32)
+        dst = dst.astype(jnp.int32)
+        win = jnp.clip(win.astype(jnp.int32), 0, n_windows - 1)
+        t = Table(columns={"src": src, "dst": dst}, n_valid=n_valid)
+        valid = t.valid_mask()
 
-    (ip_values, ip_ids, n_ips, ov_ips), activity = _fold_dictionary_and_activity(
-        state, src, dst, win, valid, n_valid, backend
-    )
+        (ip_values, ip_ids, n_ips, ov_ips), activity = \
+            _fold_dictionary_and_activity(
+                state, src, dst, win, valid, n_valid, backend
+            )
 
-    links, ov_links = from_coo(
-        [jnp.concatenate([state.win, win]),
-         jnp.concatenate([state.src, src])],
-        jnp.concatenate([state.dst, dst]),
-        jnp.concatenate([state.packets, jnp.ones((src.shape[0],), jnp.int32)]),
-        valid_mask=jnp.concatenate([state.links.entry_mask(), valid]),
-        op="plus",
-        nnz_capacity=state.link_capacity,
-    )
+        with jax.named_scope("links"):
+            links, ov_links = from_coo(
+                [jnp.concatenate([state.win, win]),
+                 jnp.concatenate([state.src, src])],
+                jnp.concatenate([state.dst, dst]),
+                jnp.concatenate([state.packets,
+                                 jnp.ones((src.shape[0],), jnp.int32)]),
+                valid_mask=jnp.concatenate([state.links.entry_mask(), valid]),
+                op="plus",
+                nnz_capacity=state.link_capacity,
+            )
 
-    return StreamState(
-        ip_values=ip_values, ip_ids=ip_ids, n_ips=n_ips,
-        links=links,
-        activity=activity,
-        n_packets=state.n_packets + n_valid,
-        n_batches=state.n_batches + 1,
-        overflow=state.overflow + ov_ips + ov_links,
-    )
+        return StreamState(
+            ip_values=ip_values, ip_ids=ip_ids, n_ips=n_ips,
+            links=links,
+            activity=activity,
+            n_packets=state.n_packets + n_valid,
+            n_batches=state.n_batches + 1,
+            overflow=state.overflow + ov_ips + ov_links,
+        )
 
 
 def update_state_naive(
@@ -335,40 +345,46 @@ def update_state_naive(
     second concat group-by merging it into the accumulated flat link table
     (:func:`_merge_links`), then a pack into the CSR state layout.  Produces
     a bit-identical ``StreamState`` to :func:`update_state` — asserted by
-    tests/test_stream.py — at one extra sort per batch.
+    tests/test_stream.py — at one extra sort per batch.  Same device
+    scopes as :func:`update_state`.
     """
-    n_windows = state.n_windows
-    n_valid = jnp.asarray(n_valid, jnp.int32)
-    src = src.astype(jnp.int32)
-    dst = dst.astype(jnp.int32)
-    win = jnp.clip(win.astype(jnp.int32), 0, n_windows - 1)
-    t = Table(columns={"src": src, "dst": dst}, n_valid=n_valid)
-    valid = t.valid_mask()
+    with jax.named_scope("update_state"):
+        n_windows = state.n_windows
+        n_valid = jnp.asarray(n_valid, jnp.int32)
+        src = src.astype(jnp.int32)
+        dst = dst.astype(jnp.int32)
+        win = jnp.clip(win.astype(jnp.int32), 0, n_windows - 1)
+        t = Table(columns={"src": src, "dst": dst}, n_valid=n_valid)
+        valid = t.valid_mask()
 
-    (ip_values, ip_ids, n_ips, ov_ips), activity = _fold_dictionary_and_activity(
-        state, src, dst, win, valid, n_valid, backend
-    )
+        (ip_values, ip_ids, n_ips, ov_ips), activity = \
+            _fold_dictionary_and_activity(
+                state, src, dst, win, valid, n_valid, backend
+            )
 
-    bl = groupby_aggregate(
-        [win, src, dst],
-        {"packets": (jnp.ones((src.shape[0],), jnp.int32), "sum")},
-        n_valid=n_valid,
-        count_name=None,
-    )
-    w2, s2, d2, pk2, n_links, ov_links = _merge_links(
-        state, bl.keys, bl.aggs["packets"], bl.mask()
-    )
-    # pack the (already distinct, lex-sorted) flat table into the CSR layout
-    links, _ = from_coo([w2, s2], d2, pk2, n_valid=n_links, op="plus")
+        with jax.named_scope("links"):
+            bl = groupby_aggregate(
+                [win, src, dst],
+                {"packets": (jnp.ones((src.shape[0],), jnp.int32), "sum")},
+                n_valid=n_valid,
+                count_name=None,
+            )
+            w2, s2, d2, pk2, n_links, ov_links = _merge_links(
+                state, bl.keys, bl.aggs["packets"], bl.mask()
+            )
+            # pack the (already distinct, lex-sorted) flat table into the
+            # CSR layout
+            links, _ = from_coo([w2, s2], d2, pk2, n_valid=n_links,
+                                op="plus")
 
-    return StreamState(
-        ip_values=ip_values, ip_ids=ip_ids, n_ips=n_ips,
-        links=links,
-        activity=activity,
-        n_packets=state.n_packets + n_valid,
-        n_batches=state.n_batches + 1,
-        overflow=state.overflow + ov_ips + ov_links,
-    )
+        return StreamState(
+            ip_values=ip_values, ip_ids=ip_ids, n_ips=n_ips,
+            links=links,
+            activity=activity,
+            n_packets=state.n_packets + n_valid,
+            n_batches=state.n_batches + 1,
+            overflow=state.overflow + ov_ips + ov_links,
+        )
 
 
 def merge_states(a: StreamState, b: StreamState) -> StreamState:
@@ -439,8 +455,10 @@ def link_table(state: StreamState) -> Table:
 def _snapshot_results(
     state: StreamState, *, top_k: int, backend: str
 ) -> ChallengeResults:
+    with jax.named_scope("link_table"):
+        table = link_table(state)
     res = challenge_analyze(
-        link_table(state), n_windows=state.n_windows, ip_bins=state.ip_bins,
+        table, n_windows=state.n_windows, ip_bins=state.ip_bins,
         k=top_k, backend=backend,
     )
     # the accumulated activity (original-IP bins, mergeable) replaces the
@@ -506,7 +524,9 @@ class StreamSnapshot:
 class StreamBatchTimings:
     """Wall seconds of one ingest.  ``compile=True`` batches carry the
     trace+compile cost and are excluded from steady-state summaries —
-    the same protocol as ``ChallengePhaseTimings.compile_s``."""
+    the same protocol as ``ChallengePhaseTimings.compile_s``.  ``stream_plq``
+    sets it when JAX compiled or loaded a program during the batch
+    (``repro.obs.jit_compile_count``), so a warm engine has none."""
 
     n_packets: int
     prep_s: float        # host: cast + window slice + padding
@@ -732,35 +752,40 @@ class StreamEngine:
         only; raises on exchange overflow per the repo contract).
         """
         t0 = time.perf_counter()
-        state = self._state
-        results = None
-        if self.cfg.exact_enabled:
-            results = self._snap(state)
-            if distributed:
-                results = dataclasses.replace(
-                    results,
-                    scalars=distributed_scalar_queries(link_table(state)),
-                )
-            jax.block_until_ready(results)
-        sketch = None
-        if self._sketch_state is not None:
-            sketch = snapshot_sketch(self._sketch_state, k=self.cfg.top_k)
-        exact = self.cfg.exact_enabled
-        n_packets = int(state.n_packets) if exact \
-            else int(self._sketch_state.n_packets)
-        n_batches = int(state.n_batches) if exact \
-            else int(self._sketch_state.n_batches)
-        snap = StreamSnapshot(
-            results=results,
-            n_packets=n_packets,
-            n_batches=n_batches,
-            n_links=int(state.n_links) if exact else None,
-            n_ips=int(state.n_ips) if exact else None,
-            overflow=int(state.overflow) if exact else None,
-            sketch=sketch,
-            tier=self.cfg.tier,
-            health=dataclasses.replace(self.health),
-        )
+        with obs_span("snapshot", tier=self.cfg.tier):
+            state = self._state
+            results = None
+            if self.cfg.exact_enabled:
+                with obs_span("exact"):
+                    results = self._snap(state)
+                    if distributed:
+                        results = dataclasses.replace(
+                            results,
+                            scalars=distributed_scalar_queries(
+                                link_table(state)),
+                        )
+                    jax.block_until_ready(results)
+            sketch = None
+            if self._sketch_state is not None:
+                with obs_span("sketch"):
+                    sketch = snapshot_sketch(self._sketch_state,
+                                             k=self.cfg.top_k)
+            exact = self.cfg.exact_enabled
+            n_packets = int(state.n_packets) if exact \
+                else int(self._sketch_state.n_packets)
+            n_batches = int(state.n_batches) if exact \
+                else int(self._sketch_state.n_batches)
+            snap = StreamSnapshot(
+                results=results,
+                n_packets=n_packets,
+                n_batches=n_batches,
+                n_links=int(state.n_links) if exact else None,
+                n_ips=int(state.n_ips) if exact else None,
+                overflow=int(state.overflow) if exact else None,
+                sketch=sketch,
+                tier=self.cfg.tier,
+                health=dataclasses.replace(self.health),
+            )
         # snapshot time is the one spot that already forces a device sync,
         # so mirroring engine + ingest-health facts into the registry here
         # costs no extra block_until_ready on the hot ingest path
@@ -834,46 +859,47 @@ def stream_plq(
     ``time_phases=True`` blocks after transfer and update to attribute wall
     time per phase (accurate phases, no overlap); the default overlapped
     mode records dispatch walls only and is the throughput measurement —
-    see docs/METHODOLOGY.md.
+    see docs/METHODOLOGY.md.  The whole pass, up to its final
+    ``engine.block()``, is the ``stream.pass`` span.
     """
     cap = engine.cfg.batch_capacity
     timings: List[StreamBatchTimings] = []
     off = 0
-    for i, chunk in enumerate(Prefetcher(read_plq_chunks(path, list(columns)),
-                                         depth=depth)):
-        t_start = time.perf_counter()
-        n = len(chunk[columns[0]])
-        if n > cap:
-            raise ValueError(
-                f"row group {i} has {n} rows > batch_capacity {cap}; "
-                f"rewrite the capture with row_group_size <= {cap}"
+    with obs_span("stream.pass", tier=engine.cfg.tier):
+        for i, chunk in enumerate(Prefetcher(
+                read_plq_chunks(path, list(columns)), depth=depth)):
+            t_start = time.perf_counter()
+            compiles = jit_compile_count()
+            n = len(chunk[columns[0]])
+            if n > cap:
+                raise ValueError(
+                    f"row group {i} has {n} rows > batch_capacity {cap}; "
+                    f"rewrite the capture with row_group_size <= {cap}"
+                )
+            pad = lambda a: np.concatenate(
+                [np.asarray(a, np.int32), np.zeros(cap - len(a), np.int32)]
             )
-        pad = lambda a: np.concatenate(
-            [np.asarray(a, np.int32), np.zeros(cap - len(a), np.int32)]
-        )
-        src = pad(chunk["src"])
-        dst = pad(chunk["dst"])
-        win = pad(win_full[off:off + n])
-        off += n
-        t1 = time.perf_counter()
-        dev_src, dev_dst, dev_win = jax.device_put((src, dst, win))
-        if time_phases:
-            jax.block_until_ready((dev_src, dev_dst, dev_win))
-        t2 = time.perf_counter()
-        engine.ingest_padded(dev_src, dev_dst, dev_win, n)
-        if time_phases:
-            engine.block()
-        t3 = time.perf_counter()
-        timings.append(StreamBatchTimings(
-            n_packets=n, prep_s=t1 - t_start, transfer_s=t2 - t1,
-            update_s=t3 - t2, total_s=t3 - t_start, compile=(i == 0),
-        ))
-        if i > 0:  # steady-state only: the compile batch would skew p99
-            get_registry().histogram(
-                "stream_batch_seconds",
-                "steady-state wall seconds per ingested micro-batch",
-            ).observe(t3 - t_start)
-        if on_batch is not None:
-            on_batch(i, engine)
-    engine.block()
+            src = pad(chunk["src"])
+            dst = pad(chunk["dst"])
+            win = pad(win_full[off:off + n])
+            off += n
+            t1 = time.perf_counter()
+            dev_src, dev_dst, dev_win = jax.device_put((src, dst, win))
+            if time_phases:
+                jax.block_until_ready((dev_src, dev_dst, dev_win))
+            t2 = time.perf_counter()
+            engine.ingest_padded(dev_src, dev_dst, dev_win, n)
+            if time_phases:
+                engine.block()
+            t3 = time.perf_counter()
+            # a batch is a compile batch iff JAX compiled (or loaded from the
+            # persistent cache) a program while it ran
+            timings.append(StreamBatchTimings(
+                n_packets=n, prep_s=t1 - t_start, transfer_s=t2 - t1,
+                update_s=t3 - t2, total_s=t3 - t_start,
+                compile=jit_compile_count() > compiles,
+            ))
+            if on_batch is not None:
+                on_batch(i, engine)
+        engine.block()
     return timings

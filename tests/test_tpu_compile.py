@@ -12,6 +12,8 @@ The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
 imports this file.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -102,3 +104,24 @@ def test_hll_update_compiles(spec):
         spec((CMS_BATCH,), jnp.int32), spec((CMS_BATCH,), jnp.int32),
     )
     assert "tpu_custom_call" in text
+
+
+# each kernel's custom call carries its ``name=``, which is the op's name
+# in a device trace (``__unknown_`` without one)
+@pytest.mark.parametrize("name", ["histogram", "segmax", "cms_update"])
+def test_kernel_custom_call_is_named(spec, name):
+    n = 1 << 16
+    fn, args = {
+        "histogram": (functools.partial(histogram_pallas, num_bins=256),
+                      [spec((n,), jnp.int32)]),
+        "segmax": (functools.partial(segment_max_pallas, num_segments=256),
+                   [spec((n,), jnp.float32), spec((n,), jnp.int32)]),
+        "cms_update": (cms_update_pallas,
+                       [spec((CMS_DEPTH, CMS_WIDTH), jnp.int32),
+                        spec((CMS_DEPTH, n), jnp.int32),
+                        spec((n,), jnp.int32)]),
+    }[name]
+    calls = [line.split("=", 1)[0].split()[-1]
+             for line in _compiled_text(fn, *args).splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert calls and all(c.startswith(f"%{name}.") for c in calls)
