@@ -23,7 +23,9 @@ mirrors the paper's per-phase tables); ``fused=True`` additionally compiles
 build->anonymize->analyze into ONE jitted, buffer-donated program — the
 "whole workload is one XLA computation" measurement no per-phase timing can
 see.  ``distributed=True`` runs the scalar suite via shard_map
-(dist/relational.py) over all local devices.
+(dist/relational.py) over all local devices.  Each phase program is built
+once per process, keyed by the config's statics, so a repeat run of a config
+traces and compiles nothing.
 """
 from __future__ import annotations
 
@@ -150,7 +152,9 @@ class ChallengePhaseTimings:
     fused_s: Optional[float] = None      # one-program build+anonymize+analyze
     compile_s: Optional[float] = None    # warm pass (trace+compile+first run)
                                          # excluded from the phase walls when
-                                         # ChallengeConfig.warm is set
+                                         # ChallengeConfig.warm is set; a
+                                         # config already run in the process
+                                         # reads a cache hit here
 
     @property
     def total_s(self) -> float:
@@ -691,6 +695,33 @@ def _dispatch_and_sync(fn, *args):
         return _block(out)
 
 
+def _build(s, d, wn, nv):
+    with jax.named_scope("build_table"):
+        table = build_table(s, d, wn, nv)  # build once; A_t groups it
+    with jax.named_scope("traffic_matrix"):
+        return table, traffic_matrix(table)
+
+
+def _fused(s, d, wn, nv, k_, *, method, rounds, **kw):
+    with jax.named_scope("build_table"):
+        t = build_table(s, d, wn, nv)
+    return analyze(anonymize(t, k_, method=method, rounds=rounds).table, **kw)
+
+
+# Phase programs are built once per process, keyed by the phase function and
+# the static arguments that shape its trace; array shapes are left to each
+# jitted callable's own cache.  A jax.jit made per call misses JAX's
+# in-memory executable cache, which is keyed by function identity, so every
+# pass would trace, lower and load each program again (the stream engine
+# caches its programs the same way).  Keying by the function as well gives a
+# replaced ``anonymize`` or ``analyze`` a program of its own.
+
+@functools.lru_cache(maxsize=None)
+def _jitted(fn, donate_argnums=(), **statics):
+    return jax.jit(functools.partial(fn, **statics),
+                   donate_argnums=donate_argnums)
+
+
 def run_challenge(
     cfg: ChallengeConfig, key: Optional[jax.Array] = None
 ) -> ChallengeRun:
@@ -704,18 +735,9 @@ def run_challenge(
     kw = dict(n_windows=cfg.n_windows, ip_bins=cfg.ip_bins, k=cfg.top_k,
               backend=cfg.backend, fused_epilogue=cfg.fused_epilogue,
               algorithms=cfg.algorithms, bfs_source=cfg.bfs_source)
-
-    def _build(s, d, wn, nv):
-        with jax.named_scope("build_table"):
-            table = build_table(s, d, wn, nv)  # build once; A_t groups it
-        with jax.named_scope("traffic_matrix"):
-            return table, traffic_matrix(table)
-
-    build_fn = jax.jit(_build)
-    anon_fn = jax.jit(
-        lambda t, k_: anonymize(t, k_, method=cfg.method, rounds=cfg.rounds)
-    )
-    analyze_fn = jax.jit(lambda t: analyze(t, **kw))
+    build_fn = _jitted(_build)
+    anon_fn = _jitted(anonymize, method=cfg.method, rounds=cfg.rounds)
+    analyze_fn = _jitted(analyze, **kw)
 
     # Phase timing is span-based (obs/trace.py): each wall below is a span's
     # duration over the same perf_counter clock the old inline timers used,
@@ -735,7 +757,8 @@ def run_challenge(
 
         # ---- warm pass: trace + compile every phase so the timed walls
         # below measure steady-state execution, matching the paper's
-        # protocol of excluding one-time costs (recorded as compile_s) ----
+        # protocol of excluding one-time costs (recorded as compile_s; a
+        # config already run in this process finds its programs cached) ----
         sp_compile = None
         if cfg.warm:
             with obs_span("compile") as sp_compile:
@@ -786,18 +809,10 @@ def run_challenge(
 
 def _time_fused(cfg, src, dst, win, n, key, kw) -> float:
     """build+anonymize+analyze as ONE jitted, buffer-donated program."""
-
-    def fused(s, d, wn, nv, k_):
-        with jax.named_scope("build_table"):
-            t = build_table(s, d, wn, nv)
-        return analyze(
-            anonymize(t, k_, method=cfg.method, rounds=cfg.rounds).table, **kw
-        )
-
     # donating the column buffers lets XLA reuse them for the sort scratch;
     # CPU ignores donation, so only request it off-CPU (avoids the warning).
     donate = (0, 1, 2) if jax.default_backend() != "cpu" else ()
-    fn = jax.jit(fused, donate_argnums=donate)
+    fn = _jitted(_fused, donate, method=cfg.method, rounds=cfg.rounds, **kw)
     _block(fn(src, dst, win, n, key))  # compile + warm
     src2, dst2, win2 = np.copy(src), np.copy(dst), np.copy(win)
     with obs_span("fused") as sp:

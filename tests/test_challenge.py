@@ -233,6 +233,59 @@ def test_challenge_read_cache_reuses_capture(tmp_path):
             int(getattr(run2.results.scalars, k)), k
 
 
+# one case per static of the phase programs' cache key: (base config, the
+# static changed, the phase whose program that static shapes)
+_STATIC_CASES = {
+    "method": ({}, {"method": "hash"}, "anonymize"),
+    "rounds": ({}, {"rounds": 2}, "anonymize"),
+    "n_windows": ({}, {"n_windows": 2}, "analyze"),
+    "ip_bins": ({}, {"ip_bins": 32}, "analyze"),
+    "top_k": ({}, {"top_k": 3}, "analyze"),
+    "backend": ({}, {"backend": "xla"}, "analyze"),
+    "fused_epilogue": ({}, {"fused_epilogue": True}, "analyze"),
+    "algorithms": ({}, {"algorithms": True}, "analyze"),
+    "bfs_source": ({"algorithms": True}, {"bfs_source": 5}, "analyze"),
+}
+
+
+@pytest.mark.parametrize("static", list(_STATIC_CASES))
+def test_phase_program_cache_keys_every_static(tmp_path, static):
+    """A run with one static changed after a run with it at its default
+    builds the changed phase's program anew and answers as ``anonymize``
+    and ``analyze`` jitted afresh with that static."""
+    from repro.core.anonymize import anonymize
+    from repro.obs import get_tracer, reset_tracer
+
+    base, change, phase = _STATIC_CASES[static]
+    # a capacity no other test compiles, so the changed program is new here
+    common = dict(scale=8, n_packets=256, capacity=256 + 19, n_windows=3,
+                  ip_bins=64, top_k=5, warm=False, workdir=str(tmp_path))
+    run_challenge(ChallengeConfig(**{**common, **base}))
+    cfg = ChallengeConfig(**{**common, **base, **change})
+    reset_tracer()
+    run = run_challenge(cfg)
+    traced = {r["parent"] for r in get_tracer().records()
+              if r["kind"] == "counter" and r["name"] == "jit.trace_s"}
+    reset_tracer()
+    assert f"challenge/{phase}/dispatch" in traced
+    assert not {f"challenge/{p}/dispatch"
+                for p in ("build_device", "anonymize", "analyze")
+                if p != phase} & traced
+
+    src, dst, win, n = build_columns(run.capture, cfg)
+    anon = jax.jit(lambda t, k: anonymize(
+        t, k, method=cfg.method, rounds=cfg.rounds))(
+        build_table(src, dst, win, n), jax.random.key(cfg.seed))
+    want = jax.jit(lambda t: analyze(
+        t, n_windows=cfg.n_windows, ip_bins=cfg.ip_bins, k=cfg.top_k,
+        backend=cfg.backend, fused_epilogue=cfg.fused_epilogue,
+        algorithms=cfg.algorithms, bfs_source=cfg.bfs_source))(anon.table)
+    for got, ref in ((run.anon_table, anon.table), (run.results, want)):
+        assert jax.tree.structure(got) == jax.tree.structure(ref)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_analyze_is_one_jittable_call():
     rng = np.random.default_rng(11)
     n, cap = 500, 512

@@ -6,6 +6,7 @@ kernels run in interpret mode there, and their ``name=`` becomes a scope
 of the ops that emulate them.  The annotations and counters are read from a
 CPU profiler trace and the ``repro.obs`` tracer.
 """
+import dataclasses
 import glob
 import re
 import time
@@ -214,6 +215,27 @@ def test_run_challenge_phases_have_dispatch_and_sync(tmp_path):
     for phase in ("build_device", "anonymize", "analyze"):
         assert {f"challenge/{phase}/dispatch",
                 f"challenge/{phase}/sync"} <= paths
+
+
+def test_run_challenge_builds_its_programs_once(tmp_path):
+    from repro.challenge import ChallengeConfig, run_challenge
+
+    cfg = ChallengeConfig(scale=8, n_packets=256, warm=False,
+                          workdir=str(tmp_path))
+    first = run_challenge(cfg)
+    reset_tracer()
+    before = jit_compile_count()
+    second = run_challenge(cfg)
+    assert jit_compile_count() == before
+    assert not [r for r in get_tracer().records()
+                if r["kind"] == "counter" and r["name"].startswith("jit.")
+                and (r["parent"] or "").startswith("challenge")]
+    for f in dataclasses.fields(first.results):
+        a, b = getattr(first.results, f.name), getattr(second.results, f.name)
+        assert jax.tree.structure(a) == jax.tree.structure(b), f.name
+        for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
+                                          err_msg=f.name)
 
 
 # ------------------------------------------------- profiler annotations
