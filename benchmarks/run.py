@@ -22,7 +22,7 @@ section likewise writes ``--graphblas-json`` (default
 dense-grid vs CSR A/B with the compiled peak-HBM estimate (DESIGN.md §2.4).
 The algorithms section writes ``--algorithms-json`` (default
 ``BENCH_algorithms.json``): per-algorithm walls with oracle-parity flags
-plus the analyze(algorithms=True) HLO sort count (DESIGN.md §2.5).
+plus the graph phase's HLO sort count (DESIGN.md §2.5).
 
 The serve section writes ``--serve-json`` (default ``BENCH_serve.json``):
 checkpoint/restore/replay walls with the recovered-vs-uninterrupted

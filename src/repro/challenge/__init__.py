@@ -2,11 +2,13 @@
 
 Runs the full paper pipeline (read -> build -> anonymize -> analyze) as
 timed phases over one static-shape table, with an optional single-program
-fused path and a shard_map scalar path.  CLI:
+fused path, a shard_map scalar path, and a graph phase (BFS, WCC, PageRank,
+triangles) chosen by ``ChallengeConfig.analytics``.  CLI:
 
     PYTHONPATH=src python -m repro.challenge.run --scale 14
 """
 from .pipeline import (
+    Analytics,
     ChallengeConfig,
     ChallengePhaseTimings,
     ChallengeResults,
@@ -18,6 +20,7 @@ from .pipeline import (
 )
 
 __all__ = [
+    "Analytics",
     "ChallengeConfig",
     "ChallengePhaseTimings",
     "ChallengeResults",

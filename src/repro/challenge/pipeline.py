@@ -16,7 +16,13 @@ is that orchestrator:
              multi-temporal windowed suite, cross-window IP overlap
              (semi-join), top-k heaviest links, and a per-window source
              activity histogram batched through the Pallas histogram kernel
-             in one dispatch (kernels/ops.windowed_histogram).
+             in one dispatch (kernels/ops.windowed_histogram);
+  graph      the graph kernels (BFS, WCC, PageRank, triangles) over the
+             anonymized traffic graph: one plan program builds the CSR
+             pair, then each selected kernel runs as its own program.
+
+``ChallengeConfig.analytics`` picks what follows anonymize: the Table III
+suite (``analyze``, the default), graph kernels, or both.
 
 Each phase is timed with ``block_until_ready`` walls (`ChallengePhaseTimings`
 mirrors the paper's per-phase tables); ``fused=True`` additionally compiles
@@ -40,8 +46,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import algorithms as algo
 from ..core.anonymize import anonymize
-from ..obs import span as obs_span
+from ..obs import counter_event, span as obs_span
 from ..core.ops import factorize, groupby_aggregate, mix32, semi_join, unique
 from ..core.plan import lead_fanout, lead_groups, link_groups, unique_lead
 from ..core.queries import (
@@ -50,6 +57,7 @@ from ..core.queries import (
     packet_weights,
     run_all_queries_naive,
     scalar_queries_from_plans,
+    table_csrs,
     table_plans,
     top_links,
     top_links_from_plan,
@@ -64,25 +72,85 @@ from ..data.rmat import synthetic_packets
 from ..kernels.ops import histogram, windowed_histogram
 
 __all__ = [
+    "Analytics",
     "ChallengeConfig",
     "ChallengePhaseTimings",
     "ChallengeResults",
     "ChallengeRun",
+    "GRAPH_KERNELS",
+    "GraphRun",
+    "TrafficGraph",
     "cross_window_ip_overlap",
     "cross_window_ip_overlap_naive",
     "analyze",
     "analyze_peak_buffer_bytes",
     "distributed_scalar_queries",
+    "graph_kernel",
+    "graph_plan",
+    "graph_vertices",
     "run_challenge",
     "timings_from_spans",
 ]
 
-PHASES = ("read", "build", "anonymize", "analyze")
+PHASES = ("read", "build", "anonymize", "analyze", "graph")
+
+#: the graph kernels a pass can run, in the order it runs them
+GRAPH_KERNELS = ("bfs", "wcc", "pagerank", "triangles")
+
+#: BFS levels and WCC labels ride float32 carriers (core/algorithms.py),
+#: exact for vertex ids below 2**24
+MAX_GRAPH_VERTICES = 1 << 24
 
 
 # ---------------------------------------------------------------------------
 # configuration
 # ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class Analytics:
+    """What a pass computes from the anonymized table.
+
+    ``queries`` runs the Table III suite (the ``analyze`` phase);
+    ``kernels`` names the graph kernels of the ``graph`` phase, from
+    :data:`GRAPH_KERNELS`.  ``bfs_source`` is an anonymized vertex id, or
+    None for the anonymized source of the capture's first packet, a vertex
+    with at least one out-link that changes with the capture.  Either way
+    it reaches the BFS program as an operand, so a new source compiles
+    nothing.  PageRank splits rank over out-links by packet count
+    (``weighted``) or evenly over distinct out-links (Graphalytics); it
+    runs :func:`repro.core.algorithms.pagerank`'s damping 0.85 and stopping
+    rule (an L1 change below 1e-6, at most 100 steps).
+    """
+
+    queries: bool = True
+    kernels: Tuple[str, ...] = ()
+    bfs_source: Optional[int] = None
+    weighted: bool = True
+
+    def __post_init__(self):
+        unknown = [k for k in self.kernels if k not in GRAPH_KERNELS]
+        if unknown or len(set(self.kernels)) != len(self.kernels):
+            raise ValueError(f"graph kernels must be distinct names from "
+                             f"{GRAPH_KERNELS}, got {self.kernels}")
+        if not self.queries and not self.kernels:
+            raise ValueError("analytics selects nothing: enable the queries "
+                             "or name at least one graph kernel")
+
+
+def graph_vertices(capacity: int) -> int:
+    """The graph phase's static vertex domain for a table of ``capacity``
+    rows: anonymized ids are below the distinct-IP count, which both
+    endpoints of every row bound by ``2 * capacity``.  Refuses a domain
+    past :data:`MAX_GRAPH_VERTICES`, where the float32 level and label
+    carriers stop being exact."""
+    n = 2 * capacity
+    if n > MAX_GRAPH_VERTICES:
+        raise ValueError(
+            f"the graph kernels' vertex domain 2 * capacity = {n} exceeds "
+            f"2**24: BFS levels and WCC labels ride float32 and would no "
+            f"longer be exact; use a table capacity of at most 2**23")
+    return n
+
 
 @dataclasses.dataclass(frozen=True)
 class ChallengeConfig:
@@ -108,8 +176,7 @@ class ChallengeConfig:
     fused: bool = False                  # also time the one-program path
     fused_epilogue: bool = False         # fused kernel epilogues in analyze
     distributed: bool = False            # scalar suite via shard_map
-    algorithms: bool = False             # BFS/CC/PageRank/triangles pass
-    bfs_source: int = 0                  # BFS source (anonymized vertex id)
+    analytics: Analytics = Analytics()   # Table III suite and/or graph kernels
     workdir: Optional[str] = None        # capture cache dir (tmp if None)
 
     def __post_init__(self):
@@ -119,6 +186,9 @@ class ChallengeConfig:
         for field in ("n_windows", "ip_bins", "top_k"):
             if getattr(self, field) < 1:
                 raise ValueError(f"{field} must be >= 1")
+        if (self.fused or self.distributed) and not self.analytics.queries:
+            raise ValueError("fused and distributed run the Table III "
+                             "queries; enable analytics.queries")
 
     @property
     def packets(self) -> int:
@@ -148,7 +218,8 @@ class ChallengePhaseTimings:
     read_s: float
     build_s: float
     anonymize_s: float
-    analyze_s: float
+    analyze_s: Optional[float]           # None when the queries are off
+    graph_s: Optional[float] = None      # None when no graph kernel runs
     fused_s: Optional[float] = None      # one-program build+anonymize+analyze
     compile_s: Optional[float] = None    # warm pass (trace+compile+first run)
                                          # excluded from the phase walls when
@@ -156,16 +227,21 @@ class ChallengePhaseTimings:
                                          # config already run in the process
                                          # reads a cache hit here
 
+    def phases(self) -> Dict[str, float]:
+        """Wall seconds of each phase that ran, in run order."""
+        walls = {p: getattr(self, f"{p}_s") for p in PHASES}
+        return {p: s for p, s in walls.items() if s is not None}
+
     @property
     def total_s(self) -> float:
-        return self.read_s + self.build_s + self.anonymize_s + self.analyze_s
+        return sum(self.phases().values())
 
     def packets_per_s(self, phase: str = "total") -> float:
         s = self.total_s if phase == "total" else getattr(self, f"{phase}_s")
         return self.n_packets / s if s and s > 0 else float("inf")
 
     def as_dict(self) -> Dict[str, float]:
-        d = {f"{p}_s": getattr(self, f"{p}_s") for p in PHASES}
+        d = {f"{p}_s": s for p, s in self.phases().items()}
         d["total_s"] = self.total_s
         if self.fused_s is not None:
             d["fused_s"] = self.fused_s
@@ -175,8 +251,7 @@ class ChallengePhaseTimings:
 
     def format_table(self) -> str:
         rows = [f"{'phase':12s}{'seconds':>12s}{'packets/sec':>16s}"]
-        for p in PHASES:
-            s = getattr(self, f"{p}_s")
+        for p, s in self.phases().items():
             rows.append(f"{p:12s}{s:12.4f}{self.n_packets / max(s, 1e-12):16,.0f}")
         rows.append(
             f"{'total':12s}{self.total_s:12.4f}"
@@ -199,7 +274,8 @@ def timings_from_spans(records) -> ChallengePhaseTimings:
     The inverse of the span wiring in :func:`run_challenge`: given the
     record dicts of one telemetry export (``repro.obs.read_jsonl`` output,
     or ``get_tracer().records()`` directly), find the LAST completed
-    ``challenge`` span group and reassemble the phase walls.  Because both
+    ``challenge`` span group and reassemble the phase walls (``analyze``
+    and ``graph`` each None where the run had no such phase).  Because both
     the live dataclass and this replay read the very same span durations —
     and JSON serializes floats via shortest-round-trip repr — the result is
     bit-identical to the ``ChallengeRun.timings`` of that run (asserted in
@@ -218,18 +294,20 @@ def timings_from_spans(records) -> ChallengePhaseTimings:
     if last is None:
         raise ValueError("no completed 'challenge' span group in records")
     missing = [p for p in ("read", "build_host", "build_device",
-                           "anonymize", "analyze") if p not in last]
-    if missing:
-        raise ValueError(f"challenge span group incomplete: missing {missing}")
-    dur = lambda name: last[name]["duration_s"]
+                           "anonymize") if p not in last]
+    if missing or ("analyze" not in last and "graph" not in last):
+        raise ValueError(f"challenge span group incomplete: missing "
+                         f"{missing or ['analyze or graph']}")
+    dur = lambda name: last[name]["duration_s"] if name in last else None
     return ChallengePhaseTimings(
         n_packets=int(last["challenge"]["attrs"]["n_packets"]),
         read_s=dur("read"),
         build_s=dur("build_host") + dur("build_device"),
         anonymize_s=dur("anonymize"),
         analyze_s=dur("analyze"),
-        fused_s=dur("fused") if "fused" in last else None,
-        compile_s=dur("compile") if "compile" in last else None,
+        graph_s=dur("graph"),
+        fused_s=dur("fused"),
+        compile_s=dur("compile"),
     )
 
 
@@ -246,11 +324,7 @@ class ChallengeResults:
     values), ``per_source``/``per_destination`` (Q6/Q11) and
     ``source_fanout``/``destination_fanin`` (Q8/Q13).  Beyond Table III:
     per-window statistics, the batched per-window activity histogram, the
-    cross-window IP overlap and the k heaviest links.  ``algorithms`` is
-    the optional iterative-algorithm pass (``analyze(algorithms=True)``):
-    a :class:`repro.core.algorithms.AlgorithmResults` bundle over the
-    anonymized traffic graph, or None when the pass is off (None is a
-    valid empty pytree subtree, so the dataclass jits either way).
+    cross-window IP overlap and the k heaviest links.
     """
 
     scalars: QueryResults
@@ -265,7 +339,6 @@ class ChallengeResults:
     windowed: Dict[str, jnp.ndarray]
     window_activity: jnp.ndarray      # (n_windows, ip_bins) float32
     window_ip_overlap: jnp.ndarray    # (n_windows,) int32
-    algorithms: object = None         # AlgorithmResults | None
 
 
 jax.tree_util.register_dataclass(
@@ -276,24 +349,35 @@ jax.tree_util.register_dataclass(
 
 
 @dataclasses.dataclass
+class GraphRun:
+    """What the graph phase produced: each selected kernel's result by
+    name (``bfs``: :class:`~repro.core.algorithms.BfsResult`, ``wcc``:
+    ``ComponentsResult``, ``pagerank``: ``PageRankResult``, ``triangles``:
+    ``TriangleResult``), the live vertex count (anonymized ids are
+    ``[0, n_live)``) and the BFS source used, as scalars."""
+
+    kernels: Dict[str, object]
+    n_live: jax.Array
+    source: jax.Array
+
+
+@dataclasses.dataclass
 class ChallengeRun:
     """A finished run: device results + timings + the host capture columns.
 
-    ``anon_table`` is the anonymized device table ``analyze`` ran on, so a
-    caller can re-run analyze on exactly that input (chip_smoke.py compares
-    kernel backends on it).  ``anon_columns`` (populated when
-    ``config.algorithms`` is set) holds
-    host copies of the anonymized src/dst live prefix — the exact edge
-    list the algorithm pass ran on, so the NumPy oracles can replay it
-    directly in the anonymized-id domain (challenge/run.py --verify).
+    ``results`` is None when the queries are off, ``graph`` None when no
+    graph kernel ran.  ``anon_table`` is the anonymized device table both
+    phases ran on, so a caller can re-run analyze on exactly that input
+    (chip_smoke.py compares kernel backends on it) and whose live prefix
+    is the edge list the NumPy oracles replay (challenge/run.py --verify).
     """
 
-    results: ChallengeResults
+    results: Optional[ChallengeResults]
     timings: ChallengePhaseTimings
     capture: Dict[str, np.ndarray]
     config: ChallengeConfig
-    anon_columns: Optional[Dict[str, np.ndarray]] = None
     anon_table: Optional[Table] = None
+    graph: Optional[GraphRun] = None
 
 
 # ---------------------------------------------------------------------------
@@ -478,8 +562,7 @@ def analyze(
     use_plan: bool = True,
     windowed_method: str = "csr",
     fused_epilogue: bool = False,
-    algorithms: bool = False,
-    bfs_source: int = 0,
+    plans=None,
 ) -> ChallengeResults:
     """Every challenge statistic in one jit-able call.
 
@@ -504,19 +587,12 @@ def analyze(
     path (which stays the A/B baseline), same 3-sort budget; requires the
     CSR windowed method.
 
-    ``algorithms=True`` adds the iterative pass (DESIGN.md §2.5): BFS
-    levels from ``bfs_source``, connected components, PageRank and
-    triangle counts over the anonymized traffic graph.  The pass runs off
-    the zero-sort CSR pair of the two plans (components reuses the
-    dst-keyed CSR as its transpose), so the THREE-sort budget holds with
-    it enabled — asserted alongside the base budget in tests.
+    ``plans`` is the plan pair of ``t`` when the caller already holds it
+    (:func:`_analyze_and_plans`); the plan path then sorts only for
+    ``unique_ips``.  The graph kernels are a phase of their own
+    (:func:`graph_plan`, :func:`graph_kernel`), not part of this program.
     """
     if not use_plan:
-        if algorithms:
-            raise ValueError(
-                "algorithms=True requires the plan path (use_plan=True): "
-                "the pass is defined off the plan's zero-sort CSR pair"
-            )
         if fused_epilogue:
             raise ValueError(
                 "fused_epilogue=True requires the plan path (use_plan=True):"
@@ -529,7 +605,7 @@ def analyze(
     # (analyze/plan, analyze/groups, ...) and changes no computation
     with jax.named_scope("analyze"):
         with jax.named_scope("plan"):
-            plans = table_plans(t)
+            plans = table_plans(t) if plans is None else plans
             plan_src, plan_dst = plans
             ips = unique_ips(t)
         with jax.named_scope("groups"):
@@ -538,21 +614,6 @@ def analyze(
             per_dst = lead_groups(plan_dst)
             fanout = lead_fanout(plan_src)
             fanin = lead_fanout(plan_dst)
-
-        algo = None
-        if algorithms:
-            from ..core.algorithms import graph_algorithms
-            from ..core.queries import table_csrs
-
-            with jax.named_scope("algorithms"):
-                csr_src, csr_dst = table_csrs(t, plans)
-                # static vertex domain: anonymized ids are < n_unique_ips,
-                # which is bounded by both endpoints of every packet row
-                # -> 2 * capacity
-                algo = graph_algorithms(
-                    csr_src, csr_dst, 2 * t.capacity,
-                    n_live=ips.n_unique, source=bfs_source, backend=backend,
-                )
 
         with jax.named_scope("scalars"):
             scalars = scalar_queries_from_plans(
@@ -579,7 +640,6 @@ def analyze(
             )
 
     return ChallengeResults(
-        algorithms=algo,
         scalars=scalars,
         links=links,
         per_source=per_src,
@@ -677,6 +737,87 @@ def analyze_peak_buffer_bytes(
 
 
 # ---------------------------------------------------------------------------
+# phase: graph
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class TrafficGraph:
+    """The anonymized traffic graph the graph kernels run on.
+
+    ``csr_src`` is A (rows by source: out-links, weighted by packets),
+    ``csr_dst`` its transpose, ``keys_src``/``keys_dst`` their entries' row
+    keys (``entry_row_key(0)``, a binary search each kernel step would
+    repeat otherwise); ``n_live`` counts the vertices (ids are
+    ``[0, n_live)``); ``source`` is the BFS source."""
+
+    csr_src: object
+    csr_dst: object
+    keys_src: jnp.ndarray
+    keys_dst: jnp.ndarray
+    n_live: jnp.ndarray
+    source: jnp.ndarray
+
+
+jax.tree_util.register_dataclass(
+    TrafficGraph,
+    data_fields=[f.name for f in dataclasses.fields(TrafficGraph)],
+    meta_fields=[],
+)
+
+
+def graph_plan(t: Table, n_live, source=-1, plans=None) -> TrafficGraph:
+    """The CSR pair of the anonymized table, off the two plan sorts and
+    nothing else (``n_live`` is the anonymization's distinct-IP count), or
+    off ``plans``, the pair ``analyze`` already sorted, with no sort at
+    all.  ``source`` is the BFS source, or -1 for the source of the table's
+    first row (the capture's first packet)."""
+    with jax.named_scope("graph"):
+        if plans is None:
+            with jax.named_scope("plan"):
+                plans = table_plans(t)
+        with jax.named_scope("csr"):
+            csr_src, csr_dst = table_csrs(t, plans)
+            keys_src = csr_src.entry_row_key(0)
+            keys_dst = csr_dst.entry_row_key(0)
+    source = jnp.where(source < 0, t["src"][0], source)
+    return TrafficGraph(csr_src=csr_src, csr_dst=csr_dst, keys_src=keys_src,
+                        keys_dst=keys_dst,
+                        n_live=jnp.asarray(n_live, jnp.int32),
+                        source=source.astype(jnp.int32))
+
+
+def graph_kernel(g: TrafficGraph, *, kernel: str, n_vertices: int,
+                 backend: str = "auto", **pagerank_kw):
+    """One graph kernel over ``g`` (core/algorithms.py): ``bfs`` levels
+    from ``g.source`` over out-links, ``wcc`` min-id labels of the weak
+    components, ``pagerank`` (``pagerank_kw``: ``weighted``) or
+    ``triangles``."""
+    with jax.named_scope("graph"), jax.named_scope(kernel):
+        if kernel == "bfs":
+            return algo.bfs_levels(g.csr_src, g.source, n_vertices,
+                                   n_live=g.n_live, backend=backend,
+                                   row_keys=g.keys_src)
+        if kernel == "wcc":
+            return algo.connected_components(
+                g.csr_src, n_vertices, csr_t=g.csr_dst, n_live=g.n_live,
+                backend=backend, row_keys=g.keys_src, row_keys_t=g.keys_dst)
+        if kernel == "pagerank":
+            return algo.pagerank(g.csr_src, n_vertices, n_live=g.n_live,
+                                 backend=backend, row_keys=g.keys_src,
+                                 **pagerank_kw)
+        if kernel == "triangles":
+            return algo.triangle_counts(g.csr_src, n_vertices,
+                                        backend=backend)
+    raise ValueError(f"unknown graph kernel {kernel!r}")
+
+
+def _graph_statics(a: Analytics, kernel: str) -> dict:
+    """The statics that shape ``kernel``'s program, and no others, so a
+    PageRank setting compiles no new BFS."""
+    return dict(weighted=a.weighted) if kernel == "pagerank" else {}
+
+
+# ---------------------------------------------------------------------------
 # the orchestrator
 # ---------------------------------------------------------------------------
 
@@ -693,6 +834,14 @@ def _dispatch_and_sync(fn, *args):
         out = fn(*args)
     with obs_span("sync"):
         return _block(out)
+
+
+def _analyze_and_plans(t, **kw):
+    """``analyze`` that also hands back its plan pair, for a pass whose
+    graph phase then builds its CSR pair with no sort of its own."""
+    with jax.named_scope("analyze"), jax.named_scope("plan"):
+        plans = table_plans(t)
+    return analyze(t, plans=plans, **kw), plans
 
 
 def _build(s, d, wn, nv):
@@ -725,7 +874,11 @@ def _jitted(fn, donate_argnums=(), **statics):
 def run_challenge(
     cfg: ChallengeConfig, key: Optional[jax.Array] = None
 ) -> ChallengeRun:
-    """Run read -> build -> anonymize -> analyze, timing each phase."""
+    """Run read -> build -> anonymize -> analyze and/or graph, timing each
+    phase."""
+    a = cfg.analytics
+    # refuse a vertex domain the float32 carriers cannot hold before the run
+    n_vertices = graph_vertices(cfg.table_capacity) if a.kernels else 0
     if cfg.distributed:
         _mesh_device_count()  # refuse before the run, not after it
     if key is None:
@@ -733,11 +886,39 @@ def run_challenge(
     workdir = cfg.workdir or tempfile.mkdtemp(prefix="netsense_challenge_")
     os.makedirs(workdir, exist_ok=True)
     kw = dict(n_windows=cfg.n_windows, ip_bins=cfg.ip_bins, k=cfg.top_k,
-              backend=cfg.backend, fused_epilogue=cfg.fused_epilogue,
-              algorithms=cfg.algorithms, bfs_source=cfg.bfs_source)
+              backend=cfg.backend, fused_epilogue=cfg.fused_epilogue)
     build_fn = _jitted(_build)
     anon_fn = _jitted(anonymize, method=cfg.method, rounds=cfg.rounds)
-    analyze_fn = _jitted(analyze, **kw)
+    analyze_fn = None
+    if a.queries:  # with kernels too, the graph phase reuses its plan pair
+        analyze_fn = _jitted(_analyze_and_plans if a.kernels else analyze,
+                             **kw)
+    plan_fn = _jitted(graph_plan)
+    kernel_fns = {k: _jitted(graph_kernel, kernel=k, n_vertices=n_vertices,
+                             backend=cfg.backend, **_graph_statics(a, k))
+                  for k in a.kernels}
+
+    # -1: the first packet's source; an operand either way
+    source = np.int32(-1 if a.bfs_source is None else a.bfs_source)
+
+    def analyze_phase(table, span=_dispatch_and_sync):
+        """(results, the plan pair when the graph phase reuses it)"""
+        out = span(analyze_fn, table)
+        return out if a.kernels else (out, None)
+
+    def graph_phase(anon, plans, span=_dispatch_and_sync):
+        with obs_span("plan"):
+            g = span(plan_fn, anon.table, anon.n_ips, source, plans)
+        out = {}
+        for k, fn in kernel_fns.items():
+            with obs_span(k):
+                out[k] = span(fn, g)
+                if k != "triangles":  # the one kernel with no fixed point
+                    counter_event(f"graph.{k}.iterations",
+                                  int(out[k].iterations))
+                    counter_event(f"graph.{k}.converged",
+                                  int(out[k].converged))
+        return GraphRun(kernels=out, n_live=g.n_live, source=g.source)
 
     # Phase timing is span-based (obs/trace.py): each wall below is a span's
     # duration over the same perf_counter clock the old inline timers used,
@@ -763,7 +944,13 @@ def run_challenge(
         if cfg.warm:
             with obs_span("compile") as sp_compile:
                 wt, _ = _block(build_fn(src, dst, win, n))
-                _block(analyze_fn(_block(anon_fn(wt, key)).table))
+                wa = _block(anon_fn(wt, key))
+                warm = lambda fn, *x: _block(fn(*x))
+                plans = None
+                if analyze_fn is not None:
+                    _, plans = analyze_phase(wa.table, span=warm)
+                if kernel_fns:
+                    graph_phase(wa, plans, span=warm)
 
         # ---- build (windows + transfer + A_t group-by) ----
         with obs_span("build_device") as sp_build_dev:
@@ -774,16 +961,27 @@ def run_challenge(
             anon = _dispatch_and_sync(anon_fn, table, key)
 
         # ---- analyze ----
-        with obs_span("analyze") as sp_analyze:
-            results = _dispatch_and_sync(analyze_fn, anon.table)
+        results = plans = sp_analyze = None
+        if analyze_fn is not None:
+            with obs_span("analyze") as sp_analyze:
+                results, plans = analyze_phase(anon.table)
 
+        # ---- graph: the CSR pair (and the plan pair unless analyze sorted
+        # it), then each kernel's own program ----
+        graph = sp_graph = None
+        if kernel_fns:
+            with obs_span("graph") as sp_graph:
+                graph = graph_phase(anon, plans)
+
+        wall = lambda sp: None if sp is None else sp.duration_s
         timings = ChallengePhaseTimings(
             n_packets=n,
             read_s=sp_read.duration_s,
             build_s=sp_build_host.duration_s + sp_build_dev.duration_s,
             anonymize_s=sp_anon.duration_s,
-            analyze_s=sp_analyze.duration_s,
-            compile_s=sp_compile.duration_s if sp_compile is not None else None,
+            analyze_s=wall(sp_analyze),
+            graph_s=wall(sp_graph),
+            compile_s=wall(sp_compile),
         )
 
         if cfg.distributed:
@@ -794,17 +992,8 @@ def run_challenge(
         if cfg.fused:
             timings.fused_s = _time_fused(cfg, src, dst, win, n, key, kw)
 
-    anon_columns = None
-    if cfg.algorithms:
-        at = anon.table
-        anon_columns = {
-            "src": np.asarray(at["src"])[:n].astype(np.int64),
-            "dst": np.asarray(at["dst"])[:n].astype(np.int64),
-        }
-
     return ChallengeRun(results=results, timings=timings, capture=capture,
-                        config=cfg, anon_columns=anon_columns,
-                        anon_table=anon.table)
+                        config=cfg, anon_table=anon.table, graph=graph)
 
 
 def _time_fused(cfg, src, dst, win, n, key, kw) -> float:

@@ -4,9 +4,13 @@ Prints the per-phase timing table (paper-style), all 14 Table III query
 results (scalars verbatim, vector queries as count + head), the per-window
 statistics, cross-window IP overlap and the k heaviest links; ``--verify``
 (default) checks every scalar against the sequential NumPy oracle.
+``--algorithms`` adds the graph phase (BFS, WCC, PageRank, triangles, or a
+comma list of them), each kernel checked against its NumPy oracle.
 
     PYTHONPATH=src python -m repro.challenge.run --scale 14
     PYTHONPATH=src python -m repro.challenge.run --scale 18 --fused --format pcaplite
+    PYTHONPATH=src python -m repro.challenge.run --scale 12 --no-queries \
+        --algorithms bfs,wcc,pagerank --pagerank-unweighted
 """
 from __future__ import annotations
 
@@ -18,7 +22,13 @@ import numpy as np
 
 from ..core.ref import ref_run_all_queries
 from ..core.sketch import SketchConfig, SketchSnapshot
-from .pipeline import ChallengeConfig, ChallengeRun, run_challenge
+from .pipeline import (
+    GRAPH_KERNELS,
+    Analytics,
+    ChallengeConfig,
+    ChallengeRun,
+    run_challenge,
+)
 
 
 def format_queries(r) -> str:
@@ -88,76 +98,88 @@ def format_extras(r, nw: int) -> str:
     return "\n".join(out)
 
 
-def format_algorithms(r) -> str:
-    """Summary of the iterative-algorithm pass (``analyze --algorithms``)."""
-    a = r.algorithms
-    n = int(r.scalars.n_unique_ips)
-    levels = np.asarray(a.bfs.levels)[:n]
-    reached = levels[levels >= 0]
-    out = ["", f"graph algorithms over the anonymized traffic graph "
+def format_algorithms(run: ChallengeRun) -> str:
+    """Summary of the graph phase (``--algorithms``): each kernel that ran."""
+    g = run.graph
+    n = int(g.n_live)
+    out = ["", f"graph kernels over the anonymized traffic graph "
               f"({n:,} vertices):"]
-    out.append(
-        f"  bfs        reached {int(a.bfs.n_reached):,} vertices, "
-        f"max level {int(reached.max()) if reached.size else -1}, "
-        f"{int(a.bfs.iterations)} iters, converged={bool(a.bfs.converged)}"
-    )
-    out.append(
-        f"  components {int(a.components.n_components):,} weakly connected, "
-        f"{int(a.components.iterations)} iters, "
-        f"converged={bool(a.components.converged)}"
-    )
-    ranks = np.asarray(a.pagerank.ranks)[:n]
-    top = np.argsort(ranks)[::-1][:3]
-    head = " ".join(f"{v}:{ranks[v]:.5f}" for v in top)
-    out.append(
-        f"  pagerank   residual {float(a.pagerank.residual):.2e} after "
-        f"{int(a.pagerank.iterations)} iters, "
-        f"converged={bool(a.pagerank.converged)}, top {head}"
-    )
-    out.append(
-        f"  triangles  {int(a.triangles.total):,} closed directed wedges "
-        f"(A ⊙ A·A mass)"
-    )
+    k = g.kernels
+    if "bfs" in k:
+        levels = np.asarray(k["bfs"].levels)[:n]
+        reached = levels[levels >= 0]
+        out.append(
+            f"  bfs        from {int(g.source)}: reached "
+            f"{int(k['bfs'].n_reached):,} vertices, max level "
+            f"{int(reached.max()) if reached.size else -1}, "
+            f"{int(k['bfs'].iterations)} iters, "
+            f"converged={bool(k['bfs'].converged)}")
+    if "wcc" in k:
+        out.append(
+            f"  wcc        {int(k['wcc'].n_components):,} weakly connected, "
+            f"{int(k['wcc'].iterations)} iters, "
+            f"converged={bool(k['wcc'].converged)}")
+    if "pagerank" in k:
+        pr = k["pagerank"]
+        ranks = np.asarray(pr.ranks)[:n]
+        top = np.argsort(ranks)[::-1][:3]
+        head = " ".join(f"{v}:{ranks[v]:.5f}" for v in top)
+        out.append(
+            f"  pagerank   residual {float(pr.residual):.2e} after "
+            f"{int(pr.iterations)} iters, converged={bool(pr.converged)}, "
+            f"top {head}")
+    if "triangles" in k:
+        out.append(
+            f"  triangles  {int(k['triangles'].total):,} closed directed "
+            f"wedges (A ⊙ A·A mass)")
     return "\n".join(out)
 
 
 def verify_algorithms(run: ChallengeRun) -> int:
-    """Replay all four algorithms with the NumPy oracles on the anonymized
-    edge list; return the number of disagreeing result families."""
+    """Replay each kernel of the graph phase with the NumPy oracles on the
+    anonymized edge list; return the number of disagreeing kernels."""
     from ..kernels.ref import ref_bfs, ref_cc, ref_pagerank, ref_triangles
 
-    a = run.results.algorithms
-    src, dst = run.anon_columns["src"], run.anon_columns["dst"]
-    n = int(run.results.scalars.n_unique_ips)
-    bad = 0
+    g, a = run.graph, run.config.analytics
+    n_rows = int(run.anon_table.n_valid)
+    src = np.asarray(run.anon_table["src"])[:n_rows].astype(np.int64)
+    dst = np.asarray(run.anon_table["dst"])[:n_rows].astype(np.int64)
+    n = int(g.n_live)
+    k = g.kernels
+    bad = []
 
-    levels = np.asarray(a.bfs.levels)
-    want = ref_bfs(src, dst, n, run.config.bfs_source)
-    if not (np.array_equal(levels[:n], want) and np.all(levels[n:] == -1)):
-        print("MISMATCH bfs levels vs oracle", file=sys.stderr)
-        bad += 1
+    if "bfs" in k:
+        levels = np.asarray(k["bfs"].levels)
+        want = ref_bfs(src, dst, n, int(g.source))
+        if not (np.array_equal(levels[:n], want) and np.all(levels[n:] == -1)):
+            bad.append("bfs levels")
 
-    labels = np.asarray(a.components.labels)
-    want = ref_cc(src, dst, n)
-    if not (np.array_equal(labels[:n], want) and np.all(labels[n:] == -1)
-            and int(a.components.n_components) == len(np.unique(want))):
-        print("MISMATCH component labels vs oracle", file=sys.stderr)
-        bad += 1
+    if "wcc" in k:
+        labels = np.asarray(k["wcc"].labels)
+        want = ref_cc(src, dst, n)
+        if not (np.array_equal(labels[:n], want) and np.all(labels[n:] == -1)
+                and int(k["wcc"].n_components) == len(np.unique(want))):
+            bad.append("wcc labels")
 
-    ranks = np.asarray(a.pagerank.ranks)
-    want, _, _ = ref_pagerank(src, dst, np.ones(len(src)), n)
-    l1 = np.abs(ranks[:n] - want).sum()
-    if not (l1 < 1e-6 and np.all(ranks[n:] == 0.0)):
-        print(f"MISMATCH pagerank vs oracle: L1={l1:.3e}", file=sys.stderr)
-        bad += 1
+    if "pagerank" in k:
+        ranks = np.asarray(k["pagerank"].ranks)
+        # unweighted: one unit per distinct out-link, not per packet
+        e_src, e_dst = ((src, dst) if a.weighted else
+                        np.unique(np.stack([src, dst]), axis=1))
+        want, _, _ = ref_pagerank(e_src, e_dst, np.ones(len(e_src)), n)
+        l1 = np.abs(ranks[:n] - want).sum()
+        if not (l1 < 1e-6 and np.all(ranks[n:] == 0.0)):
+            bad.append(f"pagerank (L1={l1:.3e})")
 
-    per_node = np.asarray(a.triangles.per_node)
-    want, total = ref_triangles(src, dst, n)
-    if not (np.array_equal(per_node[:n], want.astype(np.float32))
-            and int(a.triangles.total) == total):
-        print("MISMATCH triangle counts vs oracle", file=sys.stderr)
-        bad += 1
-    return bad
+    if "triangles" in k:
+        per_node = np.asarray(k["triangles"].per_node)
+        want, total = ref_triangles(src, dst, n)
+        if not (np.array_equal(per_node[:n], want.astype(np.float32))
+                and int(k["triangles"].total) == total):
+            bad.append("triangle counts")
+    for what in bad:
+        print(f"MISMATCH {what} vs oracle", file=sys.stderr)
+    return len(bad)
 
 
 # --- the approximate (sketch) tier --------------------------------------------
@@ -324,11 +346,20 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                          "otherwise")
     ap.add_argument("--distributed", action="store_true",
                     help="scalar suite via shard_map over local devices")
-    ap.add_argument("--algorithms", action="store_true",
-                    help="run BFS/CC/PageRank/triangles over the anonymized "
-                         "traffic graph (oracle-checked under --verify)")
-    ap.add_argument("--bfs-source", type=int, default=0,
-                    help="BFS source vertex (anonymized id, default 0)")
+    ap.add_argument("--algorithms", nargs="?", const=",".join(GRAPH_KERNELS),
+                    default="", metavar="KERNELS",
+                    help="graph phase over the anonymized traffic graph: a "
+                         "comma list from " + ",".join(GRAPH_KERNELS) +
+                         " (all four when given bare; oracle-checked under "
+                         "--verify)")
+    ap.add_argument("--bfs-source", type=int, default=None,
+                    help="BFS source vertex (anonymized id; default the "
+                         "source of the first packet)")
+    ap.add_argument("--pagerank-unweighted", action="store_true",
+                    help="split rank evenly over distinct out-links "
+                         "(Graphalytics) instead of by packet count")
+    ap.add_argument("--no-queries", dest="queries", action="store_false",
+                    help="skip the Table III queries (graph phase only)")
     ap.add_argument("--workdir", default=None,
                     help="capture cache dir (tmp if unset)")
     ap.add_argument("--tier", default="exact",
@@ -347,20 +378,27 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     ap.add_argument("--no-verify", dest="verify", action="store_false",
                     help="skip the NumPy-oracle scalar check")
     args = ap.parse_args(argv)
+    if args.tier != "exact" and not args.queries:
+        ap.error("--tier sketch/both is checked against the queries; drop "
+                 "--no-queries")
     if argv is None:  # the command line, not a caller passing its own argv
         from ..launch.compile_cache import use_compile_cache
 
         use_compile_cache()
 
     try:
+        analytics = Analytics(
+            queries=args.queries,
+            kernels=tuple(k for k in args.algorithms.split(",") if k),
+            bfs_source=args.bfs_source, weighted=not args.pagerank_unweighted)
         cfg = ChallengeConfig(
             scale=args.scale, n_packets=args.n_packets, n_windows=args.windows,
             ip_bins=args.ip_bins, top_k=args.top_k, method=args.method,
             rounds=args.rounds, seed=args.seed, fmt=args.format,
             backend=args.backend, fused=args.fused,
             fused_epilogue=args.fused_epilogue,
-            distributed=args.distributed, algorithms=args.algorithms,
-            bfs_source=args.bfs_source, workdir=args.workdir,
+            distributed=args.distributed, analytics=analytics,
+            workdir=args.workdir,
         )
     except ValueError as e:
         ap.error(str(e))
@@ -385,11 +423,12 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     run = run_challenge(cfg)
 
     print("\n" + run.timings.format_table())
-    print()
-    print(format_queries(run.results))
-    print(format_extras(run.results, run.config.n_windows))
-    if args.algorithms:
-        print(format_algorithms(run.results))
+    if run.results is not None:
+        print()
+        print(format_queries(run.results))
+        print(format_extras(run.results, run.config.n_windows))
+    if run.graph is not None:
+        print(format_algorithms(run))
 
     sketch_snap = None
     if args.tier != "exact":
@@ -410,8 +449,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         print(format_sketch(sketch_snap))
 
     if args.verify:
-        bad = verify_scalars(run)
-        if args.algorithms:
+        bad = verify_scalars(run) if run.results is not None else 0
+        if run.graph is not None:
             bad += verify_algorithms(run)
         if sketch_snap is not None:
             s = run.results.scalars
@@ -426,9 +465,11 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         if bad:
             print(f"\n{bad} result(s) disagree with the oracle", file=sys.stderr)
             return 1
-        print("\nall scalar queries match the NumPy oracle ✓")
-        if args.algorithms:
-            print("all four graph algorithms match their NumPy oracles ✓")
+        if run.results is not None:
+            print("\nall scalar queries match the NumPy oracle ✓")
+        if run.graph is not None:
+            print(f"graph kernels {','.join(run.graph.kernels)} match their "
+                  "NumPy oracles ✓")
         if sketch_snap is not None:
             print("all sketch estimates within their configured bounds ✓")
     return 0

@@ -16,7 +16,9 @@ Conventions shared by every algorithm here:
     scalar).  Iteration state lives in this domain; one step is a masked
     :func:`~repro.core.sparse.vxm` push (``y[v] = ⊕_u A[u, v] ⊗ x[u]``)
     with :func:`~repro.core.sparse.gather_rows` bridging vertex-indexed
-    state back to the row-slot inputs ``vxm`` consumes.  Everything is
+    state back to the row-slot inputs ``vxm`` consumes; an iteration
+    folds the two into one gather through each entry's row key, computed
+    once before its loop (``entry_row_key``).  Everything is
     scatters, gathers, and segmented reductions — **zero sorts** beyond
     whatever plan the CSR came from (asserted by the challenge HLO budget
     tests).
@@ -25,19 +27,30 @@ Conventions shared by every algorithm here:
     whose result carries the executed iteration count **and** a
     ``converged`` flag — hitting the cap returns the well-formed partial
     state with ``converged == False``, it never masquerades as an answer.
+  * **Frontier push.**  BFS and connected components push only the
+    vertices that changed in the previous step.  A step whose active set
+    is small (at most ``frontier[0]`` vertices owning at most
+    ``frontier[1]`` stored entries) runs over just those entries; any
+    larger step runs the full masked ``vxm``.  A full product costs one
+    gather and one scatter over every stored entry, whatever the active
+    set, so without this the near-empty tail levels of a BFS cost as much
+    as its widest level.
   * **float32 carriers.**  Distances, labels, and wedge counts ride float32
-    through the semiring kernels; vertex ids and hop counts stay below
-    2**24 at every challenge scale, so the integer results are exact
-    (same argument as the packet-count path, DESIGN.md §2.4).
+    through the semiring kernels, exact for integers below 2**24: a vertex
+    domain past 2**24 ids would make the labels silently wrong, so the
+    challenge's graph phase refuses one
+    (``repro.challenge.pipeline.graph_vertices``).
   * **Oracle-locked.**  Each algorithm has a NumPy twin in
     :mod:`repro.kernels.ref` (``ref_bfs`` / ``ref_cc`` / ``ref_pagerank``
     / ``ref_triangles``); the exact algorithms must match bit-identically,
     PageRank to 1e-6 L1 (tests/test_algorithms.py, scales 10 and 14).
+    The benchmark holds the challenge's graph phase to its own NumPy
+    reference (``bench/graph_reference.py``) at the timed sizes.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Optional
+from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -45,13 +58,13 @@ import jax.numpy as jnp
 from ..kernels.ops import segmented_reduce
 from .sparse import (
     CsrMatrix,
-    gather_rows,
     reduce_rows,
     scatter_rows,
     vxm,
 )
 
 __all__ = [
+    "FRONTIER",
     "FixedPoint",
     "fixed_point",
     "UNREACHABLE",
@@ -72,6 +85,91 @@ _INF = jnp.float32(jnp.inf)
 #: BFS level / component label reported for unreachable or non-live
 #: vertices — a sentinel, never garbage.
 UNREACHABLE = -1
+
+#: (vertices, entries): the largest active set a BFS or components step
+#: pushes over its own entries instead of the full product (the vertices
+#: capped by the vertex domain, the entries by the CSR's; ``(0, 0)``
+#: always runs the full product).  On the RMAT traffic graph at 2^22
+#: links this takes the near-empty tail levels off the full product and
+#: leaves it the wide middle ones, whose number barely moves with the
+#: capture (PERF.md)
+FRONTIER = (1 << 12, 1 << 17)
+
+
+# ---------------------------------------------------------------------------
+# frontier push — the (min, second) product over the active vertices only
+# ---------------------------------------------------------------------------
+
+def _compact(active: jnp.ndarray, cap: int):
+    """The ids of the first ``cap`` active vertices (``len(active)`` past
+    their count) and their count: a prefix count and a binary search per
+    slot, no scatter over the vertex domain."""
+    count = jnp.cumsum(active.astype(jnp.int32))
+    ids = jnp.searchsorted(count, jnp.arange(1, cap + 1, dtype=jnp.int32),
+                           side="left").astype(jnp.int32)
+    return ids, count[-1]
+
+
+def _push_min(
+    x: jnp.ndarray,
+    active: jnp.ndarray,
+    compact,
+    csr: CsrMatrix,
+    n: int,
+    *,
+    live: jnp.ndarray,
+    rows: jnp.ndarray,
+    backend: str,
+    frontier: Tuple[int, int],
+) -> jnp.ndarray:
+    """``y[v] = min x[u]`` over the stored entries ``u -> v`` with ``u``
+    active (``+inf`` where none; non-live ``v`` read ``+inf``): the masked
+    ``vxm(where(active, x, inf), csr, add="min", mul="second")``.
+
+    ``compact`` is :func:`_compact` of ``active`` at ``frontier[0]``.  When
+    the active vertices fit it and own at most ``frontier[1]`` entries, the
+    product runs over just those entries (each vertex's row found by a
+    binary search over the row keys); otherwise over all of them.  Both
+    give the same ``y``: a min over the same entries."""
+    n_front, n_entries = frontier
+    n_entries = min(n_entries, csr.nnz_capacity)
+
+    def dense(_):
+        return vxm(jnp.where(active, x, _INF), csr, n, add="min",
+                   mul="second", mask=live, backend=backend, rows=rows)
+
+    if n_front <= 0 or n_entries <= 0:
+        return dense(None)
+    ids, count = compact
+    rk = csr.row_keys[0]
+    cap_r = csr.row_capacity
+    r = jnp.searchsorted(rk, ids.astype(rk.dtype), side="left")
+    r = jnp.minimum(r.astype(jnp.int32), cap_r - 1)
+    has = (ids < n) & (r < csr.n_rows) & (rk[r] == ids.astype(rk.dtype))
+    start = csr.indptr[r]
+    deg = jnp.where(has, csr.indptr[r + 1] - start, 0)
+    ends = jnp.cumsum(deg)
+    fits = (count <= n_front) & (ends[-1] <= n_entries)
+
+    def sparse(_):
+        offs = ends - deg
+        k = jnp.arange(n_entries, dtype=jnp.int32)
+        # the active vertex owning each of the first ends[-1] slots: its
+        # index marked where its entries start, carried forward
+        owner = jax.lax.cummax(
+            jnp.zeros((n_entries,), jnp.int32).at[
+                jnp.where(deg > 0, offs, n_entries)
+            ].max(jnp.arange(ids.shape[0], dtype=jnp.int32), mode="drop"))
+        ok = k < ends[-1]
+        pos = jnp.where(ok, start[owner] + (k - offs[owner]), 0)
+        cols = csr.col_keys[pos].astype(jnp.int32)
+        ok = ok & (cols >= 0) & (cols < n)
+        vals = x[jnp.minimum(ids, n - 1)][owner]
+        y = jnp.full((n,), _INF, jnp.float32).at[
+            jnp.where(ok, cols, n)].min(jnp.where(ok, vals, _INF), mode="drop")
+        return jnp.where(live, y, _INF)
+
+    return jax.lax.cond(fits, sparse, dense, None)
 
 
 # ---------------------------------------------------------------------------
@@ -175,6 +273,8 @@ def bfs_levels(
     n_live=None,
     max_iters: Optional[int] = None,
     backend: str = "auto",
+    row_keys: Optional[jnp.ndarray] = None,
+    frontier: Tuple[int, int] = FRONTIER,
 ) -> BfsResult:
     """BFS hop levels from ``source`` — min-plus masked frontier expansion.
 
@@ -183,31 +283,36 @@ def bfs_levels(
     skips the ⊗ multiply entirely — packet weights carry no distance and
     ``inf * 0`` NaNs are never formed), then ``dist = min(dist, cand)``;
     the frontier is exactly the vertices whose distance improved, and the
-    fixed point is the empty frontier.  ``max_iters`` defaults to
-    ``n_vertices`` (the longest possible shortest path + confirmation).
+    fixed point is the empty frontier.  A frontier within ``frontier``
+    (vertices, entries) pushes over its own entries only (:data:`FRONTIER`).
+    ``max_iters`` defaults to ``n_vertices`` (the longest possible shortest
+    path + confirmation).  ``row_keys`` is ``csr.entry_row_key(0)``,
+    computed here once when not given.
     """
     n = int(n_vertices)
+    keys = csr.entry_row_key(0) if row_keys is None else row_keys
     cap = n if max_iters is None else max_iters
     n_live_ = jnp.asarray(n if n_live is None else n_live, jnp.int32)
     vids = jnp.arange(n, dtype=jnp.int32)
     live = vids < n_live_
     source = jnp.asarray(source, jnp.int32)
+    n_front = min(frontier[0], n)
 
     dist0 = jnp.full((n,), _INF, jnp.float32).at[source].set(0.0)
-    frontier0 = (vids == source) & live
+    front0 = (vids == source) & live
 
     def step(carry):
-        dist, frontier = carry
-        x = jnp.where(frontier, dist, _INF)
-        hop = vxm(
-            gather_rows(csr, x, fill=_INF), csr, n,
-            add="min", mul="second", mask=live, backend=backend,
+        dist, front = carry
+        compact = _compact(front, n_front) if n_front > 0 else None
+        hop = _push_min(
+            dist, front, compact, csr, n, live=live, rows=keys,
+            backend=backend, frontier=(n_front, frontier[1]),
         ) + 1.0
         new = jnp.minimum(dist, hop)
         return new, new < dist
 
     fp = fixed_point(
-        step, (dist0, frontier0), cap,
+        step, (dist0, front0), cap,
         lambda old, new: jnp.logical_not(jnp.any(new[1])),
     )
     dist, _ = fp.state
@@ -256,45 +361,67 @@ def connected_components(
     n_live=None,
     max_iters: Optional[int] = None,
     backend: str = "auto",
+    row_keys: Optional[jnp.ndarray] = None,
+    row_keys_t: Optional[jnp.ndarray] = None,
+    frontier: Tuple[int, int] = FRONTIER,
 ) -> ComponentsResult:
     """Label propagation under the (min, second) semiring to a fixed point.
 
-    Labels start as own vertex ids and each step takes the min over both
-    edge directions (``A`` and ``A^T``) plus self — weak connectivity
-    without materializing ``A ⊕ A^T``: pass the challenge's dst-keyed CSR
-    as ``csr_t`` and the whole computation adds **zero** sorts to the
-    plan's budget (``csr_t=None`` falls back to one
-    :func:`~repro.core.sparse.transpose` sort).  Converges in at most
-    diameter+1 steps (cap: ``n_vertices``).
+    Labels start as own vertex ids, but for the vertex with the most
+    out-links, which starts below them all (-1); each step pushes the
+    labels that changed in the previous step over both edge directions
+    (``A`` and ``A^T``) and keeps the min — weak connectivity without
+    materializing ``A ⊕ A^T``: pass the challenge's dst-keyed CSR as
+    ``csr_t`` and the whole computation adds **zero** sorts to the plan's
+    budget (``csr_t=None`` falls back to one
+    :func:`~repro.core.sparse.transpose` sort).  Each component settles on
+    its smallest starting label, and the hub's then takes its smallest id,
+    so the labels are the min-id labels whichever vertex started low.
+    Starting from the hub makes the step count its eccentricity (+1 to
+    confirm) rather than that of whichever vertex holds the smallest id,
+    which moves with the anonymization key; a changed set within
+    ``frontier`` pushes over its own entries only (:data:`FRONTIER`).
+    Converges in at most diameter+1 steps (cap: ``n_vertices``).
+    ``row_keys``/``row_keys_t`` are the two CSRs' ``entry_row_key(0)``,
+    computed here once when not given.
     """
     n = int(n_vertices)
     cap = n if max_iters is None else max_iters
     n_live_ = jnp.asarray(n if n_live is None else n_live, jnp.int32)
-    live = jnp.arange(n, dtype=jnp.int32) < n_live_
+    vids = jnp.arange(n, dtype=jnp.int32)
+    live = vids < n_live_
     if csr_t is None:
         from .sparse import transpose  # local: keep the zero-sort path lean
 
         csr_t, _ = transpose(csr)
+    keys = csr.entry_row_key(0) if row_keys is None else row_keys
+    keys_t = csr_t.entry_row_key(0) if row_keys_t is None else row_keys_t
+    n_front = min(frontier[0], n)
 
-    labels0 = jnp.where(live, jnp.arange(n, dtype=jnp.float32), _INF)
+    # the row with the most entries (padding rows hold none)
+    hub = csr.row_keys[0][jnp.argmax(csr.indptr[1:] - csr.indptr[:-1])]
+    hub = jnp.where((csr.nnz > 0) & (hub >= 0) & (hub < n), hub, n)
+    labels0 = jnp.where(live, vids.astype(jnp.float32), _INF)
+    labels0 = jnp.where(live & (vids == hub), -1.0, labels0)
 
-    def step(labels):
-        fwd = vxm(
-            gather_rows(csr, labels, fill=_INF), csr, n,
-            add="min", mul="second", mask=live, backend=backend,
-        )
-        bwd = vxm(
-            gather_rows(csr_t, labels, fill=_INF), csr_t, n,
-            add="min", mul="second", mask=live, backend=backend,
-        )
-        return jnp.minimum(labels, jnp.minimum(fwd, bwd))
+    def step(carry):
+        labels, changed = carry
+        compact = _compact(changed, n_front) if n_front > 0 else None
+        kw = dict(live=live, backend=backend,
+                  frontier=(n_front, frontier[1]))
+        fwd = _push_min(labels, changed, compact, csr, n, rows=keys, **kw)
+        bwd = _push_min(labels, changed, compact, csr_t, n, rows=keys_t, **kw)
+        new = jnp.minimum(labels, jnp.minimum(fwd, bwd))
+        return new, new < labels
 
     fp = fixed_point(
-        step, labels0, cap,
-        lambda old, new: jnp.all(old == new),
+        step, (labels0, live), cap,
+        lambda old, new: jnp.logical_not(jnp.any(new[1])),
     )
-    labels = jnp.where(live, fp.state, jnp.float32(UNREACHABLE)).astype(jnp.int32)
-    roots = live & (labels == jnp.arange(n, dtype=jnp.int32))
+    low = fp.state[0] < 0  # the hub's component
+    labels = jnp.where(low, jnp.min(jnp.where(low, vids, n)), fp.state[0])
+    labels = jnp.where(live, labels, jnp.float32(UNREACHABLE)).astype(jnp.int32)
+    roots = live & (labels == vids)
     return ComponentsResult(
         labels=labels,
         n_components=jnp.sum(roots).astype(jnp.int32),
@@ -339,6 +466,7 @@ def pagerank(
     max_iters: int = 100,
     weighted: bool = True,
     backend: str = "auto",
+    row_keys: Optional[jnp.ndarray] = None,
 ) -> PageRankResult:
     """Power iteration ``r = d·(rP + dangling/n) + (1-d)/n`` to L1 tol.
 
@@ -350,8 +478,11 @@ def pagerank(
     range, so ``sum(ranks) == 1`` to float32 roundoff at every step.
     Damping contracts the iteration by ``d`` per step, so the L1 residual
     test bounds the distance to the true fixed point by ``tol/(1-d)``.
+    ``row_keys`` is ``csr.entry_row_key(0)``, computed here once when not
+    given.
     """
     n = int(n_vertices)
+    keys = csr.entry_row_key(0) if row_keys is None else row_keys
     n_live_ = jnp.asarray(n if n_live is None else n_live, jnp.int32)
     live = jnp.arange(n, dtype=jnp.int32) < n_live_
     nf = jnp.maximum(n_live_, 1).astype(jnp.float32)
@@ -370,10 +501,8 @@ def pagerank(
         r, _ = carry
         has_out = outw > 0
         contrib = jnp.where(has_out, r / jnp.where(has_out, outw, 1.0), 0.0)
-        y = vxm(
-            gather_rows(csr, contrib, fill=0.0), csr, n,
-            add="plus", mul=mul, mask=live, backend=backend,
-        )
+        y = vxm(contrib, csr, n, add="plus", mul=mul, mask=live,
+                backend=backend, rows=keys)
         dangling = jnp.sum(jnp.where(live & ~has_out, r, 0.0))
         new = d * (y + dangling * base) + (1.0 - d) * base
         return new, jnp.sum(jnp.abs(new - r))

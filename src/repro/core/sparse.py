@@ -478,17 +478,23 @@ def vxm(
     mul: str = "times",
     mask: Optional[jnp.ndarray] = None,
     backend: str = "auto",
+    rows: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Masked ``y = x ⊕.⊗ A`` — the column-side mirror of :func:`mxv`.
 
     ``x`` is indexed by row slot (length ``row_capacity``); the output has
     ``num_cols`` slots indexed by column key.  Same semiring/mask/identity
-    conventions and kernel dispatch as :func:`mxv`.
+    conventions and kernel dispatch as :func:`mxv`.  ``rows`` is the index
+    into ``x`` of each entry: its row slot, ``csr.entry_rows()`` (the
+    default), or, for an ``x`` over the row keys' domain, the entry's row
+    key, ``csr.entry_row_key(0)`` (which folds :func:`gather_rows` into
+    this one gather).  An iteration passes it in, computed once.
     """
     if add not in _ADD_OPS or mul not in _MUL_OPS:
         raise ValueError(f"unsupported semiring ({add!r}, {mul!r})")
     x = jnp.asarray(x)
-    rows = csr.entry_rows()
+    if rows is None:
+        rows = csr.entry_rows()
     ok = (
         csr.entry_mask()
         & (csr.col_keys >= 0) & (csr.col_keys < num_cols)

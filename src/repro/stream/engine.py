@@ -820,8 +820,8 @@ class StreamEngine:
 
         Answers from the accumulated link-table CSR (two sorts over
         ``link_capacity`` rows, never the packet stream); equals the batch
-        ``analyze(algorithms=True)`` pass on the concatenated stream up to
-        id relabeling.  Returns an AlgorithmResults pytree (host-synced).
+        pipeline's graph phase on the concatenated stream up to id
+        relabeling.  Returns an AlgorithmResults pytree (host-synced).
         """
         from .algorithms import snapshot_algorithms
 

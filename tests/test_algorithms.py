@@ -1,15 +1,29 @@
 """Iterative graph algorithms (core/algorithms.py): fixed-point harness
 semantics, NumPy-oracle parity at scales 10 and 14, edge-case behaviour
 (sentinels, dangling mass, cap-outs), streaming-vs-batch equivalence, and
-the analyze(algorithms=True) sort budget."""
+the challenge's graph phase: its sort budget, spans and counters, operand
+BFS source, and agreement with both the program's oracles and the
+benchmark's reference."""
+import dataclasses
 import functools
+import importlib.util
+from pathlib import Path
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.challenge.pipeline import ChallengeConfig, analyze, run_challenge
+from repro.challenge.pipeline import (
+    GRAPH_KERNELS,
+    Analytics,
+    ChallengeConfig,
+    analyze,
+    graph_kernel,
+    graph_plan,
+    graph_vertices,
+    run_challenge,
+)
 from repro.core import (
     Table,
     UNREACHABLE,
@@ -20,6 +34,7 @@ from repro.core import (
     graph_algorithms,
     pagerank,
     table_csrs,
+    table_plans,
     triangle_counts,
 )
 from repro.kernels.ref import ref_bfs, ref_cc, ref_pagerank, ref_triangles
@@ -160,6 +175,53 @@ def test_triangles_match_oracle(scale):
         np.asarray(res.triangles.per_node), want_pn.astype(np.float32)
     )
     assert int(res.triangles.total) == want_total
+
+
+@pytest.mark.parametrize("frontier", [(0, 0), (4, 16), (64, 512), None])
+def test_frontier_push_matches_full_product(frontier):
+    """BFS and components give the same levels, labels and step counts
+    whether a step pushes over its active vertices' entries or runs the
+    full product: (0, 0) always runs the full product, the small caps mix
+    the two within one run, and None is the default."""
+    from repro.data.rmat import synthetic_packets
+
+    cols = synthetic_packets(1 << 12, scale=12, seed=3)
+    uniq = np.unique(np.concatenate([cols["src"], cols["dst"]]))
+    src = np.searchsorted(uniq, cols["src"]).astype(np.int32)
+    dst = np.searchsorted(uniq, cols["dst"]).astype(np.int32)
+    _, _, cs, cd, nv = _graph_table(src, dst)
+    kw = {} if frontier is None else dict(frontier=frontier)
+    bfs = jax.jit(lambda a: bfs_levels(a, int(src[0]), nv, backend="xla",
+                                       **kw))(cs)
+    cc = jax.jit(lambda a, b: connected_components(a, nv, csr_t=b,
+                                                   backend="xla", **kw))(cs, cd)
+    lv = np.asarray(bfs.levels)
+    np.testing.assert_array_equal(lv, ref_bfs(src, dst, nv, int(src[0])))
+    assert int(bfs.iterations) == int(lv.max()) + 1 and bool(bfs.converged)
+    np.testing.assert_array_equal(np.asarray(cc.labels),
+                                  ref_cc(src, dst, nv))
+    full = connected_components(cs, nv, csr_t=cd, backend="xla",
+                                frontier=(0, 0))
+    assert int(cc.iterations) == int(full.iterations)
+    assert bool(cc.converged)
+
+
+@pytest.mark.parametrize("first", [0, 8])
+def test_components_start_from_the_hub(first):
+    """Components settle in the eccentricity of the vertex with the most
+    out-links (+1 to confirm), wherever the smallest id sits: a 9-vertex
+    path with the hub in its middle takes 5 steps, not the 9 that labels
+    spreading from an end of the path would take."""
+    path = list(range(9))
+    if first:  # the smallest id at the other end of the path
+        path = path[::-1]
+    src = path[:-1] + [path[4]] * 3
+    dst = path[1:] + [9, 10, 11]
+    s, d, cs, cd, nv = _graph_table(src, dst)
+    res = connected_components(cs, nv, csr_t=cd, backend="xla")
+    np.testing.assert_array_equal(np.asarray(res.labels), ref_cc(s, d, nv))
+    assert np.all(np.asarray(res.labels) == 0)
+    assert int(res.iterations) == 5 and bool(res.converged)
 
 
 # --------------------------------------------------------------- edge cases
@@ -340,49 +402,85 @@ def test_stream_algorithms_invariant_to_rechunking():
 
 # ------------------------------------------------- challenge integration
 
+ROOT = Path(__file__).resolve().parents[1]
+
+
+def _bench_reference():
+    """``bench/graph_reference.py``: the benchmark's NumPy reference of
+    the Graphalytics kernels, loaded by path (it imports nothing of the
+    program)."""
+    spec = importlib.util.spec_from_file_location(
+        "graph_reference", ROOT / "bench" / "graph_reference.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _graph_cfg(tmp_path, scale=10, **analytics):
+    return ChallengeConfig(
+        scale=scale, n_windows=4, ip_bins=64, top_k=5, warm=False,
+        workdir=str(tmp_path), backend="xla",
+        analytics=Analytics(**{"queries": False, **analytics}))
+
+
+def _anon_edges(run):
+    n = int(run.anon_table.n_valid)
+    return (np.asarray(run.anon_table["src"])[:n].astype(np.int64),
+            np.asarray(run.anon_table["dst"])[:n].astype(np.int64))
+
+
 def test_analyze_algorithms_sort_budget():
-    """analyze(algorithms=True) still lowers to <= 3 HLO sorts — the
-    iterative pass rides the plan's CSR pair with zero extra sorts."""
+    """The graph phase sorts only for its plan pair: its plan program
+    lowers to exactly the sorts of ``table_plans`` alone, every kernel
+    program to none, and ``analyze`` keeps its three."""
     cap = 512
     t = Table.from_dict(
         {c: np.zeros(cap, np.int32) for c in ("src", "dst", "win")},
         n_valid=cap - 1,
     )
-    sorts = {}
-    for algo in (False, True):
-        f = jax.jit(lambda tt, a=algo: analyze(
-            tt, n_windows=4, ip_bins=64, k=5, backend="xla", algorithms=a,
-        ))
-        sorts[algo] = count_hlo_sorts(f.lower(t).compile().as_text())
-    assert sorts[True] <= 3
-    assert sorts[True] == sorts[False]  # the pass adds ZERO sorts
+    sorts = lambda f, *a: count_hlo_sorts(
+        jax.jit(f).lower(*a).compile().as_text())
+    plans = sorts(table_plans, t)
+    assert plans == 2
+    assert sorts(graph_plan, t, cap) == plans
+    g = jax.eval_shape(graph_plan, t, cap)
+    for k in GRAPH_KERNELS:
+        assert sorts(lambda g, k=k: graph_kernel(
+            g, kernel=k, n_vertices=2 * cap, backend="xla"), g) == 0, k
+    assert sorts(lambda tt: analyze(tt, n_windows=4, ip_bins=64, k=5,
+                                    backend="xla"), t) == 3
 
 
 def test_analyze_naive_rejects_algorithms():
+    """The graph kernels left ``analyze`` for a phase of their own: neither
+    the plan nor the naive path takes them any more."""
     t = Table.from_dict(
         {c: np.zeros(8, np.int32) for c in ("src", "dst", "win")}
     )
-    with pytest.raises(ValueError, match="plan path"):
-        analyze(t, n_windows=2, ip_bins=8, k=2, use_plan=False,
-                algorithms=True)
+    for use_plan in (True, False):
+        with pytest.raises(TypeError, match="algorithms"):
+            analyze(t, n_windows=2, ip_bins=8, k=2, use_plan=use_plan,
+                    algorithms=True)
 
 
 def test_challenge_run_scale10_algorithms_match_oracles(tmp_path):
-    """The CLI-level gate: a scale-10 end-to-end run with the algorithm
-    pass enabled agrees with all four NumPy oracles on the anonymized
-    edge list (the CI algorithms smoke runs this same check)."""
+    """The CLI-level gate: a scale-10 end-to-end run with every graph
+    kernel agrees with all four NumPy oracles on the anonymized edge list
+    (the CI algorithms smoke runs this same check)."""
     from repro.challenge.run import verify_algorithms, verify_scalars
 
     cfg = ChallengeConfig(
-        scale=10, n_windows=4, ip_bins=64, top_k=5, algorithms=True,
-        bfs_source=3, workdir=str(tmp_path), backend="xla",
+        scale=10, n_windows=4, ip_bins=64, top_k=5,
+        analytics=Analytics(kernels=GRAPH_KERNELS, bfs_source=3),
+        workdir=str(tmp_path), backend="xla",
     )
     run = run_challenge(cfg)
-    a = run.results.algorithms
-    assert a is not None
-    assert bool(a.bfs.converged) and bool(a.components.converged)
-    assert bool(a.pagerank.converged)
-    assert run.anon_columns is not None
+    k = run.graph.kernels
+    assert list(k) == list(GRAPH_KERNELS)
+    assert int(run.graph.source) == 3
+    assert bool(k["bfs"].converged) and bool(k["wcc"].converged)
+    assert bool(k["pagerank"].converged)
+    assert run.timings.graph_s > 0 and run.timings.analyze_s > 0
     assert verify_scalars(run) == 0
     assert verify_algorithms(run) == 0
 
@@ -393,5 +491,151 @@ def test_challenge_run_without_algorithms_keeps_field_none(tmp_path):
         backend="xla",
     )
     run = run_challenge(cfg)
-    assert run.results.algorithms is None
-    assert run.anon_columns is None
+    assert run.graph is None and run.timings.graph_s is None
+    assert "graph" not in run.timings.format_table()
+
+
+# the triangle scan is sized for small graphs, so it runs at scale 10 only
+@pytest.mark.parametrize("kernels,scale", [
+    (("bfs",), 10), (("wcc",), 10), (("pagerank",), 10), (("triangles",), 10),
+    (GRAPH_KERNELS, 10), (("bfs",), 12), (("wcc",), 12), (("pagerank",), 12),
+    (("bfs", "wcc", "pagerank"), 12)])
+def test_graph_phase_matches_both_references(tmp_path, kernels, scale):
+    """Each subset of kernels, run as the graph phase alone, agrees with
+    the program's oracles (``kernels/ref.py``) and with the benchmark's
+    reference: levels and labels exactly, PageRank within the limit."""
+    from repro.challenge.run import verify_algorithms
+
+    gr = _bench_reference()
+    run = run_challenge(_graph_cfg(tmp_path, scale, kernels=kernels,
+                                   weighted=False))
+    assert run.results is None and run.timings.analyze_s is None
+    assert tuple(run.graph.kernels) == kernels
+    assert verify_algorithms(run) == 0
+
+    src, dst = _anon_edges(run)
+    u, v = gr.links(src, dst)
+    k = run.graph.kernels
+    got = {"unconverged": sum(not bool(r.converged) for name, r in k.items()
+                              if name != "triangles")}
+    if "bfs" in k:
+        got["bfs_levels"] = np.asarray(k["bfs"].levels)
+    if "wcc" in k:
+        got["wcc_labels"] = np.asarray(k["wcc"].labels)
+    if "pagerank" in k:
+        got["pagerank_ranks"] = np.asarray(k["pagerank"].ranks)
+        got["pagerank_steps"] = int(k["pagerank"].iterations)
+    checks = gr.checks(got, u, v, int(run.graph.n_live), int(src[0]),
+                       damping=0.85, tol=1e-6, max_steps=100)
+    assert all(val <= gr.LIMITS[name] for name, val in checks.items()), checks
+
+
+@pytest.mark.parametrize("weighted", [True, False])
+def test_graph_phase_pagerank_weighted_and_unweighted(tmp_path, weighted):
+    """Weighted PageRank splits rank by packets per link, unweighted evenly
+    over distinct out-links (Graphalytics); each matches the float64
+    oracle given those weights, and the two differ."""
+    cfg = _graph_cfg(tmp_path, 11, kernels=("pagerank",), weighted=weighted)
+    run = run_challenge(cfg)
+    other = run_challenge(dataclasses.replace(cfg, analytics=dataclasses.replace(
+        cfg.analytics, weighted=not weighted)))
+    src, dst = _anon_edges(run)
+    n = int(run.graph.n_live)
+    if not weighted:
+        src, dst = np.unique(np.stack([src, dst]), axis=1)
+    want, iters, conv = ref_pagerank(src, dst, np.ones(len(src)), n)
+    pr = run.graph.kernels["pagerank"]
+    ranks = np.asarray(pr.ranks)
+    assert np.abs(ranks[:n] - want).sum() < 1e-6 and conv
+    assert int(pr.iterations) == iters and bool(pr.converged)
+    assert np.abs(ranks - np.asarray(
+        other.graph.kernels["pagerank"].ranks)).sum() > 1e-3
+
+
+def test_second_bfs_source_compiles_nothing(tmp_path):
+    """The BFS source is an operand of the BFS program: after the first
+    run (from the anonymized source of the first packet), a given id
+    compiles nothing."""
+    from repro.obs import jit_compile_count
+
+    cfg = _graph_cfg(tmp_path, 10, kernels=("bfs",))
+    first = run_challenge(cfg)
+    before = jit_compile_count()
+    levels = {}
+    for source in (5, 17):
+        run = run_challenge(dataclasses.replace(
+            cfg, analytics=dataclasses.replace(cfg.analytics,
+                                               bfs_source=source)))
+        assert int(run.graph.source) == source
+        levels[source] = np.asarray(run.graph.kernels["bfs"].levels)
+    assert jit_compile_count() == before
+    src, dst = _anon_edges(first)
+    n = int(first.graph.n_live)
+    assert int(first.graph.source) == src[0]
+    for source, got in levels.items():
+        np.testing.assert_array_equal(got[:n], ref_bfs(src, dst, n, source))
+
+
+def test_graph_spans_and_counters_are_recorded(tmp_path):
+    from repro.obs import get_tracer, reset_tracer
+
+    reset_tracer()
+    run = run_challenge(_graph_cfg(tmp_path, 10,
+                                   kernels=("bfs", "wcc", "pagerank")))
+    recs = get_tracer().records()
+    reset_tracer()
+    paths = {r["path"] for r in recs if r["kind"] == "span"}
+    for part in ("plan", "bfs", "wcc", "pagerank"):
+        assert {f"challenge/graph/{part}/dispatch",
+                f"challenge/graph/{part}/sync"} <= paths, part
+    assert "challenge/analyze" not in paths
+    counters = {(r["parent"], r["name"]): r["value"] for r in recs
+                if r["kind"] == "counter" and r["name"].startswith("graph.")}
+    for k, res in run.graph.kernels.items():
+        parent = f"challenge/graph/{k}"
+        assert counters[(parent, f"graph.{k}.iterations")] \
+            == int(res.iterations)
+        assert counters[(parent, f"graph.{k}.converged")] == 1
+    assert len(counters) == 6
+
+
+def test_graph_kernels_leave_the_analyze_program_alone(tmp_path):
+    """The default config compiles ``analyze`` alone: three sorts, nothing
+    of the graph in it.  Graph kernels beside the queries run the same
+    analysis, which hands its plan pair over (still three sorts, the same
+    results), and the graph phase's plan program then sorts nothing."""
+    from repro.challenge.pipeline import _analyze_and_plans, _jitted
+
+    kw = dict(n_windows=2, ip_bins=32, k=3, backend="auto",
+              fused_epilogue=False)
+    runs = []
+    for kernels in ((), ("bfs", "wcc", "pagerank")):
+        cfg = ChallengeConfig(scale=8, n_windows=2, ip_bins=32, top_k=3,
+                              warm=False, workdir=str(tmp_path),
+                              analytics=Analytics(kernels=kernels))
+        runs.append(run_challenge(cfg))
+    t = runs[0].anon_table
+    text = _jitted(analyze, **kw).lower(t).compile().as_text()
+    assert count_hlo_sorts(text) == 3
+    assert "graph/" not in text
+    shared = _jitted(_analyze_and_plans, **kw)
+    assert count_hlo_sorts(shared.lower(t).compile().as_text()) == 3
+    _, plans = shared(t)
+    assert count_hlo_sorts(jax.jit(graph_plan).lower(
+        t, 256, -1, plans).compile().as_text()) == 0
+    for a_, b in zip(jax.tree.leaves(runs[0].results),
+                     jax.tree.leaves(runs[1].results)):
+        np.testing.assert_array_equal(np.asarray(a_), np.asarray(b))
+
+
+def test_graph_phase_refuses_a_vertex_domain_past_2_24(tmp_path):
+    """BFS levels and WCC labels ride float32, exact below 2**24 ids: a
+    table whose vertex domain 2 * capacity passes that is refused before
+    anything is read."""
+    assert graph_vertices(1 << 23) == 1 << 24
+    cfg = ChallengeConfig(scale=8, capacity=(1 << 23) + 1, warm=False,
+                          workdir=str(tmp_path),
+                          analytics=Analytics(kernels=("wcc",)))
+    with pytest.raises(ValueError, match="2\\*\\*24"):
+        run_challenge(cfg)
+    assert not list(tmp_path.iterdir())

@@ -1,11 +1,14 @@
 """End-to-end pipeline tests: repro.challenge phases vs the NumPy oracle,
 plus the new semi-join / isin / top-k relational ops."""
+import dataclasses
+
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.challenge import (
+    Analytics,
     ChallengeConfig,
     analyze,
     cross_window_ip_overlap,
@@ -234,29 +237,37 @@ def test_challenge_read_cache_reuses_capture(tmp_path):
 
 
 # one case per static of the phase programs' cache key: (base config, the
-# static changed, the phase whose program that static shapes)
+# static changed, the phase programs traced anew).  Kernels beside the
+# queries make analyze hand its plan pair to the graph phase, a program of
+# its own.  The BFS source is an operand of the BFS program, so changing it
+# traces nothing.
+_BFS = Analytics(kernels=("bfs",))
 _STATIC_CASES = {
-    "method": ({}, {"method": "hash"}, "anonymize"),
-    "rounds": ({}, {"rounds": 2}, "anonymize"),
-    "n_windows": ({}, {"n_windows": 2}, "analyze"),
-    "ip_bins": ({}, {"ip_bins": 32}, "analyze"),
-    "top_k": ({}, {"top_k": 3}, "analyze"),
-    "backend": ({}, {"backend": "xla"}, "analyze"),
-    "fused_epilogue": ({}, {"fused_epilogue": True}, "analyze"),
-    "algorithms": ({}, {"algorithms": True}, "analyze"),
-    "bfs_source": ({"algorithms": True}, {"bfs_source": 5}, "analyze"),
+    "method": ({}, {"method": "hash"}, {"anonymize"}),
+    "rounds": ({}, {"rounds": 2}, {"anonymize"}),
+    "n_windows": ({}, {"n_windows": 2}, {"analyze"}),
+    "ip_bins": ({}, {"ip_bins": 32}, {"analyze"}),
+    "top_k": ({}, {"top_k": 3}, {"analyze"}),
+    "backend": ({}, {"backend": "xla"}, {"analyze"}),
+    "fused_epilogue": ({}, {"fused_epilogue": True}, {"analyze"}),
+    "algorithms": ({}, {"analytics": _BFS},
+                   {"analyze", "graph/plan", "graph/bfs"}),
+    "bfs_source": ({"analytics": _BFS},
+                   {"analytics": dataclasses.replace(_BFS, bfs_source=5)},
+                   set()),
 }
 
 
 @pytest.mark.parametrize("static", list(_STATIC_CASES))
 def test_phase_program_cache_keys_every_static(tmp_path, static):
     """A run with one static changed after a run with it at its default
-    builds the changed phase's program anew and answers as ``anonymize``
-    and ``analyze`` jitted afresh with that static."""
+    builds the changed phase's program anew, and only it, and answers as
+    the phase functions jitted afresh with that static."""
+    from repro.challenge.pipeline import _graph_statics, graph_kernel, graph_plan
     from repro.core.anonymize import anonymize
     from repro.obs import get_tracer, reset_tracer
 
-    base, change, phase = _STATIC_CASES[static]
+    base, change, phases = _STATIC_CASES[static]
     # a capacity no other test compiles, so the changed program is new here
     common = dict(scale=8, n_packets=256, capacity=256 + 19, n_windows=3,
                   ip_bins=64, top_k=5, warm=False, workdir=str(tmp_path))
@@ -267,10 +278,7 @@ def test_phase_program_cache_keys_every_static(tmp_path, static):
     traced = {r["parent"] for r in get_tracer().records()
               if r["kind"] == "counter" and r["name"] == "jit.trace_s"}
     reset_tracer()
-    assert f"challenge/{phase}/dispatch" in traced
-    assert not {f"challenge/{p}/dispatch"
-                for p in ("build_device", "anonymize", "analyze")
-                if p != phase} & traced
+    assert traced == {f"challenge/{p}/dispatch" for p in phases}
 
     src, dst, win, n = build_columns(run.capture, cfg)
     anon = jax.jit(lambda t, k: anonymize(
@@ -278,12 +286,21 @@ def test_phase_program_cache_keys_every_static(tmp_path, static):
         build_table(src, dst, win, n), jax.random.key(cfg.seed))
     want = jax.jit(lambda t: analyze(
         t, n_windows=cfg.n_windows, ip_bins=cfg.ip_bins, k=cfg.top_k,
-        backend=cfg.backend, fused_epilogue=cfg.fused_epilogue,
-        algorithms=cfg.algorithms, bfs_source=cfg.bfs_source))(anon.table)
-    for got, ref in ((run.anon_table, anon.table), (run.results, want)):
+        backend=cfg.backend, fused_epilogue=cfg.fused_epilogue))(anon.table)
+    pairs = [(run.anon_table, anon.table), (run.results, want)]
+    a = cfg.analytics
+    if a.kernels:
+        g = jax.jit(graph_plan)(anon.table, anon.n_ips,
+                                -1 if a.bfs_source is None else a.bfs_source)
+        for k in a.kernels:
+            pairs.append((run.graph.kernels[k], jax.jit(
+                lambda g, k=k: graph_kernel(
+                    g, kernel=k, n_vertices=2 * cfg.table_capacity,
+                    backend=cfg.backend, **_graph_statics(a, k)))(g)))
+    for got, ref in pairs:
         assert jax.tree.structure(got) == jax.tree.structure(ref)
-        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        for a_, b in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+            np.testing.assert_array_equal(np.asarray(a_), np.asarray(b))
 
 
 def test_analyze_is_one_jittable_call():

@@ -78,10 +78,18 @@ def test_analyze_names_the_histogram_kernel(analyze_names):
 
 
 def test_analyze_names_the_algorithm_pass_when_on():
-    names = _op_names(lambda t: analyze(t, n_windows=4, ip_bins=64, k=5,
-                                        backend="xla", algorithms=True),
-                      _table(256))
-    assert _has_scope(names, "analyze/algorithms")
+    """The graph phase's programs name their parts graph/plan, graph/csr
+    and graph/<kernel>."""
+    from repro.challenge.pipeline import GRAPH_KERNELS, graph_kernel, graph_plan
+
+    t = _table(256)
+    names = _op_names(graph_plan, t, 97)
+    assert _has_scope(names, "graph/plan") and _has_scope(names, "graph/csr")
+    g = jax.eval_shape(graph_plan, t, 97)
+    for k in GRAPH_KERNELS:
+        names = _op_names(lambda g, k=k: graph_kernel(
+            g, kernel=k, n_vertices=512, backend="xla"), g)
+        assert _has_scope(names, f"graph/{k}"), k
 
 
 def test_naive_analyze_uses_the_same_family_names():
