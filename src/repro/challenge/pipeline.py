@@ -746,8 +746,8 @@ class TrafficGraph:
 
     ``csr_src`` is A (rows by source: out-links, weighted by packets),
     ``csr_dst`` its transpose, ``keys_src``/``keys_dst`` their entries' row
-    keys (``entry_row_key(0)``, a binary search each kernel step would
-    repeat otherwise); ``n_live`` counts the vertices (ids are
+    keys (``entry_row_key(0)``, a scatter and a prefix sum each kernel
+    step would repeat otherwise); ``n_live`` counts the vertices (ids are
     ``[0, n_live)``); ``source`` is the BFS source."""
 
     csr_src: object

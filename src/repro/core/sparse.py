@@ -101,14 +101,20 @@ class CsrMatrix:
     def entry_rows(self) -> jnp.ndarray:
         """Row id of each stored entry (``row_capacity`` on padding slots).
 
-        Derived from the row pointers — entry i belongs to row r iff
-        ``indptr[r] <= i < indptr[r + 1]`` — by one binary search per entry,
-        the inverse of the CSR compression (no stored per-entry row array).
+        The inverse of the CSR compression (no stored per-entry row array):
+        entry i belongs to row r iff ``indptr[r] <= i < indptr[r + 1]``, so
+        its row is the number of row pointers at or below i, less one.  A 1
+        scattered at each pointer below ``nnz`` and a prefix sum count them
+        in O(row_capacity + nnz_capacity); the padding rows, all pointing
+        at ``nnz``, scatter nothing.  Row pointers never decrease, so the
+        scatter's indices come sorted and XLA adds no sort to order them.
         """
-        idx = jnp.arange(self.nnz_capacity, dtype=jnp.int32)
-        rows = (
-            jnp.searchsorted(self.indptr, idx, side="right").astype(jnp.int32) - 1
-        )
+        cap = self.nnz_capacity
+        idx = jnp.arange(cap, dtype=jnp.int32)
+        starts = jnp.where(self.indptr < self.nnz, self.indptr, cap)
+        marks = jnp.zeros((cap,), jnp.int32).at[starts].add(
+            1, mode="drop", indices_are_sorted=True)
+        rows = jnp.cumsum(marks, dtype=jnp.int32) - 1
         return jnp.where(idx < self.nnz, rows, self.row_capacity)
 
     def entry_row_key(
@@ -117,8 +123,8 @@ class CsrMatrix:
         """Expand row key column ``k`` back to per-entry granularity.
 
         ``rows`` lets a caller expanding several key columns reuse one
-        :meth:`entry_rows` pass (eager execution repeats the binary search
-        otherwise; under jit XLA CSE dedupes it either way).
+        :meth:`entry_rows` pass (eager execution repeats the scatter and
+        prefix sum otherwise; under jit XLA CSE dedupes it either way).
         """
         key = self.row_keys[k]
         if rows is None:

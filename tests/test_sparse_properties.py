@@ -12,16 +12,21 @@ fixed examples otherwise):
   * ``mxv``/``vxm`` are dual through :func:`transpose` — exact for the
     min/max monoids, allclose for plus (summation order differs);
   * ``transpose``/``symmetrize``/min-monoid reductions agree with dense
-    NumPy / scipy oracles.
+    NumPy / scipy oracles;
+  * ``entry_rows`` inverts the row pointers exactly as a binary search
+    would, eager and under jit, and lowers to no loop.
 """
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
 from _hypothesis_compat import given, settings, st
 
+from repro.core.plan import sorted_edges
 from repro.core.sparse import (
     CsrMatrix,
+    csr_from_plan,
     ewise_union,
     from_coo,
     gather_rows,
@@ -254,3 +259,88 @@ def test_transpose_rejects_multi_key_rows():
     )
     with pytest.raises(ValueError, match="1-column row key"):
         transpose(multi)
+
+
+# ------------------------------------------------------------- entry_rows
+
+def _csr_from_degrees(degrees, row_capacity, nnz_capacity):
+    """A CSR whose live rows hold ``degrees`` entries each (zeros allowed)."""
+    n_rows, nnz = len(degrees), int(sum(degrees))
+    indptr = np.full(row_capacity + 1, nnz, np.int32)
+    indptr[: n_rows + 1] = np.concatenate([[0], np.cumsum(degrees)])
+    keys = np.full(row_capacity, np.iinfo(np.int32).max, np.int32)
+    keys[:n_rows] = np.arange(n_rows)
+    cols = np.full(nnz_capacity, np.iinfo(np.int32).max, np.int32)
+    cols[:nnz] = np.arange(nnz)
+    return CsrMatrix(
+        row_keys=(jnp.asarray(keys),), indptr=jnp.asarray(indptr),
+        col_keys=jnp.asarray(cols), vals=jnp.zeros((nnz_capacity,), jnp.int32),
+        n_rows=jnp.asarray(n_rows, jnp.int32), nnz=jnp.asarray(nnz, jnp.int32),
+    )
+
+
+def _random_edges(seed, n, cap, hi):
+    rng = np.random.default_rng(seed)
+    rows = np.full(cap, 0, np.int32)
+    cols = np.full(cap, 0, np.int32)
+    rows[:n] = rng.integers(0, hi, n)
+    cols[:n] = rng.integers(0, hi, n)
+    return jnp.asarray(rows), jnp.asarray(cols)
+
+
+def _plan_case():
+    rows, cols = _random_edges(3, 150, 200, 40)
+    plan = sorted_edges(rows, cols, n_valid=150)
+    return csr_from_plan(plan), np.asarray(plan.link_to_k0()[:plan.capacity])
+
+
+def _coo_case():
+    rows, cols = _random_edges(5, 90, 128, 25)
+    csr, dropped = from_coo(
+        [rows], cols, jnp.ones((128,), jnp.int32),
+        n_valid=jnp.asarray(90, jnp.int32), nnz_capacity=96, row_capacity=48,
+    )
+    assert int(dropped) == 0
+    return csr, None
+
+
+ENTRY_ROWS_CASES = {
+    "empty_rows_middle": lambda: (
+        _csr_from_degrees([2, 0, 0, 3, 1, 0, 2], 9, 12), None),
+    "empty_rows_first": lambda: (
+        _csr_from_degrees([0, 0, 3, 0, 2], 6, 8), None),
+    "nnz_zero": lambda: (_csr_from_degrees([], 5, 7), None),
+    "nnz_zero_live_rows": lambda: (_csr_from_degrees([0, 0], 5, 7), None),
+    "nnz_full": lambda: (_csr_from_degrees([3, 1, 0, 4], 6, 8), None),
+    "rows_above_nnz": lambda: (_csr_from_degrees([1, 0, 2, 1], 16, 6), None),
+    "rows_below_nnz": lambda: (_csr_from_degrees([7, 0, 9], 3, 20), None),
+    "from_plan": _plan_case,
+    "from_coo": _coo_case,
+}
+
+
+@pytest.mark.parametrize("jit", [False, True], ids=["eager", "jit"])
+@pytest.mark.parametrize("case", sorted(ENTRY_ROWS_CASES))
+def test_entry_rows_inverts_row_pointers(case, jit):
+    """Each entry's row is the binary search of its slot in the row
+    pointers, ``row_capacity`` past ``nnz``; off a plan, it is also the
+    plan's own link -> key0 map."""
+    csr, link_to_k0 = ENTRY_ROWS_CASES[case]()
+    f = jax.jit(lambda c: c.entry_rows()) if jit else (lambda c: c.entry_rows())
+    got = np.asarray(f(csr))
+    idx = np.arange(csr.nnz_capacity)
+    want = np.searchsorted(np.asarray(csr.indptr), idx, side="right") - 1
+    want = np.where(idx < int(csr.nnz), want, csr.row_capacity)
+    assert got.dtype == np.int32
+    np.testing.assert_array_equal(got, want)
+    if link_to_k0 is not None:
+        np.testing.assert_array_equal(got, link_to_k0)
+
+
+def test_entry_rows_lowers_to_no_loop():
+    """The row map is a scatter and a prefix sum: no ``while`` (a binary
+    search lowers to one of log2(rows) steps over every entry)."""
+    csr = _csr_from_degrees(
+        np.random.default_rng(0).integers(0, 4, 1 << 12), 1 << 12, 1 << 14)
+    text = jax.jit(lambda c: c.entry_rows()).lower(csr).as_text()
+    assert "while" not in text
