@@ -1,21 +1,10 @@
-"""Paper Fig. 2: jaxdf vs a GraphBLAS-style sparse-matrix reference,
-plus the in-repo GraphBLAS-lite CSR A/B (DESIGN.md §2.4).
+"""Paper Fig. 2: jaxdf vs a GraphBLAS-style sparse-matrix reference.
 
 The challenge's verification path formulates every query over the traffic
 matrix A_t in sparse linear algebra.  scipy.sparse.csr_matrix plays the
 SuiteSparse-GraphBLAS role here (same formulation: 1^T A 1, |A|_0, A·1,
 |A|_0·1, max(...)), giving the paper's "data science vs GraphBLAS"
-comparison on identical hardware.  Since PR 5 the repo speaks that matrix
-language natively (``core/sparse.py``), so this section also runs the
-head-to-head the ISSUE gates on:
-
-  * ``run_all_queries`` (group-by form) vs ``run_all_queries_csr`` (CSR
-    reductions) — equality-asserted, both 3-sort;
-  * the windowed suite, dense-grid vs CSR-scan formulation —
-    equality-asserted, with the compiled-HLO peak-buffer estimate
-    (``launch/hloanalysis.peak_buffer_bytes``) of the full ``analyze``
-    program under each method: the O(n_windows × capacity) vs O(nnz)
-    memory claim, measured.
+comparison on identical hardware, equality-asserted query by query.
 
 Rows are written machine-readably to ``BENCH_graphblas.json`` when a path
 is given — joining the ``BENCH_queries.json`` trajectory emitted by
@@ -34,15 +23,9 @@ import jax.numpy as jnp
 import numpy as np
 import scipy.sparse as sp
 
-from repro.challenge.pipeline import analyze_peak_buffer_bytes
-from repro.core import Table, run_all_queries, run_all_queries_csr
-from repro.core.temporal import windowed_queries
+from repro.core import Table, run_all_queries
 
 from .common import device_kind, emit, packet_arrays, run_manifest, time_fn
-
-# the memory A/B compiles analyze twice; a larger window axis makes the
-# dense grids' O(n_windows × capacity) term dominate (tests pin >= 4x here)
-MEMORY_AB_WINDOWS = 32
 
 
 def graphblas_all_queries(src, dst, n_vertices: int):
@@ -83,76 +66,24 @@ def run(
     t = Table.from_dict({"src": jnp.asarray(src), "dst": jnp.asarray(dst)})
 
     jall = jax.jit(run_all_queries)
-    jcsr = jax.jit(run_all_queries_csr)
     t_jax = time_fn(jall, t, iters=iters)
-    t_csr = time_fn(jcsr, t, iters=iters)
     t_gb = time_fn(lambda: graphblas_all_queries(src, dst, n_vertices), iters=iters)
 
-    res, res_csr = jall(t), jcsr(t)
+    res = jall(t)
     ref = graphblas_all_queries(src, dst, n_vertices)
     ok = all(int(getattr(res, k)) == v for k, v in ref.items())
-    ok_csr = all(int(getattr(res_csr, k)) == v for k, v in ref.items())
-    if not (ok and ok_csr):
-        raise AssertionError(
-            f"scalar suite diverges from scipy-CSR reference "
-            f"(groupby ok={ok}, csr ok={ok_csr})"
-        )
+    if not ok:
+        raise AssertionError("scalar suite diverges from scipy-CSR reference")
     record("jaxdf_all14", t_jax, f"vs_scipy_csr={t_gb / t_jax:.2f}x correct={ok} n={n}")
-    record("csr_all14", t_csr,
-           f"matrix-language form, {t_jax / t_csr:.2f}x of groupby form "
-           f"correct={ok_csr} n={n}")
     record("scipy_csr_all14", t_gb, f"n={n} reference")
 
-    # ---- windowed suite: dense-grid vs CSR-scan A/B (equality-asserted) ----
-    nw = 16
-    rng = np.random.default_rng(0)
-    ts = jnp.asarray(np.sort(rng.integers(0, 1 << 20, n)).astype(np.int32))
-    tw = Table.from_dict({"src": jnp.asarray(src), "dst": jnp.asarray(dst),
-                          "ts": ts})
-    wlen = (1 << 20) // nw
-    jw_csr = jax.jit(lambda t: windowed_queries(t, wlen, nw, method="csr"))
-    jw_grid = jax.jit(lambda t: windowed_queries(t, wlen, nw, method="grid"))
-    t_wcsr = time_fn(jw_csr, tw, iters=iters)
-    t_wgrid = time_fn(jw_grid, tw, iters=iters)
-    a, b = jw_csr(tw), jw_grid(tw)
-    for k in a:
-        if not np.array_equal(np.asarray(a[k]), np.asarray(b[k])):
-            raise AssertionError(f"windowed csr/grid mismatch on {k}")
-    record("windowed_csr", t_wcsr, f"{nw} windows, O(nnz) memory n={n}")
-    record("windowed_grid", t_wgrid,
-           f"dense baseline, csr={t_wgrid / t_wcsr:.2f}x of grid wall n={n}")
-
-    # ---- peak-HBM A/B of the full analyze program (compile-only; shared
-    # harness with tests/test_memory_budget.py) -----------------------------
-    mem_n = min(n, 1 << 17)
-    pk_csr = analyze_peak_buffer_bytes(
-        mem_n, windowed_method="csr", n_windows=MEMORY_AB_WINDOWS)
-    pk_grid = analyze_peak_buffer_bytes(
-        mem_n, windowed_method="grid", n_windows=MEMORY_AB_WINDOWS)
-    emit("graphblas/analyze_peak_bytes", 0.0,
-         f"csr={pk_csr / 1e6:.1f}MB grid={pk_grid / 1e6:.1f}MB "
-         f"ratio={pk_grid / pk_csr:.2f}x at n={mem_n} nw={MEMORY_AB_WINDOWS}")
-    rows["analyze_peak_bytes"] = {
-        "us_per_call": 0.0,
-        "csr_peak_bytes": pk_csr,
-        "grid_peak_bytes": pk_grid,
-        "grid_over_csr": pk_grid / pk_csr,
-        "n": float(mem_n),
-        "n_windows": float(MEMORY_AB_WINDOWS),
-    }
-
-    # ---- roofline: both scalar-suite programs + the windowed CSR scan,
-    # each against the already-measured steady wall of its own compiled
-    # program (launch/roofline.program_roofline, ROADMAP item 5) ----
+    # ---- roofline: the scalar-suite program against its already-measured
+    # steady wall (launch/roofline.program_roofline, ROADMAP item 5) ----
     from repro.launch.roofline import program_roofline
 
     roofline = {
-        "csr_all14": program_roofline(
-            jcsr.lower(t).compile().as_text(), t_csr, device_kind()),
         "jaxdf_all14": program_roofline(
             jall.lower(t).compile().as_text(), t_jax, device_kind()),
-        "windowed_csr": program_roofline(
-            jw_csr.lower(tw).compile().as_text(), t_wcsr, device_kind()),
     }
     for kname, rf in roofline.items():
         emit(f"roofline/{kname}", rf["wall_s"],

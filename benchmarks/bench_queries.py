@@ -2,17 +2,15 @@
 sequential NumPy oracle (the single-core "Pandas" role).
 
 Reports each of the challenge queries individually (as the paper's Fig. 1
-does), the all-14-queries pipeline, and — with ``ab=True`` (CLI ``--ab``) —
-the sort-once plan vs the pre-plan implementation head-to-head
-(DESIGN.md §2.3), asserting query-for-query equality against the
-``core/ref.py`` oracle for both.
+does) and the all-14-queries pipeline, asserting query-for-query equality
+against the ``core/ref.py`` oracle.
 
 Every row is also recorded machine-readably (steady-state us/call + the
 number of sort ops in the query's compiled HLO) and written to
 ``BENCH_queries.json`` when a path is given — the trajectory file
 ``benchmarks/run.py`` emits.
 
-    PYTHONPATH=src python -m benchmarks.bench_queries --ab [--n N] [--json P]
+    PYTHONPATH=src python -m benchmarks.bench_queries [--n N] [--json P]
 """
 from __future__ import annotations
 
@@ -24,11 +22,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import Table, run_all_queries, run_all_queries_naive
+from repro.core import Table, run_all_queries
 from repro.core import queries as Q
 from repro.core.plan import count_hlo_sorts
 from repro.core.ref import ref_run_all_queries, ref_traffic_matrix
-from repro.core.temporal import windowed_queries, windowed_queries_naive
+from repro.core.temporal import windowed_queries
 
 from .common import (device_kind, emit, kernel_roofline, packet_arrays,
                      run_manifest, time_fn)
@@ -69,7 +67,6 @@ def _assert_oracle(res, ref: Dict[str, int], label: str) -> None:
 def run(
     n: int = 1 << 20,
     iters: int = 3,
-    ab: bool = False,
     json_path: Optional[str] = None,
 ) -> Dict[str, Dict[str, float]]:
     rows: Dict[str, Dict[str, float]] = {}
@@ -111,37 +108,10 @@ def run(
                           "ts": ts})
     jwin = jax.jit(lambda t: windowed_queries(t, (1 << 20) // 16, 16))
     t_win = time_fn(jwin, tw, iters=iters)
-    # since the CSR refactor (DESIGN.md §2.4) this row measures the sparse
-    # O(nnz)-memory scan — mark the formulation so trajectory readers can
-    # attribute the wall-time step; the grid A/B lives in BENCH_graphblas
     record("windowed16_pipeline", t_win,
-           f"16 windows fused (method=csr), "
+           f"16 windows fused, "
            f"{t_win / t_all:.2f}x of single-window cost n={n}",
            sorts=_hlo_sorts(jwin, tw))
-
-    if ab:
-        # ---- plan vs naive A/B: same scalars, same oracle, head-to-head ----
-        jnaive = jax.jit(run_all_queries_naive)
-        t_naive = time_fn(jnaive, t, iters=iters)
-        res_plan, res_naive = jall(t), jnaive(t)
-        _assert_oracle(res_naive, ref, "all14_naive")
-        for k in ref:
-            a, b = int(getattr(res_plan, k)), int(getattr(res_naive, k))
-            if a != b:
-                raise AssertionError(f"plan/naive mismatch on {k}: {a} != {b}")
-        record("all14_naive", t_naive,
-               f"plan_speedup={t_naive / t_all:.2f}x correct=True n={n}",
-               sorts=_hlo_sorts(jnaive, t))
-        jwin_naive = jax.jit(
-            lambda t: windowed_queries_naive(t, (1 << 20) // 16, 16))
-        t_win_naive = time_fn(jwin_naive, tw, iters=iters)
-        wa, wb = jwin(tw), jwin_naive(tw)
-        for k in wa:
-            if not np.array_equal(np.asarray(wa[k]), np.asarray(wb[k])):
-                raise AssertionError(f"windowed plan/naive mismatch on {k}")
-        record("windowed16_naive", t_win_naive,
-               f"plan_speedup={t_win_naive / t_win:.2f}x correct=True n={n}",
-               sorts=_hlo_sorts(jwin_naive, tw))
 
     # ---- roofline: the challenge kernels + the all-14 program, achieved
     # bytes/s and flops/s vs the backend peak (ROADMAP item 5; the fractions
@@ -154,7 +124,7 @@ def run(
              f"{rf['achieved_bytes_per_s'] / 1e9:.2f} GB/s)")
 
     if json_path:
-        payload = {"n": n, "iters": iters, "ab": ab,
+        payload = {"n": n, "iters": iters,
                    "backend": jax.default_backend(), "rows": rows,
                    "roofline": roofline, "manifest": run_manifest()}
         with open(json_path, "w") as f:
@@ -206,14 +176,12 @@ def main(argv=None) -> int:
     ap.add_argument("--n", type=int, default=1 << 20)
     ap.add_argument("--quick", action="store_true", help="n = 2^14")
     ap.add_argument("--iters", type=int, default=3)
-    ap.add_argument("--ab", action="store_true",
-                    help="plan-vs-naive A/B with equality asserts")
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="write machine-readable rows (BENCH_queries.json)")
     args = ap.parse_args(argv)
     n = (1 << 14) if args.quick else args.n
     print("name,us_per_call,derived")
-    run(n=n, iters=args.iters, ab=args.ab, json_path=args.json)
+    run(n=n, iters=args.iters, json_path=args.json)
     return 0
 
 

@@ -15,11 +15,9 @@ Emits ``name,us_per_call,derived`` CSV rows:
 
 The query section always writes its rows machine-readably (steady-state
 us/call + compiled-HLO sort counts per op) to ``--bench-json``
-(default ``BENCH_queries.json``) — the bench trajectory file; ``--ab`` adds
-the plan-vs-naive head-to-head rows (DESIGN.md §2.3).  The graphblas
-section likewise writes ``--graphblas-json`` (default
-``BENCH_graphblas.json``): the scipy-CSR reference plus the in-repo
-dense-grid vs CSR A/B with the compiled peak-HBM estimate (DESIGN.md §2.4).
+(default ``BENCH_queries.json``) — the bench trajectory file.  The
+graphblas section likewise writes ``--graphblas-json`` (default
+``BENCH_graphblas.json``): the suite against the scipy-CSR reference.
 The algorithms section writes ``--algorithms-json`` (default
 ``BENCH_algorithms.json``): per-algorithm walls with oracle-parity flags
 plus the graph phase's HLO sort count (DESIGN.md §2.5).
@@ -33,7 +31,7 @@ The kernel section writes ``--kernels-json`` (default
 medians, chosen vs default config, cache-hit flag, roofline fraction of
 the chosen config (DESIGN.md §2.9).
 
-``python -m benchmarks.run [--quick] [--n N] [--only PREFIX] [--ab]
+``python -m benchmarks.run [--quick] [--n N] [--only PREFIX]
 [--bench-json PATH] [--graphblas-json PATH] [--algorithms-json PATH]
 [--sketches-json PATH] [--serve-json PATH] [--kernels-json PATH]``
 """
@@ -49,12 +47,10 @@ def main() -> None:
     ap.add_argument("--n", type=int, default=1 << 20)
     ap.add_argument("--quick", action="store_true", help="n = 2^17")
     ap.add_argument("--only", default=None)
-    ap.add_argument("--ab", action="store_true",
-                    help="query section: plan-vs-naive A/B rows")
     ap.add_argument("--bench-json", default="BENCH_queries.json",
                     help="machine-readable query rows (empty string disables)")
     ap.add_argument("--graphblas-json", default="BENCH_graphblas.json",
-                    help="machine-readable graphblas A/B rows "
+                    help="machine-readable graphblas rows "
                          "(empty string disables)")
     ap.add_argument("--algorithms-json", default="BENCH_algorithms.json",
                     help="machine-readable graph-algorithm rows "
@@ -82,7 +78,7 @@ def main() -> None:
     sections = [
         ("io", lambda: bench_io.run(n=n)),
         ("query", lambda: bench_queries.run(
-            n=n, ab=args.ab, json_path=args.bench_json or None)),
+            n=n, json_path=args.bench_json or None)),
         ("graphblas", lambda: bench_graphblas.run(
             n=n, json_path=args.graphblas_json or None)),
         ("algorithms", lambda: bench_algorithms.run(

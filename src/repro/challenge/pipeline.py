@@ -49,27 +49,25 @@ import numpy as np
 from ..core import algorithms as algo
 from ..core.anonymize import anonymize
 from ..obs import counter_event, span as obs_span
-from ..core.ops import factorize, groupby_aggregate, mix32, semi_join, unique
+from ..core.ops import factorize, mix32
 from ..core.plan import lead_fanout, lead_groups, link_groups, unique_lead
 from ..core.queries import (
     QueryResults,
     TopLinks,
     packet_weights,
-    run_all_queries_naive,
     scalar_queries_from_plans,
     table_csrs,
     table_plans,
-    top_links,
     top_links_from_plan,
     traffic_matrix,
     unique_ips,
 )
 from ..core.table import Table
-from ..core.temporal import windowed_queries, windowed_queries_naive
+from ..core.temporal import windowed_queries
 from ..data import pcaplite
 from ..data.plq import read_plq, write_plq
 from ..data.rmat import synthetic_packets
-from ..kernels.ops import histogram, windowed_histogram
+from ..kernels.ops import windowed_histogram
 
 __all__ = [
     "Analytics",
@@ -81,7 +79,6 @@ __all__ = [
     "GraphRun",
     "TrafficGraph",
     "cross_window_ip_overlap",
-    "cross_window_ip_overlap_naive",
     "analyze",
     "analyze_peak_buffer_bytes",
     "distributed_scalar_queries",
@@ -174,7 +171,6 @@ class ChallengeConfig:
     fmt: str = "plq"                     # 'plq' | 'pcaplite'
     backend: str = "auto"                # histogram kernel dispatch
     fused: bool = False                  # also time the one-program path
-    fused_epilogue: bool = False         # fused kernel epilogues in analyze
     distributed: bool = False            # scalar suite via shard_map
     analytics: Analytics = Analytics()   # Table III suite and/or graph kernels
     workdir: Optional[str] = None        # capture cache dir (tmp if None)
@@ -449,8 +445,7 @@ def build_table(src, dst, win, n_valid) -> Table:
 # ---------------------------------------------------------------------------
 
 def cross_window_ip_overlap(
-    t: Table, n_windows: int, backend: str = "auto",
-    ips: Optional[object] = None, method: str = "scan",
+    t: Table, n_windows: int, ips: Optional[object] = None
 ) -> jnp.ndarray:
     """overlap[w] = |distinct IPs active in window w AND window w-1|.
 
@@ -459,40 +454,23 @@ def cross_window_ip_overlap(
     with the scalar suite when the caller passes ``ips``) is a binary
     search, so per-window IP activity is a boolean presence vector over IP
     ranks and adjacent-window AND + popcount answers the persistence
-    question with ZERO sorts beyond the shared one.  The pre-plan
-    formulation re-sorted what the group-by had just sorted (see
-    :func:`cross_window_ip_overlap_naive`).  overlap[0] == 0 by
+    question with ZERO sorts beyond the shared one.  overlap[0] == 0 by
     construction.
 
-    ``method="scan"`` (default, DESIGN.md §2.4) walks the window axis with
-    a ``lax.scan`` carrying ONE window's presence vector — O(ip_capacity)
-    peak memory; ``method="grid"`` scatters the full
-    ``(n_windows + 1, ip_capacity + 1)`` presence grid at once — the dense
-    A/B baseline, O(n_windows × ip_capacity) peak, bit-identical results.
-    ``backend`` is accepted for signature compatibility; no histogram
-    dispatch remains on this path.
+    A ``lax.scan`` walks the window axis carrying ONE window's presence
+    vector — O(ip_capacity) peak memory (DESIGN.md §2.4).
     """
-    del backend
     if ips is None:
         ips = unique_ips(t)
     valid = t.valid_mask()
     nw = n_windows
     ip_cap = ips.values.shape[0]
-    # out-of-range window ids are DROPPED (dump row), matching the naive
-    # path's histogram semantics — not clamped into the edge windows
+    # out-of-range window ids are DROPPED (dump row), not clamped into the
+    # edge windows
     in_range = valid & (t["win"] >= 0) & (t["win"] < nw)
     win = jnp.where(in_range, t["win"], nw)
     r_src = jnp.minimum(factorize(t["src"], ips.values), ip_cap)
     r_dst = jnp.minimum(factorize(t["dst"], ips.values), ip_cap)
-    if method == "grid":
-        grid = jnp.zeros((nw + 1, ip_cap + 1), jnp.bool_)
-        grid = grid.at[win, r_src].set(True)
-        grid = grid.at[win, r_dst].set(True)
-        live = grid[:nw, :ip_cap]
-        overlap = jnp.sum(live[1:] & live[:-1], axis=1, dtype=jnp.int32)
-        return jnp.concatenate([jnp.zeros((1,), jnp.int32), overlap])
-    if method != "scan":
-        raise ValueError(f"unknown overlap method {method!r}")
 
     def one_window(prev, w):
         cur = jnp.zeros((ip_cap + 1,), jnp.bool_)
@@ -506,36 +484,6 @@ def cross_window_ip_overlap(
         jnp.arange(nw, dtype=jnp.int32),
     )
     return overlap
-
-
-def cross_window_ip_overlap_naive(
-    t: Table, n_windows: int, backend: str = "auto"
-) -> jnp.ndarray:
-    """Pre-plan overlap: distinct (window, ip) pairs (one group-by over both
-    endpoints), then a semi-join of (w, ip) against (w'+1, ip) — which
-    re-sorts the rows the group-by just sorted — then one histogram dispatch
-    to count members per window.  A/B baseline for the plan path.
-
-    Window ids >= n_windows are dropped by the final histogram (identical to
-    the plan path).  A *negative* window id would leak into ``overlap[0]``
-    here via the w+1 shift, violating the documented overlap[0] == 0
-    contract — the plan path drops it instead; every in-repo caller clips
-    window ids upstream, so the two paths agree on all reachable inputs."""
-    valid = t.valid_mask()
-    win2 = jnp.concatenate([t["win"], t["win"]])
-    ip2 = jnp.concatenate([t["src"], t["dst"]])
-    mask2 = jnp.concatenate([valid, valid])
-    wip = groupby_aggregate([win2, ip2], None, valid_mask=mask2)
-    member = semi_join(
-        [wip.keys[0], wip.keys[1]],
-        [wip.keys[0] + 1, wip.keys[1]],
-        left_n_valid=wip.n_groups,
-        right_n_valid=wip.n_groups,
-    )
-    counts = histogram(
-        jnp.where(member, wip.keys[0], -1), n_windows, backend=backend
-    )
-    return counts.astype(jnp.int32)
 
 
 def _window_activity(t: Table, n_windows: int, ip_bins: int, backend: str):
@@ -559,9 +507,6 @@ def analyze(
     ip_bins: int,
     k: int,
     backend: str = "auto",
-    use_plan: bool = True,
-    windowed_method: str = "csr",
-    fused_epilogue: bool = False,
     plans=None,
 ) -> ChallengeResults:
     """Every challenge statistic in one jit-able call.
@@ -572,35 +517,16 @@ def analyze(
     Scalars, vector queries, fan-out/fan-in, top-k, the windowed suite and
     the cross-window overlap all derive from that shared ``SortedEdges``
     pair + sorted IP domain with zero additional sorts (asserted on the
-    lowered HLO in tests/test_plan.py).  The windowed suite defaults to the
-    sparse CSR formulation (DESIGN.md §2.4, O(nnz) peak memory);
-    ``windowed_method="grid"`` keeps the dense-scatter A/B baseline
-    (O(n_windows × capacity) peak).  ``use_plan=False`` runs the pre-plan
-    formulation — ~10 independent group-by sorts that XLA CSE can only
-    partially dedupe — as the A/B baseline; all paths return bit-identical
-    results.
-
-    ``fused_epilogue=True`` routes the analyze phase's two remaining
-    scatter/gather chains — the windowed suite's per-window slice select
-    and the top-k pre-mask — through the kernel lane's fused gate /
-    valid-mask epilogues (DESIGN.md §2.9).  Bit-identical to the unfused
-    path (which stays the A/B baseline), same 3-sort budget; requires the
-    CSR windowed method.
+    lowered HLO in tests/test_plan.py).  Each query family has one
+    implementation: the windowed suite scans per-window CSR segments
+    (DESIGN.md §2.4, O(nnz) peak memory) and the overlap scans per-window
+    presence vectors over IP ranks.
 
     ``plans`` is the plan pair of ``t`` when the caller already holds it
-    (:func:`_analyze_and_plans`); the plan path then sorts only for
+    (:func:`_analyze_and_plans`); analyze then sorts only for
     ``unique_ips``.  The graph kernels are a phase of their own
     (:func:`graph_plan`, :func:`graph_kernel`), not part of this program.
     """
-    if not use_plan:
-        if fused_epilogue:
-            raise ValueError(
-                "fused_epilogue=True requires the plan path (use_plan=True):"
-                " the epilogues fuse into the plan's shared reductions"
-            )
-        return _analyze_naive(
-            t, n_windows=n_windows, ip_bins=ip_bins, k=k, backend=backend
-        )
     # jax.named_scope labels each query family in the ops' metadata
     # (analyze/plan, analyze/groups, ...) and changes no computation
     with jax.named_scope("analyze"):
@@ -624,70 +550,14 @@ def analyze(
             unique_sources = unique_lead(plan_src)
             unique_destinations = unique_lead(plan_dst)
         with jax.named_scope("topk"):
-            top = top_links_from_plan(
-                plan_src, k, links, fused=fused_epilogue, backend=backend
-            )
+            top = top_links_from_plan(plan_src, k, links)
         with jax.named_scope("windowed"):
             windowed = windowed_queries(
-                t, 1, n_windows, ts_col="win", t0=0, plans=plans,
-                method=windowed_method, fused=fused_epilogue, backend=backend)
+                t, 1, n_windows, ts_col="win", t0=0, plans=plans)
         with jax.named_scope("activity"):
             activity = _window_activity(t, n_windows, ip_bins, backend)
         with jax.named_scope("overlap"):
-            overlap = cross_window_ip_overlap(
-                t, n_windows, ips=ips,
-                method="scan" if windowed_method == "csr" else "grid",
-            )
-
-    return ChallengeResults(
-        scalars=scalars,
-        links=links,
-        per_source=per_src,
-        per_destination=per_dst,
-        source_fanout=fanout,
-        destination_fanin=fanin,
-        unique_sources=unique_sources,
-        unique_destinations=unique_destinations,
-        top=top,
-        windowed=windowed,
-        window_activity=activity,
-        window_ip_overlap=overlap,
-    )
-
-
-def _analyze_naive(
-    t: Table, *, n_windows: int, ip_bins: int, k: int, backend: str
-) -> ChallengeResults:
-    """Pre-plan analyze: one group-by sort per query family, relying on XLA
-    CSE to dedupe what it structurally can."""
-    with jax.named_scope("analyze"):
-        with jax.named_scope("groups"):
-            w = packet_weights(t)
-            links = traffic_matrix(t)
-            per_src = groupby_aggregate(
-                [t["src"]], {"packets": (w, "sum")}, n_valid=t.n_valid
-            )
-            per_dst = groupby_aggregate(
-                [t["dst"]], {"packets": (w, "sum")}, n_valid=t.n_valid
-            )
-            fanout = groupby_aggregate([links.keys[0]], None,
-                                       n_valid=links.n_groups)
-            fanin = groupby_aggregate([links.keys[1]], None,
-                                      n_valid=links.n_groups)
-        with jax.named_scope("scalars"):
-            scalars = run_all_queries_naive(t)
-        with jax.named_scope("groups"):
-            unique_sources = unique(t["src"], n_valid=t.n_valid)
-            unique_destinations = unique(t["dst"], n_valid=t.n_valid)
-        with jax.named_scope("topk"):
-            top = top_links(t, k)
-        with jax.named_scope("windowed"):
-            windowed = windowed_queries_naive(t, 1, n_windows, ts_col="win",
-                                              t0=0)
-        with jax.named_scope("activity"):
-            activity = _window_activity(t, n_windows, ip_bins, backend)
-        with jax.named_scope("overlap"):
-            overlap = cross_window_ip_overlap_naive(t, n_windows, backend)
+            overlap = cross_window_ip_overlap(t, n_windows, ips=ips)
 
     return ChallengeResults(
         scalars=scalars,
@@ -708,7 +578,6 @@ def _analyze_naive(
 def analyze_peak_buffer_bytes(
     capacity: int,
     *,
-    windowed_method: str,
     n_windows: int,
     ip_bins: int = 1024,
     k: int = 10,
@@ -718,10 +587,8 @@ def analyze_peak_buffer_bytes(
 
     Compile-only (nothing executes): lowers ``analyze`` over a zero table
     and feeds the post-optimization HLO to
-    ``launch/hloanalysis.peak_buffer_bytes``.  The ONE definition of the
-    memory-gate harness — ``benchmarks/bench_graphblas.py`` (the CI smoke)
-    and ``tests/test_memory_budget.py`` (the pinned scale-17 gate) both
-    call it, so the two gates measure the same program.
+    ``launch/hloanalysis.peak_buffer_bytes`` — the harness of the pinned
+    scale-17 memory gate (``tests/test_memory_budget.py``).
     """
     from ..launch.hloanalysis import peak_buffer_bytes
 
@@ -729,10 +596,8 @@ def analyze_peak_buffer_bytes(
         {c: np.zeros(capacity, np.int32) for c in ("src", "dst", "win")},
         n_valid=capacity - 1 if n_valid is None else n_valid,
     )
-    f = jax.jit(lambda t: analyze(
-        t, n_windows=n_windows, ip_bins=ip_bins, k=k, backend="xla",
-        windowed_method=windowed_method,
-    ))
+    f = jax.jit(lambda t: analyze(t, n_windows=n_windows, ip_bins=ip_bins,
+                                  k=k, backend="xla"))
     return peak_buffer_bytes(f.lower(t).compile().as_text())
 
 
@@ -886,7 +751,7 @@ def run_challenge(
     workdir = cfg.workdir or tempfile.mkdtemp(prefix="netsense_challenge_")
     os.makedirs(workdir, exist_ok=True)
     kw = dict(n_windows=cfg.n_windows, ip_bins=cfg.ip_bins, k=cfg.top_k,
-              backend=cfg.backend, fused_epilogue=cfg.fused_epilogue)
+              backend=cfg.backend)
     build_fn = _jitted(_build)
     anon_fn = _jitted(anonymize, method=cfg.method, rounds=cfg.rounds)
     analyze_fn = None

@@ -334,10 +334,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                     choices=["auto", "xla", "pallas", "interpret"])
     ap.add_argument("--fused", action="store_true",
                     help="also time build+anonymize+analyze as one program")
-    ap.add_argument("--fused-epilogue", action="store_true",
-                    help="fuse the analyze windowed/top-k scatter chains "
-                         "into the kernel epilogues (bit-identical; the "
-                         "unfused path stays the A/B baseline)")
     ap.add_argument("--autotune", action="store_true",
                     help="sweep Pallas block configs at this run's kernel "
                          "shapes first and persist the winners "
@@ -396,7 +392,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             ip_bins=args.ip_bins, top_k=args.top_k, method=args.method,
             rounds=args.rounds, seed=args.seed, fmt=args.format,
             backend=args.backend, fused=args.fused,
-            fused_epilogue=args.fused_epilogue,
             distributed=args.distributed, analytics=analytics,
             workdir=args.workdir,
         )

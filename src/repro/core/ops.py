@@ -475,8 +475,8 @@ def clamp_k(k: int, capacity: int) -> int:
     """``min(k, capacity)`` — the static top-k clamp.
 
     ``lax.top_k`` rejects k > buffer length, so every top-k entry point
-    clamps identically; centralising it keeps the output shapes of the
-    plan/naive paths in step.
+    clamps identically; centralising it keeps the output shapes of
+    ``top_links`` and ``top_links_from_plan`` in step.
     """
     return min(k, capacity)
 
@@ -591,8 +591,6 @@ def argmax_top_k(
     values: jnp.ndarray,
     k: int,
     valid_mask: Optional[jnp.ndarray] = None,
-    *,
-    n_valid=None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Sort-free :func:`top_k`: ``k`` rounds of masked argmax.
 
@@ -605,11 +603,6 @@ def argmax_top_k(
     to the dtype min are indistinguishable from retired slots, so this
     variant requires ``values > dtype min`` on live rows (always true for
     the non-negative counts/packet sums it is used on).
-
-    ``n_valid`` is a caller-known count of live rows: when the mask is
-    already retired *into* ``values`` (the kernel lane's fused
-    ``valid_mask``/``retire`` epilogue), pass ``n_valid`` instead of
-    ``valid_mask`` and the ``sum(valid_mask)`` recount is skipped.
     """
     k = clamp_k(k, values.shape[0])
     masked = values if valid_mask is None else jnp.where(
@@ -628,13 +621,10 @@ def argmax_top_k(
         0, k, body,
         (masked, jnp.full((k,), ident, values.dtype), jnp.zeros((k,), jnp.int32)),
     )
-    if n_valid is not None:
-        n_live = jnp.asarray(n_valid, jnp.int32)
-    else:
-        n_live = jnp.asarray(
-            values.shape[0] if valid_mask is None else jnp.sum(valid_mask),
-            jnp.int32,
-        )
+    n_live = jnp.asarray(
+        values.shape[0] if valid_mask is None else jnp.sum(valid_mask),
+        jnp.int32,
+    )
     n_live = jnp.minimum(n_live, k)
     keep = jnp.arange(k, dtype=jnp.int32) < n_live
     return (

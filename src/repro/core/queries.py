@@ -11,13 +11,12 @@ Each query mirrors one paper Table III row (matrix / summation / data-science
 notation reproduced in the docstrings).  Destination-side queries are the
 ``src``/``dst`` swap per the paper's note.
 
-Two equivalent formulations are exposed (bit-identical, same 3-sort
-budget): the data-science group-by forms, and — since DESIGN.md §2.4 — the
-GraphBLAS matrix language over :class:`repro.core.sparse.CsrMatrix`
-(:func:`traffic_matrix_csr`, :func:`run_all_queries_csr`: ``1^T A 1``,
-``|A|_0``, ``A·1``, ``|A|_0·1`` as CSR reductions).  The per-window maxima
-speak the same language in :mod:`repro.core.temporal` — per-window value
-slices over the shared CSR skeleton, reduced one window at a time.
+The whole suite (:func:`run_all_queries`, :func:`scalar_queries_from_plans`)
+derives from one shared sort-once plan pair (DESIGN.md §2.3, 3 sorts), and
+:func:`table_csrs` reads the same pair as GraphBLAS-lite CSR matrices
+(DESIGN.md §2.4) for the graph kernels.  The per-window maxima are CSR
+reductions too, in :mod:`repro.core.temporal` — per-window value slices
+over the shared CSR skeleton, reduced one window at a time.
 """
 from __future__ import annotations
 
@@ -47,7 +46,7 @@ from .plan import (
     plan_for_table,
     unique_concat,
 )
-from .sparse import CsrMatrix, csr_from_plan, degrees, reduce_rows
+from .sparse import CsrMatrix, csr_from_plan
 from .table import Table
 
 __all__ = [
@@ -56,9 +55,6 @@ __all__ = [
     "top_links_from_plan",
     "table_plans",
     "table_csrs",
-    "traffic_matrix_csr",
-    "scalar_queries_from_csrs",
-    "run_all_queries_csr",
     "scalar_queries_from_plans",
     "packet_weights",
     "traffic_matrix",
@@ -79,7 +75,6 @@ __all__ = [
     "max_destination_fanin",
     "QueryResults",
     "run_all_queries",
-    "run_all_queries_naive",
     "top_k_drift",
     "top_links_drift",
     "new_talker_rate",
@@ -221,8 +216,7 @@ def top_links(t: Table, k: int) -> TopLinks:
 
 
 def top_links_from_plan(
-    plan: SortedEdges, k: int, links: Optional[GroupResult] = None,
-    *, fused: bool = False, backend: str = "auto",
+    plan: SortedEdges, k: int, links: Optional[GroupResult] = None
 ) -> TopLinks:
     """:func:`top_links` off a shared plan, sort-free.
 
@@ -230,30 +224,10 @@ def top_links_from_plan(
     identical k heaviest links (packet sums are non-negative, so its dtype-
     min caveat never binds) without spending a sort on an already-grouped
     buffer.
-
-    ``fused=True`` folds the top-k pre-mask (``where(link_mask, packets,
-    int32_min)``) into the segmented-reduction kernel's ``valid_mask``/
-    ``retire`` epilogue (DESIGN.md §2.9): the per-link packet sums come
-    straight off the plan with dead slots already retired, and the known
-    live count (``plan.n_links``) replaces the mask recount.  Bit-identical
-    to the unfused path — same per-slot contributions, same retire value,
-    same first-max tie rule.
     """
     g = link_groups(plan) if links is None else links
     k = clamp_k(k, plan.capacity)
-    if fused:
-        from ..kernels.ops import segmented_reduce
-
-        cap = plan.capacity
-        imin = int(jnp.iinfo(jnp.int32).min)
-        pk_buf = segmented_reduce(
-            plan.w, plan.seg, cap + 1, op="sum",
-            valid_mask=jnp.arange(cap + 1, dtype=jnp.int32) < plan.n_links,
-            retire=imin, out_dtype=jnp.int32, backend=backend,
-        )[:cap]
-        pk, idx, n_live = argmax_top_k(pk_buf, k, n_valid=plan.n_links)
-    else:
-        pk, idx, n_live = argmax_top_k(g.aggs["packets"], k, g.mask())
+    pk, idx, n_live = argmax_top_k(g.aggs["packets"], k, g.mask())
     keep = jnp.arange(k, dtype=jnp.int32) < n_live
     return TopLinks(
         src=jnp.where(keep, g.keys[0][idx], 0),
@@ -320,74 +294,12 @@ def table_plans(t: Table) -> Tuple[SortedEdges, SortedEdges]:
     return plan_for_table(t, "src", "dst"), plan_for_table(t, "dst", "src")
 
 
-# --- the matrix-language (GraphBLAS-lite CSR) formulation ---------------------
-
-def traffic_matrix_csr(
-    t: Table, plan: Optional[SortedEdges] = None
-) -> CsrMatrix:
-    """A_t as a static-shape CSR (rows = src, cols = dst, vals = packets).
-
-    The sparse-first form of :func:`traffic_matrix` — same one packed sort
-    (zero when ``plan`` is shared), but the result carries row pointers, so
-    fan-out is a pointer difference and every per-source statistic is a row
-    reduction (DESIGN.md §2.4).
-    """
-    return csr_from_plan(plan_for_table(t) if plan is None else plan)
-
-
 def table_csrs(
     t: Table, plans: Optional[Tuple[SortedEdges, SortedEdges]] = None
 ) -> Tuple[CsrMatrix, CsrMatrix]:
     """(A_t, A_t^T) as CSRs off the shared plan pair — zero extra sorts."""
     plan_src, plan_dst = table_plans(t) if plans is None else plans
     return csr_from_plan(plan_src), csr_from_plan(plan_dst)
-
-
-def scalar_queries_from_csrs(
-    t: Table,
-    csr_src: CsrMatrix,
-    csr_dst: CsrMatrix,
-    ips: Optional[UniqueResult] = None,
-) -> QueryResults:
-    """All ten Table III scalars in matrix language over the CSR pair.
-
-    Each line is the paper's GraphBLAS formulation, verbatim: 1^T A 1 /
-    |A|_0 / max(A) / A·1 / |A|_0·1 and the transpose mirrors — computed as
-    CSR reductions (``reduce_rows``, ``degrees``) with zero sorts beyond
-    the plans the CSRs came from.  Bit-identical to the group-by forms.
-    """
-    if ips is None:
-        ips = unique_ips(t)
-    out_pk = reduce_rows(csr_src, "plus")       # A·1
-    in_pk = reduce_rows(csr_dst, "plus")        # 1^T·A (transpose rows)
-    fanout = degrees(csr_src)                   # |A|_0·1
-    fanin = degrees(csr_dst)                    # 1^T·|A|_0
-    src_mask = csr_src.row_mask()
-    dst_mask = csr_dst.row_mask()
-    return QueryResults(
-        valid_packets=jnp.sum(                  # 1^T A 1
-            jnp.where(csr_src.entry_mask(), csr_src.vals, 0)
-        ),
-        unique_links=csr_src.nnz,               # |A|_0
-        max_link_packets=masked_max(csr_src.vals, csr_src.entry_mask()),
-        n_unique_sources=csr_src.n_rows,        # |A 1|_0 support
-        n_unique_destinations=csr_dst.n_rows,
-        n_unique_ips=ips.n_unique,
-        max_source_packets=masked_max(out_pk, src_mask),
-        max_source_fanout=masked_max(fanout, src_mask),
-        max_destination_packets=masked_max(in_pk, dst_mask),
-        max_destination_fanin=masked_max(fanin, dst_mask),
-    )
-
-
-def run_all_queries_csr(
-    t: Table, plans: Optional[Tuple[SortedEdges, SortedEdges]] = None
-) -> QueryResults:
-    """:func:`run_all_queries` through the CSR matrix language — the same
-    3-sort budget (two plans + the ``unique_ips`` concat), bit-identical
-    scalars, exercised head-to-head by ``benchmarks/bench_graphblas.py``."""
-    csr_src, csr_dst = table_csrs(t, plans)
-    return scalar_queries_from_csrs(t, csr_src, csr_dst)
 
 
 def scalar_queries_from_plans(
@@ -533,28 +445,3 @@ def new_talker_rate_sketch(
     cur_card = hll_cardinality(cur_registers)
     return new_talker_rate(prev_card, union_card, cur_card)
 
-
-def run_all_queries_naive(t: Table) -> QueryResults:
-    """Pre-plan implementation: one independent group-by sort per query
-    family, deduped only where XLA CSE structurally can.  Kept as the A/B
-    baseline for ``benchmarks/bench_queries.py --ab`` and the plan-equality
-    tests; results are bit-identical to :func:`run_all_queries`.
-    """
-    links = traffic_matrix(t)
-    link_mask = links.mask()
-    fanout = groupby_aggregate([links.keys[0]], None, n_valid=links.n_groups)
-    fanin = groupby_aggregate([links.keys[1]], None, n_valid=links.n_groups)
-    per_src = packets_per_source(t)
-    per_dst = packets_per_destination(t)
-    return QueryResults(
-        valid_packets=valid_packets(t),
-        unique_links=links.n_groups,
-        max_link_packets=jnp.max(jnp.where(link_mask, links.aggs["packets"], 0)),
-        n_unique_sources=per_src.n_groups,
-        n_unique_destinations=per_dst.n_groups,
-        n_unique_ips=unique_ips(t).n_unique,
-        max_source_packets=jnp.max(jnp.where(per_src.mask(), per_src.aggs["packets"], 0)),
-        max_source_fanout=jnp.max(jnp.where(fanout.mask(), fanout.aggs["count"], 0)),
-        max_destination_packets=jnp.max(jnp.where(per_dst.mask(), per_dst.aggs["packets"], 0)),
-        max_destination_fanin=jnp.max(jnp.where(fanin.mask(), fanin.aggs["count"], 0)),
-    )

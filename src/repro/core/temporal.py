@@ -10,21 +10,12 @@ window w's links are exactly the plan's links restricted to the rows that
 fall in w, so every per-window statistic derives from the two already-sorted
 plans with zero additional sorts.
 
-Two sort-free formulations are kept (DESIGN.md §2.4):
-
-  * **CSR path (default)** — the per-window traffic matrix A_w is a *values
-    slice over the shared CSR skeleton* (``core/sparse.csr_from_plan``):
-    masking the sorted stream to window w and segment-reducing yields A_w's
-    entry values and pattern on the same row pointers, and every statistic
-    is a CSR reduction.  Windows are visited by a ``lax.scan`` whose body
-    reuses O(capacity) buffers, so peak memory is **O(nnz)** — independent
-    of ``n_windows``.
-  * **dense-grid path** (``method="grid"``, the pre-CSR A/B baseline) —
-    scatter-adds into five ``(n_windows + 1, capacity + 1)`` grids; one
-    pass, but O(n_windows × capacity) peak memory.
-
-Both are bit-identical to each other and to the pre-plan
-``windowed_queries_naive`` (five ``(win, ...)``-leading full sorts).
+The per-window traffic matrix A_w is a *values slice over the shared CSR
+skeleton* (``core/sparse.csr_from_plan``, DESIGN.md §2.4): masking the sorted
+stream to window w and segment-reducing yields A_w's entry values and
+pattern on the same row pointers, and every statistic is a CSR reduction.
+Windows are visited by a ``lax.scan`` whose body reuses O(capacity)
+buffers, so peak memory is **O(nnz)** — independent of ``n_windows``.
 """
 from __future__ import annotations
 
@@ -33,15 +24,12 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..kernels.ops import segmented_reduce
-from .ops import groupby_aggregate
 from .plan import SortedEdges, sorted_edges
 from .table import Table
 
 __all__ = [
     "window_ids",
     "windowed_queries",
-    "windowed_queries_naive",
     "windowed_suite_from_plans",
 ]
 
@@ -52,14 +40,8 @@ def window_ids(ts: jnp.ndarray, window_len: int, t0=None) -> jnp.ndarray:
     return ((ts - t0) // jnp.asarray(window_len, ts.dtype)).astype(jnp.int32)
 
 
-# ---------------------------------------------------------------------------
-# plan-based CSR path (default): per-window values over the shared CSR
-# skeleton, scanned one window at a time — O(nnz) peak memory, zero sorts
-# ---------------------------------------------------------------------------
-
 def _side_stats_csr(
-    plan: SortedEdges, win: jnp.ndarray, n_windows: int,
-    fused: bool = False, backend: str = "auto",
+    plan: SortedEdges, win: jnp.ndarray, n_windows: int
 ) -> Dict[str, jnp.ndarray]:
     """Per-window stats of one plan side off per-window CSR segments.
 
@@ -68,17 +50,7 @@ def _side_stats_csr(
     the w-masked segment sums over that skeleton — a CSR with the same
     pointers and a sliced value/pattern vector.  A ``lax.scan`` walks the
     static window axis so only ONE window's O(capacity) value buffers are
-    live at a time (the dense-grid path materialises all of them at once).
-
-    ``fused=True`` folds the per-window slice select (``where(in_w, ...)``)
-    into the segmented-reduction kernel's gate epilogue (DESIGN.md §2.9):
-    the window id rides as the (traced) gate value, so each scan step is
-    one kernel dispatch per reduction with no materialised masked copies.
-    Bit-identical to the unfused path: a row is gated out exactly when the
-    unfused path would scatter a zero (``s_win == w`` implies validity —
-    invalid and padding rows carry ``s_win == n_windows``), and the total
-    ``1^T A_w 1`` is re-derived as ``sum(link_pk)`` — the same int32
-    additions reassociated, exact under two's-complement wraparound.
+    live at a time.
     """
     cap = plan.capacity
     valid = plan.valid_rows()
@@ -93,30 +65,17 @@ def _side_stats_csr(
     link2row = plan.link_to_k0()[:cap]
 
     def one_window(carry, w):
-        if fused:
-            def gated_sum(vals, seg):
-                return segmented_reduce(
-                    vals, seg, cap + 1, op="sum", gate_ids=s_win,
-                    gate_value=w, out_dtype=jnp.int32, backend=backend,
-                )[:cap]
-
-            link_cnt = gated_sum(ones, plan.seg)
-            link_pk = gated_sum(w_live, plan.seg)
-            row_cnt = gated_sum(ones, plan.k0_seg)
-            row_pk = gated_sum(w_live, plan.k0_seg)
-            pk_total = jnp.sum(link_pk)
-        else:
-            in_w = s_win == w
-            rows_w = jnp.where(in_w, ones, 0)
-            pk_w = jnp.where(in_w, w_live, 0)
-            # A_w's entry values on the shared skeleton: per-link row counts
-            # (pattern) and packet sums (values) restricted to window w
-            link_cnt = jax.ops.segment_sum(rows_w, plan.seg, num_segments=cap + 1)[:cap]
-            link_pk = jax.ops.segment_sum(pk_w, plan.seg, num_segments=cap + 1)[:cap]
-            # row-level reductions of A_w (per leading endpoint)
-            row_cnt = jax.ops.segment_sum(rows_w, plan.k0_seg, num_segments=cap + 1)[:cap]
-            row_pk = jax.ops.segment_sum(pk_w, plan.k0_seg, num_segments=cap + 1)[:cap]
-            pk_total = jnp.sum(pk_w)
+        in_w = s_win == w
+        rows_w = jnp.where(in_w, ones, 0)
+        pk_w = jnp.where(in_w, w_live, 0)
+        # A_w's entry values on the shared skeleton: per-link row counts
+        # (pattern) and packet sums (values) restricted to window w
+        link_cnt = jax.ops.segment_sum(rows_w, plan.seg, num_segments=cap + 1)[:cap]
+        link_pk = jax.ops.segment_sum(pk_w, plan.seg, num_segments=cap + 1)[:cap]
+        # row-level reductions of A_w (per leading endpoint)
+        row_cnt = jax.ops.segment_sum(rows_w, plan.k0_seg, num_segments=cap + 1)[:cap]
+        row_pk = jax.ops.segment_sum(pk_w, plan.k0_seg, num_segments=cap + 1)[:cap]
+        pk_total = jnp.sum(pk_w)
         present = link_cnt > 0
         # |A_w|_0·1 — degrees of the per-window pattern, reduced over rows
         fan = jax.ops.segment_sum(
@@ -144,79 +103,16 @@ def _side_stats_csr(
     }
 
 
-# ---------------------------------------------------------------------------
-# plan-based dense-grid path (A/B baseline): O(n_windows * capacity) grids
-# ---------------------------------------------------------------------------
-
-def _side_stats_grid(
-    plan: SortedEdges, win: jnp.ndarray, n_windows: int
-) -> Dict[str, jnp.ndarray]:
-    """Per-window stats of one plan side via dense scatter grids: distinct
-    links, link packets, per-leading-endpoint packets/uniques/fan-out.
-    ``win`` is the per-ORIGINAL-row window id; the plan's ``row`` payload
-    routes it to sorted rows."""
-    cap = plan.capacity
-    valid = plan.valid_rows()
-    s_win = jnp.where(
-        valid, jnp.clip(win[plan.row], 0, n_windows - 1), n_windows
-    )
-    ones = valid.astype(jnp.int32)
-    w_live = jnp.where(valid, plan.w, 0)
-    zeros = lambda: jnp.zeros((n_windows + 1, cap + 1), jnp.int32)
-    # (window, link) and (window, key0-group) occupancy/packet grids
-    link_rows = zeros().at[s_win, plan.seg].add(ones)
-    link_pk = zeros().at[s_win, plan.seg].add(w_live)
-    k0_rows = zeros().at[s_win, plan.k0_seg].add(ones)
-    k0_pk = zeros().at[s_win, plan.k0_seg].add(w_live)
-    present = link_rows[:n_windows, :cap] > 0
-    # distinct key1 per (window, key0): links present in w, bucketed by the
-    # link -> key0-group map (same prefix property the batch fan-out uses)
-    link2k0 = plan.link_to_k0()[:cap]
-    fan = jax.vmap(
-        lambda p: jax.ops.segment_sum(
-            p.astype(jnp.int32), link2k0, num_segments=cap + 1
-        )
-    )(present)
-    return {
-        "unique_links": jnp.sum(present, axis=1).astype(jnp.int32),
-        "max_link_packets": jnp.max(link_pk[:n_windows, :cap], axis=1),
-        "n_unique": jnp.sum(k0_rows[:n_windows, :cap] > 0, axis=1).astype(jnp.int32),
-        "max_packets": jnp.max(k0_pk[:n_windows, :cap], axis=1),
-        "max_fanout": jnp.max(fan[:, :cap], axis=1),
-        "valid_packets": jax.ops.segment_sum(
-            w_live, s_win, num_segments=n_windows + 1
-        )[:n_windows],
-    }
-
-
 def windowed_suite_from_plans(
     plan_src: SortedEdges,
     plan_dst: SortedEdges,
     win: jnp.ndarray,
     n_windows: int,
-    method: str = "csr",
-    fused: bool = False,
-    backend: str = "auto",
 ) -> Dict[str, jnp.ndarray]:
-    """All scalar challenge statistics per window, off the shared plan pair.
-
-    ``method="csr"`` (default) scans per-window CSR segments — O(nnz) peak
-    memory; ``method="grid"`` is the dense-scatter A/B baseline —
-    O(n_windows × capacity) peak memory, bit-identical results.
-
-    ``fused=True`` (CSR only) routes the per-window reductions through the
-    kernel lane's gate epilogue — see :func:`_side_stats_csr`.
-    """
-    if method not in ("csr", "grid"):
-        raise ValueError(f"unknown windowed method {method!r}")
-    if fused and method != "csr":
-        raise ValueError("fused windowed suite requires method='csr'")
-    if method == "csr":
-        s = _side_stats_csr(plan_src, win, n_windows, fused, backend)
-        d = _side_stats_csr(plan_dst, win, n_windows, fused, backend)
-    else:
-        s = _side_stats_grid(plan_src, win, n_windows)
-        d = _side_stats_grid(plan_dst, win, n_windows)
+    """All scalar challenge statistics per window, off the shared plan pair,
+    by per-window CSR segments (:func:`_side_stats_csr`)."""
+    s = _side_stats_csr(plan_src, win, n_windows)
+    d = _side_stats_csr(plan_dst, win, n_windows)
     return {
         "valid_packets": s["valid_packets"],
         "unique_links": s["unique_links"],
@@ -237,9 +133,6 @@ def windowed_queries(
     ts_col: str = "ts",
     t0=None,
     plans: Optional[Tuple[SortedEdges, SortedEdges]] = None,
-    method: str = "csr",
-    fused: bool = False,
-    backend: str = "auto",
 ) -> Dict[str, jnp.ndarray]:
     """All scalar challenge statistics per time window.
 
@@ -254,12 +147,6 @@ def windowed_queries(
       plans: optional pre-built (src-leading, dst-leading) plan pair — the
         challenge ``analyze`` shares the suite-wide pair so the windowed
         statistics cost zero additional sorts.
-      method: ``"csr"`` (sparse default, O(nnz) memory) or ``"grid"`` (the
-        dense-scatter A/B baseline) — see :func:`windowed_suite_from_plans`.
-      fused: route the per-window reductions through the kernel gate
-        epilogue (CSR only; bit-identical, DESIGN.md §2.9).
-      backend: kernel backend for the fused reductions (``"auto"``/
-        ``"xla"``/``"pallas"``/``"interpret"``).
 
     Returns a dict of (n_windows,) arrays:
       valid_packets, unique_links, max_link_packets, n_unique_sources,
@@ -273,83 +160,5 @@ def windowed_queries(
             sorted_edges(t["src"], t["dst"], weights=w, n_valid=t.n_valid),
             sorted_edges(t["dst"], t["src"], weights=w, n_valid=t.n_valid),
         )
-    return windowed_suite_from_plans(
-        plans[0], plans[1], win, n_windows, method=method, fused=fused,
-        backend=backend,
-    )
+    return windowed_suite_from_plans(plans[0], plans[1], win, n_windows)
 
-
-# ---------------------------------------------------------------------------
-# pre-plan path: one (win, ...)-leading group-by sort per statistic family
-# (kept as the A/B baseline; results are bit-identical to the plan path)
-# ---------------------------------------------------------------------------
-
-def _per_window_max(values: jnp.ndarray, win_of_group: jnp.ndarray,
-                    mask: jnp.ndarray, n_windows: int) -> jnp.ndarray:
-    """Max of a per-group statistic within each window.
-
-    Windows with no contributing groups report 0 (the statistics here are
-    all non-negative counts/sums) — ``segment_max``'s empty-segment identity
-    is the dtype min, which used to leak into empty windows; the floor keeps
-    this path bit-identical to the plan path's zero-filled grids.
-    """
-    seg = jnp.where(mask, win_of_group, n_windows)
-    return jnp.maximum(jax.ops.segment_max(
-        jnp.where(mask, values, 0), seg, num_segments=n_windows + 1
-    )[:n_windows], 0)
-
-
-def windowed_queries_naive(
-    t: Table,
-    window_len: int,
-    n_windows: int,
-    ts_col: str = "ts",
-    t0=None,
-) -> Dict[str, jnp.ndarray]:
-    """Pre-plan windowed suite: five (win, ...)-leading full sorts."""
-    w = t["n_packets"] if "n_packets" in t else jnp.ones((t.capacity,), jnp.int32)
-    win = jnp.clip(window_ids(t[ts_col], window_len, t0=t0), 0, n_windows - 1)
-    valid = t.valid_mask()
-    win_seg = jnp.where(valid, win, n_windows)
-
-    def per_window_sum(x):
-        return jax.ops.segment_sum(
-            jnp.where(valid, x, 0), win_seg, num_segments=n_windows + 1
-        )[:n_windows]
-
-    out: Dict[str, jnp.ndarray] = {"valid_packets": per_window_sum(w)}
-
-    # links: group by (window, src, dst) once; everything link-ish follows
-    links = groupby_aggregate(
-        [win, t["src"], t["dst"]], {"packets": (w, "sum")}, n_valid=t.n_valid
-    )
-    lmask = links.mask()
-    lwin = links.keys[0]
-    ones = jnp.ones_like(lwin)
-    out["unique_links"] = jax.ops.segment_sum(
-        jnp.where(lmask, ones, 0), jnp.where(lmask, lwin, n_windows),
-        num_segments=n_windows + 1)[:n_windows]
-    out["max_link_packets"] = _per_window_max(
-        links.aggs["packets"], lwin, lmask, n_windows)
-
-    for side, col_idx in (("source", 1), ("destination", 2)):
-        # per-(window, endpoint) packet sums and distinct counts
-        ep = groupby_aggregate(
-            [win, t["src" if side == "source" else "dst"]],
-            {"packets": (w, "sum")}, n_valid=t.n_valid,
-        )
-        m = ep.mask()
-        out[f"n_unique_{side}s"] = jax.ops.segment_sum(
-            jnp.where(m, jnp.ones_like(ep.keys[0]), 0),
-            jnp.where(m, ep.keys[0], n_windows),
-            num_segments=n_windows + 1)[:n_windows]
-        out[f"max_{side}_packets"] = _per_window_max(
-            ep.aggs["packets"], ep.keys[0], m, n_windows)
-        # fan-out/fan-in: distinct peers per (window, endpoint) over links
-        fan = groupby_aggregate(
-            [lwin, links.keys[col_idx]], None, n_valid=links.n_groups
-        )
-        fname = "max_source_fanout" if side == "source" else "max_destination_fanin"
-        out[fname] = _per_window_max(
-            fan.aggs["count"], fan.keys[0], fan.mask(), n_windows)
-    return out

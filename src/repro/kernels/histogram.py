@@ -20,15 +20,10 @@ the ~16 MB v5e VMEM budget.  Block shapes default to
 :mod:`repro.kernels.defaults` and are overridden per shape bucket by the
 autotuner (:mod:`repro.kernels.autotune`).
 
-Fusion epilogues (DESIGN.md §2.9): the kernel optionally fuses the two
-scatter/gather chains that used to bracket it as separate XLA ops —
-
-  * ``gate_ids``/``gate_value`` — a row contributes only when
-    ``gate_ids[i] == gate_value`` (the windowed suite's per-window
-    ``where(in_w, ...)`` slice select, folded into the one-hot compare);
-  * ``valid_mask``/``retire`` — after the last row block accumulates, bins
-    outside the mask are overwritten with the static ``retire`` value (the
-    top-k pre-mask / mxv post-mask, folded into the final grid step).
+Fusion epilogue (DESIGN.md §2.9): with ``valid_mask``/``retire``, after
+the last row block accumulates, bins outside the mask are overwritten with
+the static ``retire`` value (the masked ``mxv``/``vxm`` post-mask of
+``core/sparse.py``, folded into the final grid step).
 """
 from __future__ import annotations
 
@@ -44,13 +39,13 @@ from .defaults import DEFAULT_BLOCK_BINS, DEFAULT_BLOCK_ROWS
 __all__ = ["histogram_pallas", "DEFAULT_BLOCK_ROWS", "DEFAULT_BLOCK_BINS"]
 
 
-def _make_hist_kernel(*, block_bins: int, gated: bool, accum: bool,
-                      masked: bool, retire: float):
+def _make_hist_kernel(*, block_bins: int, accum: bool, masked: bool,
+                      retire: float):
     """Build the histogram kernel body for one operand layout.
 
-    Operand order (after ids/weights): gate row + gate scalar when
-    ``gated``, init tile when ``accum``, mask tile when ``masked`` —
-    mirrored exactly by the in_specs assembly in :func:`histogram_pallas`.
+    Operand order (after ids/weights): init tile when ``accum``, mask tile
+    when ``masked`` — mirrored exactly by the in_specs assembly in
+    :func:`histogram_pallas`.
     """
 
     def kernel(*refs):
@@ -58,9 +53,6 @@ def _make_hist_kernel(*, block_bins: int, gated: bool, accum: bool,
         out_ref = refs.pop()
         ids_ref, w_ref = refs[0], refs[1]
         nxt = 2
-        if gated:
-            gate_ref, gv_ref = refs[nxt], refs[nxt + 1]
-            nxt += 2
         if accum:
             init_ref = refs[nxt]
             nxt += 1
@@ -73,12 +65,7 @@ def _make_hist_kernel(*, block_bins: int, gated: bool, accum: bool,
         w = w_ref[...].astype(jnp.float32)  # (1, Bn)
         base = i * block_bins
         bins = base + jax.lax.broadcasted_iota(jnp.int32, (1, block_bins), 1)
-        keep = ids.T == bins  # (Bn, St)
-        if gated:
-            # per-row gate fused into the one-hot compare: a gated-out row
-            # matches no bin, exactly the where(in_w, ...) pre-select
-            keep = keep & (gate_ref[...].T == gv_ref[0, 0])
-        onehot = keep.astype(jnp.float32)
+        onehot = (ids.T == bins).astype(jnp.float32)  # (Bn, St)
         partial = jax.lax.dot_general(
             w, onehot, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -97,7 +84,7 @@ def _make_hist_kernel(*, block_bins: int, gated: bool, accum: bool,
             @pl.when(j == pl.num_programs(1) - 1)
             def _retire():
                 # post-reduce epilogue on the final revisit: masked-out bins
-                # take the static retire value (top-k pre-mask / mxv mask)
+                # take the static retire value (the mxv/vxm mask)
                 out_ref[...] = jnp.where(
                     mask_ref[...] != 0, out_ref[...], jnp.float32(retire)
                 )
@@ -111,8 +98,6 @@ def histogram_pallas(
     weights: Optional[jnp.ndarray] = None,
     *,
     init: Optional[jnp.ndarray] = None,
-    gate_ids: Optional[jnp.ndarray] = None,
-    gate_value=None,
     valid_mask: Optional[jnp.ndarray] = None,
     retire: float = 0.0,
     block_rows: int = DEFAULT_BLOCK_ROWS,
@@ -126,9 +111,7 @@ def histogram_pallas(
     zeros — the mergeable-accumulator path: ``out = init + histogram(ids)``
     in one dispatch.
 
-    Fused epilogues: ``gate_ids`` (shape of ``ids``) + ``gate_value``
-    (scalar, may be traced) keep only rows with ``gate_ids[i] ==
-    gate_value``; ``valid_mask`` (bool, shape ``(num_bins,)``) overwrites
+    Fused epilogue: ``valid_mask`` (bool, shape ``(num_bins,)``) overwrites
     masked-out bins with ``retire`` *after* the reduction (and after the
     ``init`` fold).  ``retire`` must be a static Python number — it is
     baked into the kernel.  Returns float32 counts of shape (num_bins,).
@@ -144,7 +127,6 @@ def histogram_pallas(
         return out
     if weights is None:
         weights = jnp.ones((n,), jnp.float32)
-    gated = gate_ids is not None
     masked = valid_mask is not None
     n_pad = -n % block_rows
     b_pad = -num_bins % block_bins
@@ -157,13 +139,6 @@ def histogram_pallas(
     bin_spec = pl.BlockSpec((1, block_bins), lambda i, j: (0, i))
     in_specs = [row_spec, row_spec]
     operands = [ids_p, w_p]
-    if gated:
-        # padded gate rows are irrelevant (their id == -1 matches no bin);
-        # the gate scalar rides as a (1, 1) operand so it may be traced
-        gate_p = jnp.pad(gate_ids.astype(jnp.int32), (0, n_pad))[None, :]
-        gv = jnp.asarray(gate_value, jnp.int32).reshape(1, 1)
-        in_specs += [row_spec, pl.BlockSpec((1, 1), lambda i, j: (0, 0))]
-        operands += [gate_p, gv]
     if init is not None:
         init_p = jnp.pad(init.astype(jnp.float32), (0, b_pad))[None, :]
         in_specs.append(bin_spec)
@@ -175,8 +150,8 @@ def histogram_pallas(
         in_specs.append(bin_spec)
         operands.append(mask_p)
     kernel = _make_hist_kernel(
-        block_bins=block_bins, gated=gated, accum=init is not None,
-        masked=masked, retire=float(retire),
+        block_bins=block_bins, accum=init is not None, masked=masked,
+        retire=float(retire),
     )
     out = pl.pallas_call(
         kernel,

@@ -57,10 +57,6 @@ def histogram(
     weights: Optional[jnp.ndarray] = None,
     *,
     init: Optional[jnp.ndarray] = None,
-    gate_ids: Optional[jnp.ndarray] = None,
-    gate_value=None,
-    valid_mask: Optional[jnp.ndarray] = None,
-    retire: float = 0.0,
     backend: str = "auto",
 ) -> jnp.ndarray:
     """Weighted histogram with an optional accumulate path.
@@ -70,27 +66,16 @@ def histogram(
     mergeable-state primitive of the streaming engine (DESIGN.md §6).  On
     the Pallas path the accumulator seeds the output tile in VMEM instead
     of zeros, so accumulation costs no extra dispatch.
-
-    Fused epilogues (DESIGN.md §2.9): ``gate_ids``/``gate_value`` drop
-    rows whose gate id differs from the (possibly traced) gate value;
-    ``valid_mask`` + static ``retire`` overwrite masked-out bins *after*
-    the reduction and ``init`` fold.  Both lower to extra jnp ops on the
-    XLA path and to in-kernel epilogues on the Pallas path.
     """
     backend = _resolve(backend, num_bins)
     if backend == "xla":
-        out = ref.ref_histogram(
-            ids, num_bins, weights, gate_ids=gate_ids, gate_value=gate_value
-        )
+        out = ref.ref_histogram(ids, num_bins, weights)
         if init is not None:
             out = init.astype(jnp.float32) + out
-        if valid_mask is not None:
-            out = jnp.where(valid_mask, out, jnp.float32(retire))
         return out
     cfg = autotune.best_config("histogram", ids.shape[0], num_bins, "float32")
     return histogram_pallas(
-        ids, num_bins, weights, init=init, gate_ids=gate_ids,
-        gate_value=gate_value, valid_mask=valid_mask, retire=retire,
+        ids, num_bins, weights, init=init,
         interpret=(backend == "interpret"), **cfg,
     )
 
@@ -137,11 +122,8 @@ def segmented_reduce(
     *,
     op: str = "sum",
     init: Optional[jnp.ndarray] = None,
-    gate_ids: Optional[jnp.ndarray] = None,
-    gate_value=None,
     valid_mask: Optional[jnp.ndarray] = None,
     retire=None,
-    out_dtype=None,
     backend: str = "auto",
 ) -> jnp.ndarray:
     """1-D segmented reduction under a plus or max monoid — the reduction
@@ -152,52 +134,42 @@ def segmented_reduce(
     kernel of :mod:`repro.kernels.segreduce` — MXU accumulation is additive,
     so the max monoid needs its own kernel.  Empty segments yield the monoid
     identity (0 / ``-inf``); ``init`` folds a running accumulator in the
-    same dispatch.  Returns float32 of shape ``(num_segments,)``, or
-    ``out_dtype`` when given (``"sum"`` only — native accumulation on the
-    XLA path, exact for int32 sums; the Pallas path accumulates in float32
-    and casts, exact below 2^24).
+    same dispatch.  Returns float32 of shape ``(num_segments,)``.
 
-    Fused epilogues (DESIGN.md §2.9): ``gate_ids``/``gate_value`` drop
-    non-matching rows (the windowed suite's per-window select);
-    ``valid_mask`` + static ``retire`` (default: the monoid identity)
-    overwrite masked-out segments last (the top-k pre-mask / mxv mask).
+    Fused epilogue (DESIGN.md §2.9): ``valid_mask`` + static ``retire``
+    (default: the monoid identity) overwrite masked-out segments last (the
+    masked ``mxv``/``vxm`` of :mod:`repro.core.sparse`).
     """
     if op == "sum":
         backend = _resolve(backend, num_segments)
         r = 0.0 if retire is None else retire
         if backend == "xla":
             return ref.ref_segmented_reduce(
-                vals, seg_ids, num_segments, op, init, gate_ids=gate_ids,
-                gate_value=gate_value, valid_mask=valid_mask, retire=r,
-                out_dtype=out_dtype,
+                vals, seg_ids, num_segments, op, init,
+                valid_mask=valid_mask, retire=r,
             )
         cfg = autotune.best_config(
             "histogram", seg_ids.shape[0], num_segments, "float32"
         )
-        out = histogram_pallas(
-            seg_ids, num_segments, vals, init=init, gate_ids=gate_ids,
-            gate_value=gate_value, valid_mask=valid_mask, retire=float(r),
-            interpret=(backend == "interpret"), **cfg,
+        return histogram_pallas(
+            seg_ids, num_segments, vals, init=init, valid_mask=valid_mask,
+            retire=float(r), interpret=(backend == "interpret"), **cfg,
         )
-        return out if out_dtype is None else out.astype(out_dtype)
     if op != "max":
         raise ValueError(f"unknown segmented-reduce op {op!r}")
-    if out_dtype is not None:
-        raise ValueError("out_dtype is only supported for op='sum'")
     backend = _resolve(backend, num_segments)
     r = float("-inf") if retire is None else retire
     if backend == "xla":
         return ref.ref_segmented_reduce(
-            vals, seg_ids, num_segments, op, init, gate_ids=gate_ids,
-            gate_value=gate_value, valid_mask=valid_mask, retire=r,
+            vals, seg_ids, num_segments, op, init,
+            valid_mask=valid_mask, retire=r,
         )
     cfg = autotune.best_config(
         "segreduce", seg_ids.shape[0], num_segments, "float32"
     )
     return segment_max_pallas(
-        vals, seg_ids, num_segments, init=init, gate_ids=gate_ids,
-        gate_value=gate_value, valid_mask=valid_mask, retire=float(r),
-        interpret=(backend == "interpret"), **cfg,
+        vals, seg_ids, num_segments, init=init, valid_mask=valid_mask,
+        retire=float(r), interpret=(backend == "interpret"), **cfg,
     )
 
 

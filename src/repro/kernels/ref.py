@@ -37,8 +37,6 @@ def ref_histogram(
     num_bins: int,
     weights: Optional[jnp.ndarray] = None,
     *,
-    gate_ids: Optional[jnp.ndarray] = None,
-    gate_value=None,
     valid_mask: Optional[jnp.ndarray] = None,
     retire: float = 0.0,
 ) -> jnp.ndarray:
@@ -46,15 +44,12 @@ def ref_histogram(
 
     Out-of-range ids (e.g. the jaxdf padding id == capacity) are dropped.
     Fusion-epilogue semantics (the kernel contract, DESIGN.md §2.9):
-    ``gate_ids``/``gate_value`` additionally drop rows with
-    ``gate_ids[i] != gate_value``; ``valid_mask`` (shape ``(num_bins,)``)
-    overwrites masked-out bins with ``retire`` after the reduction.
+    ``valid_mask`` (shape ``(num_bins,)``) overwrites masked-out bins with
+    ``retire`` after the reduction.
     """
     if weights is None:
         weights = jnp.ones(ids.shape, jnp.float32)
     ok = (ids >= 0) & (ids < num_bins)
-    if gate_ids is not None:
-        ok = ok & (gate_ids == gate_value)
     out = jax.ops.segment_sum(
         jnp.where(ok, weights, 0).astype(jnp.float32),
         jnp.where(ok, ids, num_bins),
@@ -72,11 +67,8 @@ def ref_segmented_reduce(
     op: str = "sum",
     init: Optional[jnp.ndarray] = None,
     *,
-    gate_ids: Optional[jnp.ndarray] = None,
-    gate_value=None,
     valid_mask: Optional[jnp.ndarray] = None,
     retire=None,
-    out_dtype=None,
 ) -> jnp.ndarray:
     """1-D segmented reduction under a plus or max monoid (float32).
 
@@ -86,34 +78,25 @@ def ref_segmented_reduce(
     the GraphBLAS-lite reduction semantics of :mod:`repro.core.sparse`.
 
     Fusion-epilogue semantics (authoritative — the Pallas kernels are
-    verified against this): ``gate_ids``/``gate_value`` drop non-matching
-    rows; ``valid_mask`` + ``retire`` overwrite masked-out segments LAST
-    (after the ``init`` fold); ``retire`` defaults to the monoid identity.
-    ``out_dtype`` (``"sum"`` only) accumulates natively in that dtype —
-    integer sums stay exact past 2^24, which is what makes the fused
-    windowed/top-k paths bit-identical to their unfused int32 baselines.
+    verified against this): ``valid_mask`` + ``retire`` overwrite
+    masked-out segments LAST (after the ``init`` fold); ``retire`` defaults
+    to the monoid identity.
     """
     ok = (seg_ids >= 0) & (seg_ids < num_segments)
-    if gate_ids is not None:
-        ok = ok & (gate_ids == gate_value)
     seg = jnp.where(ok, seg_ids, num_segments)
     if op == "sum":
-        acc_dtype = jnp.float32 if out_dtype is None else jnp.dtype(out_dtype)
-        v = vals.astype(acc_dtype)
+        v = vals.astype(jnp.float32)
         out = jax.ops.segment_sum(
-            jnp.where(ok, v, jnp.asarray(0, acc_dtype)), seg,
+            jnp.where(ok, v, jnp.asarray(0, jnp.float32)), seg,
             num_segments=num_segments + 1,
         )[:num_segments]
         if init is not None:
-            out = init.astype(acc_dtype) + out
+            out = init.astype(jnp.float32) + out
         if valid_mask is not None:
             r = 0 if retire is None else retire
-            out = jnp.where(valid_mask, out, jnp.asarray(r, acc_dtype))
+            out = jnp.where(valid_mask, out, jnp.asarray(r, jnp.float32))
         return out
     if op == "max":
-        if out_dtype is not None:
-            raise ValueError("out_dtype is only supported for op='sum' "
-                             "(the max identity -inf has no integer image)")
         v = vals.astype(jnp.float32)
         out = jax.ops.segment_max(
             jnp.where(ok, v, -jnp.inf), seg, num_segments=num_segments + 1

@@ -20,8 +20,8 @@ Grid: ``(num_seg_tiles, num_row_blocks)``; VMEM per step is
 one value row.  Empty segments report ``-inf`` (the max monoid identity)
 unless an ``init`` accumulator seeds the tile.  Block shapes default to
 :mod:`repro.kernels.defaults`, overridden per shape bucket by the
-autotuner; the ``gate_ids``/``valid_mask`` fusion epilogues mirror the
-histogram kernel's (DESIGN.md §2.9).
+autotuner; the ``valid_mask`` fusion epilogue mirrors the histogram
+kernel's (DESIGN.md §2.9).
 """
 from __future__ import annotations
 
@@ -39,19 +39,16 @@ __all__ = ["segment_max_pallas"]
 _NEG_INF = float("-inf")
 
 
-def _make_segmax_kernel(*, block_segs: int, gated: bool, accum: bool,
-                        masked: bool, retire: float):
+def _make_segmax_kernel(*, block_segs: int, accum: bool, masked: bool,
+                        retire: float):
     """Kernel-body factory; operand layout mirrors the histogram kernel's
-    (gate row + gate scalar, then init tile, then mask tile)."""
+    (init tile, then mask tile)."""
 
     def kernel(*refs):
         refs = list(refs)
         out_ref = refs.pop()
         ids_ref, v_ref = refs[0], refs[1]
         nxt = 2
-        if gated:
-            gate_ref, gv_ref = refs[nxt], refs[nxt + 1]
-            nxt += 2
         if accum:
             init_ref = refs[nxt]
             nxt += 1
@@ -65,8 +62,6 @@ def _make_segmax_kernel(*, block_segs: int, gated: bool, accum: bool,
         base = i * block_segs
         segs = base + jax.lax.broadcasted_iota(jnp.int32, (1, block_segs), 1)
         sel = ids.T == segs  # (Bn, St)
-        if gated:
-            sel = sel & (gate_ref[...].T == gv_ref[0, 0])
         cand = jnp.where(sel, jnp.broadcast_to(v.T, sel.shape), _NEG_INF)
         partial = jnp.max(cand, axis=0, keepdims=True)  # (1, St)
 
@@ -95,8 +90,6 @@ def segment_max_pallas(
     num_segments: int,
     *,
     init: Optional[jnp.ndarray] = None,
-    gate_ids: Optional[jnp.ndarray] = None,
-    gate_value=None,
     valid_mask: Optional[jnp.ndarray] = None,
     retire: float = _NEG_INF,
     block_rows: int = DEFAULT_BLOCK_ROWS,
@@ -110,9 +103,9 @@ def segment_max_pallas(
     Empty segments yield ``-inf`` (max monoid identity) unless ``init``
     (shape ``(num_segments,)``) seeds the output.
 
-    Fused epilogues (same contract as :func:`histogram_pallas`):
-    ``gate_ids``/``gate_value`` keep only matching rows; ``valid_mask`` +
-    static ``retire`` overwrite masked-out segments after the reduction.
+    Fused epilogue (same contract as :func:`histogram_pallas`):
+    ``valid_mask`` + static ``retire`` overwrite masked-out segments after
+    the reduction.
     Returns float32 of shape (num_segments,).
     """
     n = vals.shape[0]
@@ -125,7 +118,6 @@ def segment_max_pallas(
         if valid_mask is not None:
             out = jnp.where(valid_mask, out, jnp.float32(retire))
         return out
-    gated = gate_ids is not None
     masked = valid_mask is not None
     n_pad = -n % block_rows
     s_pad = -num_segments % block_segs
@@ -138,11 +130,6 @@ def segment_max_pallas(
     seg_spec = pl.BlockSpec((1, block_segs), lambda i, j: (0, i))
     in_specs = [row_spec, row_spec]
     operands = [ids_p, v_p]
-    if gated:
-        gate_p = jnp.pad(gate_ids.astype(jnp.int32), (0, n_pad))[None, :]
-        gv = jnp.asarray(gate_value, jnp.int32).reshape(1, 1)
-        in_specs += [row_spec, pl.BlockSpec((1, 1), lambda i, j: (0, 0))]
-        operands += [gate_p, gv]
     if init is not None:
         init_p = jnp.pad(
             init.astype(jnp.float32), (0, s_pad), constant_values=_NEG_INF
@@ -154,8 +141,8 @@ def segment_max_pallas(
         in_specs.append(seg_spec)
         operands.append(mask_p)
     kernel = _make_segmax_kernel(
-        block_segs=block_segs, gated=gated, accum=init is not None,
-        masked=masked, retire=float(retire),
+        block_segs=block_segs, accum=init is not None, masked=masked,
+        retire=float(retire),
     )
     out = pl.pallas_call(
         kernel,

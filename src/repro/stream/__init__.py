@@ -19,7 +19,6 @@ from .engine import (
     steady_state,
     stream_plq,
     update_state,
-    update_state_naive,
 )
 from .algorithms import snapshot_algorithms
 from .recovery import (
@@ -50,5 +49,4 @@ __all__ = [
     "steady_state",
     "stream_plq",
     "update_state",
-    "update_state_naive",
 ]

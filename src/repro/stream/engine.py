@@ -39,7 +39,7 @@ import numpy as np
 from ..challenge.pipeline import ChallengeResults
 from ..challenge.pipeline import analyze as challenge_analyze
 from ..challenge.pipeline import distributed_scalar_queries
-from ..core.ops import factorize, groupby_aggregate, isin, mix32, multi_key_sort
+from ..core.ops import factorize, isin, mix32, multi_key_sort
 from ..core.plan import unique_concat
 from ..core.sketch import (
     SketchConfig,
@@ -66,7 +66,6 @@ __all__ = [
     "StreamBatchTimings",
     "StreamSnapshot",
     "update_state",
-    "update_state_naive",
     "merge_states",
     "link_table",
     "anonymization_mapping",
@@ -192,39 +191,6 @@ def _merge_dictionary(
     )
 
 
-def _merge_links(
-    state: StreamState,
-    keys: Sequence[jnp.ndarray],
-    packets: jnp.ndarray,
-    valid: jnp.ndarray,
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Merge incoming distinct links into the accumulated link table: one
-    concat + (win, src, dst) group-by with packet sums — the sort-based
-    upsert.  Truncation on overflow keeps the lexicographically smallest
-    groups (deterministic) and is counted, never silent."""
-    cap = state.link_capacity
-    state_valid = jnp.arange(cap, dtype=jnp.int32) < state.n_links
-    merged = groupby_aggregate(
-        [jnp.concatenate([state.win, keys[0]]),
-         jnp.concatenate([state.src, keys[1]]),
-         jnp.concatenate([state.dst, keys[2]])],
-        {"packets": (jnp.concatenate([state.packets, packets]), "sum")},
-        valid_mask=jnp.concatenate([state_valid, valid]),
-        count_name=None,
-    )
-    n2 = jnp.minimum(merged.n_groups, cap)
-    dropped = (merged.n_groups - n2).astype(jnp.int32)
-    live = jnp.arange(cap, dtype=jnp.int32) < n2
-    return (
-        jnp.where(live, merged.keys[0][:cap], _I32_MAX),
-        jnp.where(live, merged.keys[1][:cap], _I32_MAX),
-        jnp.where(live, merged.keys[2][:cap], _I32_MAX),
-        jnp.where(live, merged.aggs["packets"][:cap].astype(jnp.int32), 0),
-        n2,
-        dropped,
-    )
-
-
 def _fold_dictionary_and_activity(
     state: StreamState,
     src: jnp.ndarray,
@@ -234,7 +200,7 @@ def _fold_dictionary_and_activity(
     n_valid: jnp.ndarray,
     backend: str,
 ):
-    """Steps 1 and 3 of the state transition, shared by both link paths.
+    """Steps 1 and 3 of the state transition.
 
     1. persistent anonymization dictionary.  Batch-distinct IPs carry
     their first-appearance position (row-major, src before dst) so new
@@ -290,8 +256,7 @@ def update_state(
     over the state's CSR entries ++ the raw batch rows — duplicate collapse
     under the plus monoid is simultaneously the batch's (win, src, dst)
     group-by AND the upsert into the accumulated matrix, so the link path
-    costs one sort where the pre-CSR path (:func:`update_state_naive`)
-    paid two.  Overflow (groups beyond ``link_capacity``) is counted by
+    costs one sort.  Overflow (groups beyond ``link_capacity``) is counted by
     ``from_coo``, never silent.
 
     Device scopes: ``update_state/dictionary``, ``/links``, ``/activity``.
@@ -321,61 +286,6 @@ def update_state(
                 op="plus",
                 nnz_capacity=state.link_capacity,
             )
-
-        return StreamState(
-            ip_values=ip_values, ip_ids=ip_ids, n_ips=n_ips,
-            links=links,
-            activity=activity,
-            n_packets=state.n_packets + n_valid,
-            n_batches=state.n_batches + 1,
-            overflow=state.overflow + ov_ips + ov_links,
-        )
-
-
-def update_state_naive(
-    state: StreamState,
-    src: jnp.ndarray,
-    dst: jnp.ndarray,
-    win: jnp.ndarray,
-    n_valid: jnp.ndarray,
-    *,
-    backend: str = "auto",
-) -> StreamState:
-    """Pre-CSR link path, kept as the A/B baseline: batch group-by, then a
-    second concat group-by merging it into the accumulated flat link table
-    (:func:`_merge_links`), then a pack into the CSR state layout.  Produces
-    a bit-identical ``StreamState`` to :func:`update_state` — asserted by
-    tests/test_stream.py — at one extra sort per batch.  Same device
-    scopes as :func:`update_state`.
-    """
-    with jax.named_scope("update_state"):
-        n_windows = state.n_windows
-        n_valid = jnp.asarray(n_valid, jnp.int32)
-        src = src.astype(jnp.int32)
-        dst = dst.astype(jnp.int32)
-        win = jnp.clip(win.astype(jnp.int32), 0, n_windows - 1)
-        t = Table(columns={"src": src, "dst": dst}, n_valid=n_valid)
-        valid = t.valid_mask()
-
-        (ip_values, ip_ids, n_ips, ov_ips), activity = \
-            _fold_dictionary_and_activity(
-                state, src, dst, win, valid, n_valid, backend
-            )
-
-        with jax.named_scope("links"):
-            bl = groupby_aggregate(
-                [win, src, dst],
-                {"packets": (jnp.ones((src.shape[0],), jnp.int32), "sum")},
-                n_valid=n_valid,
-                count_name=None,
-            )
-            w2, s2, d2, pk2, n_links, ov_links = _merge_links(
-                state, bl.keys, bl.aggs["packets"], bl.mask()
-            )
-            # pack the (already distinct, lex-sorted) flat table into the
-            # CSR layout
-            links, _ = from_coo([w2, s2], d2, pk2, n_valid=n_links,
-                                op="plus")
 
         return StreamState(
             ip_values=ip_values, ip_ids=ip_ids, n_ips=n_ips,

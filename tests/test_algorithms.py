@@ -452,15 +452,13 @@ def test_analyze_algorithms_sort_budget():
 
 
 def test_analyze_naive_rejects_algorithms():
-    """The graph kernels left ``analyze`` for a phase of their own: neither
-    the plan nor the naive path takes them any more."""
+    """The graph kernels left ``analyze`` for a phase of their own: it
+    takes them no more."""
     t = Table.from_dict(
         {c: np.zeros(8, np.int32) for c in ("src", "dst", "win")}
     )
-    for use_plan in (True, False):
-        with pytest.raises(TypeError, match="algorithms"):
-            analyze(t, n_windows=2, ip_bins=8, k=2, use_plan=use_plan,
-                    algorithms=True)
+    with pytest.raises(TypeError, match="algorithms"):
+        analyze(t, n_windows=2, ip_bins=8, k=2, algorithms=True)
 
 
 def test_challenge_run_scale10_algorithms_match_oracles(tmp_path):
@@ -606,8 +604,7 @@ def test_graph_kernels_leave_the_analyze_program_alone(tmp_path):
     results), and the graph phase's plan program then sorts nothing."""
     from repro.challenge.pipeline import _analyze_and_plans, _jitted
 
-    kw = dict(n_windows=2, ip_bins=32, k=3, backend="auto",
-              fused_epilogue=False)
+    kw = dict(n_windows=2, ip_bins=32, k=3, backend="auto")
     runs = []
     for kernels in ((), ("bfs", "wcc", "pagerank")):
         cfg = ChallengeConfig(scale=8, n_windows=2, ip_bins=32, top_k=3,

@@ -201,7 +201,30 @@ def test_cross_window_overlap_direct():
     t = Table.from_dict({"src": pad(src), "dst": pad(dst), "win": pad(win)},
                         n_valid=n)
     got = np.asarray(jax.jit(
-        lambda t: cross_window_ip_overlap(t, nw, backend="xla"))(t))
+        lambda t: cross_window_ip_overlap(t, nw))(t))
+    np.testing.assert_array_equal(got, ref_window_ip_overlap(src, dst, win, nw))
+
+
+def test_cross_window_overlap_drops_out_of_range_windows():
+    """Rows whose window id lies outside [0, n_windows) join no window:
+    clamped into the edge windows, the IPs they share with windows 1 and
+    n_windows - 2 would count as persisting there."""
+    rng = np.random.default_rng(10)
+    nw, k = 4, 10
+    ips = lambda base: np.arange(base, base + k, dtype=np.int32)
+    # background traffic over IPs 0..29 in every window
+    src = [rng.integers(0, 30, 400).astype(np.int32)]
+    dst = [rng.integers(0, 30, 400).astype(np.int32)]
+    win = [rng.integers(0, nw, 400).astype(np.int32)]
+    for w, s_, d_ in ((1, 100, 200), (-1, 100, 300),
+                      (nw - 2, 400, 500), (nw, 400, 600)):
+        src.append(ips(s_))
+        dst.append(ips(d_))
+        win.append(np.full(k, w, np.int32))
+    src, dst, win = (np.concatenate(c) for c in (src, dst, win))
+    t = Table.from_dict({"src": src, "dst": dst, "win": win})
+    got = np.asarray(jax.jit(lambda t: cross_window_ip_overlap(t, nw))(t))
+    assert got[0] == 0
     np.testing.assert_array_equal(got, ref_window_ip_overlap(src, dst, win, nw))
 
 
@@ -249,7 +272,6 @@ _STATIC_CASES = {
     "ip_bins": ({}, {"ip_bins": 32}, {"analyze"}),
     "top_k": ({}, {"top_k": 3}, {"analyze"}),
     "backend": ({}, {"backend": "xla"}, {"analyze"}),
-    "fused_epilogue": ({}, {"fused_epilogue": True}, {"analyze"}),
     "algorithms": ({}, {"analytics": _BFS},
                    {"analyze", "graph/plan", "graph/bfs"}),
     "bfs_source": ({"analytics": _BFS},
@@ -286,7 +308,7 @@ def test_phase_program_cache_keys_every_static(tmp_path, static):
         build_table(src, dst, win, n), jax.random.key(cfg.seed))
     want = jax.jit(lambda t: analyze(
         t, n_windows=cfg.n_windows, ip_bins=cfg.ip_bins, k=cfg.top_k,
-        backend=cfg.backend, fused_epilogue=cfg.fused_epilogue))(anon.table)
+        backend=cfg.backend))(anon.table)
     pairs = [(run.anon_table, anon.table), (run.results, want)]
     a = cfg.analytics
     if a.kernels:

@@ -3,8 +3,8 @@
 Covers the packed-key sort edge cases (dtype extremes, empty/full validity,
 payload stability), the SortedEdges derivations against the naive group-bys
 (bit-identical buffers), the sort-free top-k, the lowered-HLO sort budget of
-``analyze`` (<= 3 full-capacity sorts, down from ~10), and plan-vs-naive
-bit-identity of the full challenge analysis at scales 10 and 14.
+``analyze`` (<= 3 full-capacity sorts), and every query family of the full
+challenge analysis against the NumPy oracles at scales 10 and 14.
 """
 import re
 
@@ -19,10 +19,10 @@ from repro.core import (
     argmax_top_k,
     count_hlo_sorts,
     groupby_aggregate,
+    mix32,
     multi_key_sort,
     packable_keys,
     run_all_queries,
-    run_all_queries_naive,
     top_k,
     top_links,
     top_links_from_plan,
@@ -37,7 +37,13 @@ from repro.core.plan import (
     unique_concat,
     unique_lead,
 )
-from repro.core.ref import ref_run_all_queries
+from repro.core.ref import (
+    ref_run_all_queries,
+    ref_top_links,
+    ref_traffic_matrix,
+    ref_window_ip_overlap,
+    ref_windowed_histogram,
+)
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -261,32 +267,20 @@ def test_top_links_from_plan_matches_top_links():
 
 # --------------------------------------------------------- HLO sort budget
 
-def _analyze_fns(cap, nw=4):
+def test_analyze_hlo_sort_budget():
+    """THE acceptance gate: jit-traced analyze performs <= 3 full-capacity
+    sorts (the two plan sorts and the ``unique_ips`` concat sort)."""
     from repro.challenge.pipeline import analyze
 
+    cap = 512
     t = Table.from_dict(
         {k: np.zeros(cap, np.int32) for k in ("src", "dst", "win")},
         n_valid=cap - 3,
     )
-    mk = lambda use_plan: jax.jit(
-        lambda t: analyze(t, n_windows=nw, ip_bins=32, k=5, backend="xla",
-                          use_plan=use_plan)
-    )
-    return t, mk(True), mk(False)
-
-
-def test_analyze_hlo_sort_budget():
-    """THE acceptance gate: jit-traced analyze performs <= 3 full-capacity
-    sorts where the pre-plan implementation performed ~10 (post-CSE)."""
-    cap = 512
-    t, f_plan, f_naive = _analyze_fns(cap)
-    plan_sorts = count_hlo_sorts(f_plan.lower(t).compile().as_text(), cap)
-    naive_sorts = count_hlo_sorts(f_naive.lower(t).compile().as_text(), cap)
+    f = jax.jit(lambda t: analyze(t, n_windows=4, ip_bins=32, k=5,
+                                  backend="xla"))
+    plan_sorts = count_hlo_sorts(f.lower(t).compile().as_text(), cap)
     assert plan_sorts <= 3, f"plan analyze lowered to {plan_sorts} sorts"
-    assert naive_sorts >= 8, (
-        f"naive baseline lowered to {naive_sorts} sorts — the A/B "
-        "comparison no longer measures what DESIGN.md §2.3 claims"
-    )
 
 
 def test_run_all_queries_hlo_sort_budget():
@@ -296,15 +290,19 @@ def test_run_all_queries_hlo_sort_budget():
     assert count_hlo_sorts(f.lower(t).compile().as_text()) <= 3
 
 
-# ------------------------------------------- plan == naive == oracle at scale
+# ------------------------------------------------- analyze == oracle at scale
 
-@pytest.mark.parametrize("scale", [10, 14])
-def test_analyze_plan_bitwise_equals_naive_at_scale(scale):
-    """All Table III results (scalar + vector + windowed + overlap + top-k)
-    bit-identical between the plan and pre-plan paths on the challenge's
-    synthetic capture, and scalars equal to the NumPy oracle."""
-    from jax import tree_util as jtu
+WINDOWED_KEYS = (
+    "valid_packets", "unique_links", "max_link_packets", "n_unique_sources",
+    "n_unique_destinations", "max_source_packets", "max_source_fanout",
+    "max_destination_packets", "max_destination_fanin",
+)
 
+
+@pytest.fixture(scope="module", params=[10, 14])
+def analyzed(request):
+    """One jitted ``analyze`` per scale over the challenge's synthetic
+    capture, with the live host columns the oracles read."""
     from repro.challenge.pipeline import (
         ChallengeConfig,
         analyze,
@@ -313,27 +311,76 @@ def test_analyze_plan_bitwise_equals_naive_at_scale(scale):
     )
     from repro.data.rmat import synthetic_packets
 
+    scale = request.param
     cfg = ChallengeConfig(scale=scale, n_windows=4, ip_bins=64, top_k=7)
     cols = synthetic_packets(cfg.packets, scale=scale, seed=3)
     src, dst, win, n = build_columns(cols, cfg)
-    t = build_table(src, dst, win, n)
-    kw = dict(n_windows=cfg.n_windows, ip_bins=cfg.ip_bins, k=cfg.top_k,
-              backend="xla")
-    res_plan = jax.jit(lambda t: analyze(t, **kw))(t)
-    res_naive = jax.jit(lambda t: analyze(t, use_plan=False, **kw))(t)
-    leaves_p = jtu.tree_leaves_with_path(res_plan)
-    leaves_n = jtu.tree_leaves_with_path(res_naive)
-    assert len(leaves_p) == len(leaves_n)
-    for (kp, vp), (kn, vn) in zip(leaves_p, leaves_n):
-        assert jtu.keystr(kp) == jtu.keystr(kn)
-        np.testing.assert_array_equal(np.asarray(vp), np.asarray(vn),
-                                      err_msg=jtu.keystr(kp))
-    ref = ref_run_all_queries(cols["src"].astype(np.int64),
-                              cols["dst"].astype(np.int64))
-    for k, v in ref.items():
-        assert int(getattr(res_plan.scalars, k)) == v, k
-    # and the scalar suite entrypoints agree with each other too
-    a = jax.jit(run_all_queries)(t)
-    b = jax.jit(run_all_queries_naive)(t)
-    for k in ref:
-        assert int(getattr(a, k)) == int(getattr(b, k)) == ref[k], k
+    res = jax.jit(lambda t: analyze(
+        t, n_windows=cfg.n_windows, ip_bins=cfg.ip_bins, k=cfg.top_k,
+        backend="xla"))(build_table(src, dst, win, n))
+    host = {k: v[:n].astype(np.int64) for k, v in
+            (("src", src), ("dst", dst), ("win", win))}
+    return cfg, host, jax.device_get(res)
+
+
+def _live(arr, n):
+    return np.asarray(arr)[:int(n)]
+
+
+def _check_group(g, keys, aggs):
+    """A tail-padded group result's live prefix against oracle arrays."""
+    n = int(g.n_groups)
+    assert n == len(keys[0])
+    for got, want in zip(g.keys, keys):
+        np.testing.assert_array_equal(_live(got, n), want)
+    for name, want in aggs.items():
+        np.testing.assert_array_equal(_live(g.aggs[name], n), want, name)
+
+
+@pytest.mark.parametrize("family", ["scalars", "vectors", "topk", "windowed",
+                                    "activity", "overlap"])
+def test_analyze_matches_oracles_at_scale(analyzed, family):
+    """Every output of ``analyze``, entry by entry, against the NumPy
+    oracles of ``core/ref.py`` (packets are unweighted: one row each)."""
+    cfg, h, res = analyzed
+    s, d, w = h["src"], h["dst"], h["win"]
+    if family == "scalars":
+        for k, v in ref_run_all_queries(s, d).items():
+            assert int(getattr(res.scalars, k)) == v, k
+    elif family == "vectors":
+        ls, ld, lp = ref_traffic_matrix(s, d)
+        _check_group(res.links, (ls, ld), {"packets": lp, "count": lp})
+        for g, ips in ((res.per_source, s), (res.per_destination, d)):
+            keys, counts = np.unique(ips, return_counts=True)
+            _check_group(g, (keys,), {"packets": counts, "count": counts})
+        for g, ends in ((res.source_fanout, ls), (res.destination_fanin, ld)):
+            keys, counts = np.unique(ends, return_counts=True)
+            _check_group(g, (keys,), {"count": counts})
+        for u, ips in ((res.unique_sources, s), (res.unique_destinations, d)):
+            keys, counts = np.unique(ips, return_counts=True)
+            assert int(u.n_unique) == len(keys)
+            np.testing.assert_array_equal(_live(u.values, len(keys)), keys)
+            np.testing.assert_array_equal(_live(u.counts, len(keys)), counts)
+    elif family == "topk":
+        ts, td, tp = ref_top_links(s, d, cfg.top_k)
+        top = res.top
+        assert int(top.n_valid) == len(tp)
+        for got, want in ((top.src, ts), (top.dst, td), (top.packets, tp)):
+            np.testing.assert_array_equal(_live(got, len(tp)), want)
+    elif family == "windowed":
+        for win in range(cfg.n_windows):
+            sel = w == win
+            ref = (ref_run_all_queries(s[sel], d[sel]) if sel.any()
+                   else dict.fromkeys(WINDOWED_KEYS, 0))
+            for k in WINDOWED_KEYS:
+                assert int(res.windowed[k][win]) == ref[k], (k, win)
+    elif family == "activity":
+        bins = np.asarray(mix32(jnp.asarray(s.astype(np.int32)))).astype(
+            np.int64) % cfg.ip_bins
+        np.testing.assert_array_equal(
+            np.asarray(res.window_activity),
+            ref_windowed_histogram(w, bins, cfg.n_windows, cfg.ip_bins))
+    else:
+        np.testing.assert_array_equal(
+            np.asarray(res.window_ip_overlap),
+            ref_window_ip_overlap(s, d, w, cfg.n_windows))

@@ -92,15 +92,6 @@ def test_analyze_names_the_algorithm_pass_when_on():
         assert _has_scope(names, f"graph/{k}"), k
 
 
-def test_naive_analyze_uses_the_same_family_names():
-    names = _op_names(lambda t: analyze(t, n_windows=4, ip_bins=64, k=5,
-                                        backend="xla", use_plan=False),
-                      _table(256))
-    for scope in ("scalars", "groups", "topk", "windowed", "activity",
-                  "overlap"):
-        assert _has_scope(names, f"analyze/{scope}"), scope
-
-
 @pytest.mark.parametrize("scope", ["unique", "permutation", "factorize",
                                    "gather"])
 def test_anonymize_names_each_step(scope):

@@ -4,8 +4,8 @@ Covers the zero-sort plan->CSR construction against scipy.sparse (the
 GraphBLAS reference role), duplicate-collapsing from_coo with overflow
 truncation, ewise_union merge identities, plus/max reductions, masked
 mxv/vxm against the dense oracle (and the Pallas segmented-reduction kernel
-in interpret mode), the CSR scalar-suite equality, and the CSR-vs-naive
-bit-identity of the streaming state transition.
+in interpret mode), the CSR scalar-suite equality, and the streaming state
+transition against a NumPy fold of the same batches.
 """
 import numpy as np
 import jax
@@ -16,12 +16,8 @@ from _hypothesis_compat import given, settings, st
 
 from repro.core import Table
 from repro.core.plan import count_hlo_sorts, sorted_edges
-from repro.core.queries import (
-    run_all_queries,
-    run_all_queries_csr,
-    table_csrs,
-    traffic_matrix_csr,
-)
+from repro.core.ops import masked_max
+from repro.core.queries import run_all_queries, table_csrs
 from repro.core.sparse import (
     csr_from_plan,
     degrees,
@@ -255,6 +251,9 @@ def test_segmented_reduce_interpret_matches_xla(seed, num_segments):
 # ------------------------------------------------- the CSR scalar suite
 
 def test_run_all_queries_csr_bit_identical_and_sort_budget():
+    """The ten scalars in matrix language over ``table_csrs`` — 1^T A 1,
+    |A|_0, max(A), A·1, |A|_0·1 and the transpose mirrors — equal the
+    group-by suite, and the CSR pair costs the two plan sorts alone."""
     rng = np.random.default_rng(7)
     n, cap = 3000, 3333
     pad = lambda a: np.concatenate([a, np.full(cap - n, 5, np.int32)])
@@ -263,44 +262,71 @@ def test_run_all_queries_csr_bit_identical_and_sort_budget():
         "dst": pad(rng.integers(0, 300, n).astype(np.int32)),
         "n_packets": pad(rng.integers(1, 6, n).astype(np.int32)),
     }, n_valid=n)
-    import dataclasses
     a = jax.jit(run_all_queries)(t)
-    b = jax.jit(run_all_queries_csr)(t)
-    for f in dataclasses.fields(a):
-        assert int(getattr(a, f.name)) == int(getattr(b, f.name)), f.name
-    txt = jax.jit(run_all_queries_csr).lower(t).compile().as_text()
-    assert count_hlo_sorts(txt) <= 3
-    # and the convenience constructors agree
-    csr_src, csr_dst = table_csrs(t)
-    one = traffic_matrix_csr(t)
-    assert int(one.nnz) == int(csr_src.nnz) == int(b.unique_links)
-    assert int(csr_dst.n_rows) == int(b.n_unique_destinations)
+    csr_src, csr_dst = jax.jit(table_csrs)(t)
+    live = csr_src.entry_mask()
+    got = {
+        "valid_packets": jnp.sum(jnp.where(live, csr_src.vals, 0)),
+        "unique_links": csr_src.nnz,
+        "max_link_packets": masked_max(csr_src.vals, live),
+        "n_unique_sources": csr_src.n_rows,
+        "n_unique_destinations": csr_dst.n_rows,
+    }
+    for side, csr in (("source", csr_src), ("destination", csr_dst)):
+        rows = csr.row_mask()
+        got[f"max_{side}_packets"] = masked_max(reduce_rows(csr, "plus"), rows)
+        fan = "max_source_fanout" if side == "source" else "max_destination_fanin"
+        got[fan] = masked_max(degrees(csr), rows)
+    for k, v in got.items():
+        assert int(v) == int(getattr(a, k)), k
+    txt = jax.jit(table_csrs).lower(t).compile().as_text()
+    assert count_hlo_sorts(txt) <= 2
 
 
-# ------------------------------------- stream transition: CSR == naive
+# ------------------------------------- stream transition: CSR == NumPy fold
 
 def test_stream_update_csr_bit_identical_to_naive():
-    """The CSR link path (one from_coo upsert) produces a bit-identical
-    StreamState to the pre-CSR two-sort path, batch by batch."""
-    from repro.stream import init_state, update_state, update_state_naive
+    """The CSR link path (one from_coo upsert), batch by batch, against a
+    NumPy fold of the same batches: the links as sorted (win, src, dst)
+    with packet sums, the dictionary with ids in first-seen order
+    (row-major, src before dst), the counters and the activity."""
+    from repro.core.ops import mix32
+    from repro.core.ref import ref_windowed_histogram
+    from repro.stream import init_state, update_state
 
     rng = np.random.default_rng(11)
-    n, batch, nw = 1024, 256, 3
+    n, batch, nw, bins = 1024, 256, 3, 32
     src = rng.integers(0, 90, n).astype(np.int32)
     dst = rng.integers(0, 90, n).astype(np.int32)
     win = rng.integers(0, nw, n).astype(np.int32)
-    a = init_state(n, 2 * n, nw, 32)
-    b = init_state(n, 2 * n, nw, 32)
+    a = init_state(n, 2 * n, nw, bins)
+    links, ids = {}, {}
     for s in range(0, n, batch):
         sl = slice(s, s + batch)
         a = update_state(a, jnp.asarray(src[sl]), jnp.asarray(dst[sl]),
                          jnp.asarray(win[sl]), batch, backend="xla")
-        b = update_state_naive(b, jnp.asarray(src[sl]), jnp.asarray(dst[sl]),
-                               jnp.asarray(win[sl]), batch, backend="xla")
-        for f in ("win", "src", "dst", "packets", "ip_values", "ip_ids"):
+        for w, u, v in zip(win[sl], src[sl], dst[sl]):
+            links[(w, u, v)] = links.get((w, u, v), 0) + 1
+            for ip in (u, v):
+                ids.setdefault(ip, len(ids))
+        keys = sorted(links)
+        n_links, n_ips = len(keys), len(ids)
+        assert (int(a.n_links), int(a.n_ips)) == (n_links, n_ips)
+        assert (int(a.n_packets), int(a.n_batches)) == (s + batch,
+                                                        s // batch + 1)
+        assert int(a.overflow) == 0
+        for f, col in (("win", 0), ("src", 1), ("dst", 2)):
             np.testing.assert_array_equal(
-                np.asarray(getattr(a, f)), np.asarray(getattr(b, f)), f)
-        for f in ("n_links", "n_ips", "n_packets", "overflow"):
-            assert int(getattr(a, f)) == int(getattr(b, f)), f
-    np.testing.assert_array_equal(np.asarray(a.activity),
-                                  np.asarray(b.activity))
+                np.asarray(getattr(a, f))[:n_links],
+                [k[col] for k in keys], f)
+        np.testing.assert_array_equal(np.asarray(a.packets)[:n_links],
+                                      [links[k] for k in keys])
+        values = sorted(ids)
+        np.testing.assert_array_equal(np.asarray(a.ip_values)[:n_ips], values)
+        np.testing.assert_array_equal(np.asarray(a.ip_ids)[:n_ips],
+                                      [ids[v] for v in values])
+        seen = slice(0, s + batch)
+        act = np.asarray(mix32(jnp.asarray(src[seen]))).astype(np.int64) % bins
+        np.testing.assert_array_equal(
+            np.asarray(a.activity),
+            ref_windowed_histogram(win[seen], act, nw, bins))

@@ -2,7 +2,6 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.core import Table
@@ -95,15 +94,12 @@ def test_window_ids_explicit_t0():
                                   [-2, -2, -1, 1])
 
 
-@pytest.mark.parametrize("method", ["csr", "grid"])
-def test_windowed_queries_empty_table(method):
-    """n_valid == 0: every statistic is 0 in every window, both paths."""
+def test_windowed_queries_empty_table():
+    """n_valid == 0: every statistic is 0 in every window."""
     t = Table.from_dict(
         {"src": np.zeros(16, np.int32), "dst": np.zeros(16, np.int32),
          "ts": np.zeros(16, np.int32)}, n_valid=0)
-    res = jax.jit(
-        lambda t: windowed_queries(t, 10, 4, method=method)
-    )(t)
+    res = jax.jit(lambda t: windowed_queries(t, 10, 4))(t)
     for k, v in res.items():
         assert v.shape == (4,)
         np.testing.assert_array_equal(np.asarray(v), 0, err_msg=k)

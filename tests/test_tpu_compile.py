@@ -59,32 +59,30 @@ def _compiled_text(fn, *args) -> str:
 
 @pytest.mark.parametrize("epilogue", [False, True])
 def test_histogram_compiles(spec, epilogue):
-    def fn(ids, w, gate, init, mask):
+    def fn(ids, w, init, mask):
         if not epilogue:
             return histogram_pallas(ids, OUTS, w)
-        return histogram_pallas(ids, OUTS, w, init=init, gate_ids=gate,
-                                gate_value=3, valid_mask=mask, retire=-1.0)
+        return histogram_pallas(ids, OUTS, w, init=init, valid_mask=mask,
+                                retire=-1.0)
 
     text = _compiled_text(
         fn, spec((ROWS,), jnp.int32), spec((ROWS,), jnp.float32),
-        spec((ROWS,), jnp.int32), spec((OUTS,), jnp.float32),
-        spec((OUTS,), jnp.bool_),
+        spec((OUTS,), jnp.float32), spec((OUTS,), jnp.bool_),
     )
     assert "tpu_custom_call" in text
 
 
 @pytest.mark.parametrize("epilogue", [False, True])
 def test_segment_max_compiles(spec, epilogue):
-    def fn(vals, ids, gate, init, mask):
+    def fn(vals, ids, init, mask):
         if not epilogue:
             return segment_max_pallas(vals, ids, OUTS)
-        return segment_max_pallas(vals, ids, OUTS, init=init, gate_ids=gate,
-                                  gate_value=3, valid_mask=mask, retire=0.0)
+        return segment_max_pallas(vals, ids, OUTS, init=init,
+                                  valid_mask=mask, retire=0.0)
 
     text = _compiled_text(
         fn, spec((ROWS,), jnp.float32), spec((ROWS,), jnp.int32),
-        spec((ROWS,), jnp.int32), spec((OUTS,), jnp.float32),
-        spec((OUTS,), jnp.bool_),
+        spec((OUTS,), jnp.float32), spec((OUTS,), jnp.bool_),
     )
     assert "tpu_custom_call" in text
 
