@@ -6,6 +6,12 @@ The paper's recipe, verbatim in data-science ops:
   2. generate ``iota(N)`` and ``shuffle`` it  -> random permutation,
   3. ``gather`` new ids for every row.
 
+Step 1 also returns each row's rank among the N IPs, as
+``np.unique(return_inverse=True)`` does: the unique sort carries each
+endpoint's position, and its segment ids are scattered back to the rows
+(``plan.unique_concat_inverse``), so step 3 is a rank off that sort and a
+gather — no binary search of the rows over the IP domain.
+
 We provide the stochastic variant (``cupy.random.shuffle`` analogue via
 ``jax.random``) and the deterministic HashGraph-style variant the paper cites
 as future work (Green et al. [22, 23]) — both over static-shape buffers.
@@ -18,8 +24,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .ops import factorize, hash_permutation, random_permutation
-from .queries import unique_ips
+from .ops import hash_permutation, random_permutation
+from .plan import unique_concat_inverse
 from .table import Table
 
 __all__ = ["AnonymizationResult", "anonymize"]
@@ -58,12 +64,13 @@ def anonymize(
         iterations further decorrelate the permutation at negligible cost.
     """
     # device scopes name the paper's three steps in the profiler's op
-    # metadata: anonymize/unique, /permutation, /factorize, /gather
+    # metadata: anonymize/unique, /factorize (the rank scatter off the
+    # unique sort), /permutation, /gather
     with jax.named_scope("anonymize"):
-        with jax.named_scope("unique"):
-            ips = unique_ips(t)
-        cap = ips.values.shape[0]
-        n = ips.n_unique
+        ips, src_rank, dst_rank = unique_concat_inverse(
+            t["src"], t["dst"], t.n_valid)
+        cap = ips.keys[0].shape[0]
+        n = ips.n_groups
         with jax.named_scope("permutation"):
             if method == "shuffle":
                 if key is None:
@@ -81,9 +88,7 @@ def anonymize(
             else:
                 raise ValueError(f"unknown method {method!r}")
 
-        with jax.named_scope("factorize"):
-            src_rank = factorize(t["src"], ips.values)
-            dst_rank = factorize(t["dst"], ips.values)
         with jax.named_scope("gather"):
             anon = t.with_columns(src=perm[src_rank], dst=perm[dst_rank])
-    return AnonymizationResult(table=anon, ip_values=ips.values, new_ids=perm, n_ips=n)
+    return AnonymizationResult(table=anon, ip_values=ips.keys[0], new_ids=perm,
+                               n_ips=n)

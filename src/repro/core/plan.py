@@ -18,7 +18,8 @@ derivation helpers below reproduce the exact ``GroupResult``/``UniqueResult``
 buffers the naive per-query group-bys emit (bit-identical, including tail
 padding), so consumers swap wholesale.  A mirrored dst-leading plan covers
 the destination side; distinct IPs take one packed concat sort
-(:func:`unique_concat`).  The sorts themselves are the packed single-operand
+(:func:`unique_concat`, or :func:`unique_concat_inverse` when each row's
+rank is wanted too).  The sorts themselves are the packed single-operand
 uint64 sorts of :mod:`repro.core.ops`.
 
 The plan is a pytree: it crosses ``jit``/``shard_map`` boundaries and can be
@@ -28,17 +29,24 @@ from __future__ import annotations
 
 import dataclasses
 import re
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
+from ..compat import enable_x64
 from .ops import (
     GroupResult,
     UniqueResult,
+    _bias_u32,
+    _fuse_u64,
     _scatter_firsts,
+    _split_u64,
+    _unbias_u32,
     groupby_aggregate,
     multi_key_sort,
+    packable_keys,
     segment_ids_from_sorted,
 )
 from .table import Table
@@ -52,6 +60,7 @@ __all__ = [
     "lead_fanout",
     "unique_lead",
     "unique_concat",
+    "unique_concat_inverse",
     "count_hlo_sorts",
 ]
 
@@ -244,6 +253,63 @@ def unique_concat(
     return groupby_aggregate(
         [compact], values, n_valid=2 * n_valid, count_name=count_name
     )
+
+
+def unique_concat_inverse(
+    a: jnp.ndarray, b: jnp.ndarray, n_valid: jnp.ndarray
+) -> Tuple[GroupResult, jnp.ndarray, jnp.ndarray]:
+    """:func:`unique_concat` plus each live row's rank among the distinct
+    values — ``np.unique(return_inverse=True)`` off the same ONE sort.
+
+    The packed uint64 key carries, below the validity flag and the biased
+    32-bit value, each compact slot's position in ⌈log2(2·cap)⌉ low bits
+    (56 bits at 2^22 rows, 64 at 2^30).  Positions are distinct and
+    ascending, so the sort orders the values as the stable sort of
+    :func:`unique_concat` does, and the ``GroupResult`` equals its
+    ``count_name=None`` result bit for bit.  The segment id of each sorted
+    slot, scattered back to its position, is its rank: no binary search
+    over the distinct values.  Returns ``(group, rank_a, rank_b)``; ranks
+    of padding rows (>= ``n_valid``) are 0.  The sort runs in scope
+    ``unique``, the scatter in ``factorize``.
+    """
+    a, b = jnp.asarray(a), jnp.asarray(b)
+    if not packable_keys([a, b]):
+        raise ValueError("unique_concat_inverse requires int32/uint32 columns")
+    cap = a.shape[0]
+    m = 2 * cap
+    pos_bits = max(1, (m - 1).bit_length())
+    if pos_bits > 31:
+        raise ValueError(f"2 * capacity {m} leaves no room for positions")
+    n_valid = jnp.asarray(n_valid, jnp.int32)
+    rows = jnp.arange(cap, dtype=jnp.int32)
+    with jax.named_scope("unique"):
+        both = jnp.concatenate([a, b])
+        idx = jnp.arange(m, dtype=jnp.int32)
+        shifted = jnp.where(idx < n_valid, idx, idx - n_valid + cap)
+        compact = both[jnp.where(idx < 2 * n_valid, shifted, 0)]
+        # the live slots are the prefix [0, 2·n_valid) before the sort and
+        # after it (the flag sends padding last), so one mask serves both
+        live = idx < 2 * n_valid
+        u = _bias_u32(compact)
+        lo = (u << pos_bits) | idx.astype(jnp.uint32)
+        hi = (u >> (32 - pos_bits)) | ((~live).astype(jnp.uint32) << pos_bits)
+        with enable_x64():
+            packed = lax.sort(_fuse_u64(hi, lo), is_stable=True)
+        shi, slo = _split_u64(packed)
+        pos = (slo & jnp.uint32((1 << pos_bits) - 1)).astype(jnp.int32)
+        svals = _unbias_u32((slo >> pos_bits) | (shi << (32 - pos_bits)),
+                            compact.dtype)
+        seg, first, n_groups = segment_ids_from_sorted([svals], 2 * n_valid)
+        group = GroupResult(keys=(_scatter_firsts(svals, seg, first, m),),
+                            aggs={}, n_groups=n_groups)
+    with jax.named_scope("factorize"):
+        # pos is a permutation of [0, m): one scatter, every slot written
+        inv = jnp.zeros((m,), jnp.int32).at[pos].set(
+            jnp.where(live, seg, 0), unique_indices=True)
+        rank_a = jnp.where(rows < n_valid, inv[:cap], 0)
+        rank_b = jnp.where(rows < n_valid,
+                           lax.dynamic_slice(inv, (n_valid,), (cap,)), 0)
+    return group, rank_a, rank_b
 
 
 # -----------------------------------------------------------------------------

@@ -18,6 +18,7 @@ from repro.core import (
     Table,
     argmax_top_k,
     count_hlo_sorts,
+    factorize,
     groupby_aggregate,
     mix32,
     multi_key_sort,
@@ -35,6 +36,7 @@ from repro.core.plan import (
     link_groups,
     sorted_edges,
     unique_concat,
+    unique_concat_inverse,
     unique_lead,
 )
 from repro.core.ref import (
@@ -235,6 +237,71 @@ def test_unique_concat_matches_masked_concat_groupby():
                                   np.asarray(want.keys[0]))
     np.testing.assert_array_equal(np.asarray(got.aggs["first_pos"])[:k],
                                   np.asarray(want.aggs["first_pos"])[:k])
+
+
+def _inverse_case(name, seed=0):
+    """(src, dst, n_valid) for one unique_concat_inverse case; the padding
+    rows hold garbage the ranks must not see."""
+    rng = np.random.default_rng(seed)
+    cap = 64
+    garbage = lambda: rng.integers(I32_MIN, I32_MAX, cap, dtype=np.int32)
+    src, dst = garbage(), garbage()
+    if name == "padding":
+        n = 41
+        src[:n] = rng.integers(0, 30, n)
+        dst[:n] = rng.integers(20, 50, n)
+    elif name == "empty":
+        n = 0
+    elif name == "one_ip":
+        n = 50
+        src[:n] = dst[:n] = 7
+    elif name == "self_loops":
+        n = cap
+        src[:] = rng.integers(0, 20, n)
+        dst[:] = np.where(rng.random(n) < 0.5, src, rng.integers(0, 20, n))
+    elif name == "dtype_extremes":
+        n = 37
+        src[:n] = rng.choice([I32_MIN, -1, 0, 5, I32_MAX - 1, I32_MAX], n)
+        dst[:n] = rng.choice([I32_MIN, 0, I32_MAX], n)
+    elif name == "uint32":
+        n = 45
+        top = np.iinfo(np.uint32).max
+        src = rng.integers(top - 40, top, cap, dtype=np.uint32, endpoint=True)
+        dst = rng.integers(0, 40, cap).astype(np.uint32)
+        dst[:5] = top
+    else:  # seeded random sweep
+        n = int(rng.integers(1, cap + 1))
+        hi = int(rng.integers(1, 200))
+        src[:n] = rng.integers(0, hi, n)
+        dst[:n] = rng.integers(0, hi, n)
+    return src, dst, n
+
+
+@pytest.mark.parametrize(
+    "name,seed",
+    [(c, 0) for c in ("padding", "empty", "one_ip", "self_loops",
+                      "dtype_extremes", "uint32")]
+    + [("random", s) for s in range(6)],
+)
+def test_unique_concat_inverse_ranks_equal_factorize(name, seed):
+    """The ranks carried through the unique sort equal a binary search of
+    each live row over the distinct values; the distinct values equal
+    ``unique_concat``'s buffers; padding rows rank 0."""
+    src, dst, n = _inverse_case(name, seed)
+    cap = len(src)
+    a, b = jnp.asarray(src), jnp.asarray(dst)
+    g, rank_a, rank_b = jax.jit(unique_concat_inverse)(a, b, n)
+    want = unique_concat(a, b, n, count_name=None)
+    assert jax.tree.structure(g) == jax.tree.structure(want)
+    for x, y in zip(jax.tree.leaves(g), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    live = np.concatenate([src[:n], dst[:n]])
+    assert int(g.n_groups) == len(np.unique(live))
+    for col, rank in ((a, rank_a), (b, rank_b)):
+        rank = np.asarray(rank)
+        np.testing.assert_array_equal(
+            rank[:n], np.asarray(factorize(col, want.keys[0]))[:n])
+        np.testing.assert_array_equal(rank[n:], np.zeros(cap - n, np.int32))
 
 
 # ------------------------------------------------------------ sort-free top-k

@@ -1,11 +1,22 @@
 """End-to-end tests: challenge queries + anonymization vs the NumPy oracle."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
-from repro.core import Table, anonymize, run_all_queries, traffic_matrix
+from repro.core import (
+    Table,
+    anonymize,
+    count_hlo_sorts,
+    factorize,
+    hash_permutation,
+    random_permutation,
+    run_all_queries,
+    traffic_matrix,
+)
 from repro.core import queries as Q
 from repro.core.ref import (
     ref_anonymize_check,
@@ -91,6 +102,61 @@ def test_anonymize_is_isomorphism(method, rounds):
     a_src = np.asarray(res.table["src"])[:n]
     a_dst = np.asarray(res.table["dst"])[:n]
     assert ref_anonymize_check(src, dst, a_src, a_dst)
+
+
+def _ref_permutation(method, rounds, key, cap, n):
+    """The permutation step alone, as anonymize composes its rounds."""
+    if method == "shuffle":
+        keys = jax.random.split(key, rounds)
+        perm = random_permutation(keys[0], cap, n)
+        for k in keys[1:]:
+            perm = perm[random_permutation(k, cap, n)]
+    else:
+        perm = hash_permutation(cap, n)
+        for r in range(1, rounds):
+            perm = perm[hash_permutation(cap, n, salt=0x9E3779B9 + r)]
+    return perm
+
+
+@pytest.mark.parametrize("method", ["shuffle", "hash"])
+@pytest.mark.parametrize("rounds", [1, 2])
+def test_anonymize_equals_unique_factorize_gather(method, rounds):
+    """anonymize == unique_ips, a binary search of each row over the IP
+    domain, and a gather through the permutation, field by field on the
+    live rows; its program holds one sort for unique and one per
+    permutation round, and no loop beyond the permutation's own."""
+    rng = np.random.default_rng(23)
+    n = 300
+    src = rng.integers(0, 90, n).astype(np.int32)
+    dst = np.where(rng.random(n) < 0.2, src, rng.integers(50, 140, n))
+    dst = dst.astype(np.int32)
+    src[:3] = np.iinfo(np.int32).max
+    t = make_table(src, dst)
+    key = jax.random.key(9)
+    fn = jax.jit(lambda t, k: anonymize(t, k, method=method, rounds=rounds))
+    res = fn(t, key)
+
+    ips = Q.unique_ips(t)
+    cap = ips.values.shape[0]
+    perm = _ref_permutation(method, rounds, key, cap, ips.n_unique)
+    assert int(res.n_ips) == int(ips.n_unique)
+    np.testing.assert_array_equal(np.asarray(res.ip_values),
+                                  np.asarray(ips.values))
+    np.testing.assert_array_equal(np.asarray(res.new_ids), np.asarray(perm))
+    for col in ("src", "dst"):
+        want = perm[factorize(t[col], ips.values)]
+        np.testing.assert_array_equal(np.asarray(res.table[col])[:n],
+                                      np.asarray(want)[:n])
+    assert int(res.table.n_valid) == n
+
+    hlo = fn.lower(t, key).compile().as_text()
+    assert count_hlo_sorts(hlo) == 1 + rounds
+    perm_hlo = jax.jit(lambda k, m: _ref_permutation(
+        method, rounds, k, cap, m)).lower(key, ips.n_unique).compile().as_text()
+    # a loop's tuple shape may hold "/*index=5*/": match the op name alone
+    loops = lambda text: len(re.findall(r"\swhile\(", text))
+    n_loops, n_perm_loops = loops(hlo), loops(perm_hlo)
+    assert n_loops <= n_perm_loops
 
 
 def test_anonymize_preserves_query_results():
